@@ -14,8 +14,9 @@ Two implementations, same interface:
 
 - :class:`PyHostPortAllocator` — pure Python.
 - :class:`NativeHostPortAllocator` — the C++ allocator in ``native/`` via
-  ctypes (the reference's native component analogue); falls back to Python
-  if the shared library is absent.
+  ctypes (the reference's native component analogue), built on demand
+  from ``native/*.cpp``; :func:`make_allocator` falls back to Python only
+  where it can be neither found nor built.
 """
 
 from __future__ import annotations
@@ -78,18 +79,57 @@ class PyHostPortAllocator:
         return base in self._used
 
 
-_NATIVE_LIB_NAMES = ("libtpujob_native.so",)
+_NATIVE_LIB_NAME = "libtpujob_native.so"
+
+
+def _build_native_lib(src_dir: str, out: str) -> bool:
+    """Build ``native/*.cpp`` with the repo's own Makefile into a private
+    directory, then rename the library into place: a reader (or another
+    builder — pytest-xdist workers all import at once) sees either no
+    file or a whole one, never a partial write."""
+    import shutil
+    import subprocess
+    import tempfile
+
+    from paddle_operator_tpu.utils.observability import get_logger
+
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    tmp = tempfile.mkdtemp(prefix=".build-", dir=os.path.dirname(out))
+    try:
+        proc = subprocess.run(["make", "-C", src_dir, f"BUILD={tmp}"],
+                              capture_output=True, text=True)
+        if proc.returncode == 0:
+            os.replace(os.path.join(tmp, _NATIVE_LIB_NAME), out)
+            return True
+        why = f"rc={proc.returncode}: {proc.stderr.strip()[-400:]}"
+    except OSError as e:       # no make on this machine
+        why = str(e)
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+    get_logger().warning(f"native library build failed ({why})")
+    return False
 
 
 def _find_native_lib() -> Optional[str]:
+    """The native library's path: the image's prebuilt copy
+    (``paddle_operator_tpu/_native``, see Dockerfile), else
+    ``native/build`` — built on demand from ``native/*.cpp`` when absent
+    or older than a source, since ``native/build`` is not in git."""
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for root in (os.path.join(here, "..", "native", "build"),
-                 os.path.join(here, "_native")):
-        for name in _NATIVE_LIB_NAMES:
-            p = os.path.abspath(os.path.join(root, name))
-            if os.path.exists(p):
-                return p
-    return None
+    packaged = os.path.join(here, "_native", _NATIVE_LIB_NAME)
+    if os.path.exists(packaged):
+        return packaged
+    src_dir = os.path.abspath(os.path.join(here, "..", "native"))
+    out = os.path.join(src_dir, "build", _NATIVE_LIB_NAME)
+    srcs = [os.path.join(src_dir, f) for f in ("hostport.cpp", "dataio.cpp",
+                                               "Makefile")]
+    if not all(os.path.exists(f) for f in srcs):
+        return out if os.path.exists(out) else None
+    stale = (not os.path.exists(out)
+             or os.path.getmtime(out) < max(map(os.path.getmtime, srcs)))
+    if stale and not _build_native_lib(src_dir, out):
+        return None
+    return out
 
 
 class NativeHostPortAllocator:
@@ -101,7 +141,8 @@ class NativeHostPortAllocator:
                  lib_path: Optional[str] = None) -> None:
         path = lib_path or _find_native_lib()
         if path is None:
-            raise FileNotFoundError("native allocator library not built")
+            raise FileNotFoundError(
+                "native allocator library not found and could not be built")
         lib = ctypes.CDLL(path)
         lib.hp_new.restype = ctypes.c_void_p
         lib.hp_new.argtypes = [ctypes.c_int, ctypes.c_int, ctypes.c_int]
@@ -144,7 +185,8 @@ class NativeHostPortAllocator:
 def make_allocator(start: int = HOST_PORT_RANGE[0],
                    end: int = HOST_PORT_RANGE[1],
                    block: int = PORT_NUM):
-    """Prefer the native allocator, fall back to Python."""
+    """Prefer the native allocator; fall back to Python where the library
+    can be neither found nor built (the failed build is logged)."""
     try:
         return NativeHostPortAllocator(start, end, block)
     except (FileNotFoundError, OSError):

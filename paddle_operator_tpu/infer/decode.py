@@ -72,6 +72,18 @@ def _use_sharded_kernel(cfg: LlamaConfig, mesh, attn_impl: str) -> bool:
             and cfg.decode_tp_compatible(mesh_tp(mesh)))
 
 
+def resolve_decode_attn(cfg: LlamaConfig, mesh) -> Tuple[str, bool]:
+    """``(attn_impl, use_sharded)`` as every decode forward on `mesh`
+    resolves them — and as the server's start-up line reports them: the
+    config's impl, except that a tp>1 mesh the kernel cannot split in
+    whole GQA groups serves through the GSPMD einsum."""
+    attn_impl = cfg.resolved_decode_attn()
+    use_sharded = _use_sharded_kernel(cfg, mesh, attn_impl)
+    if mesh_tp(mesh) > 1 and not use_sharded:
+        attn_impl = "xla"
+    return attn_impl, use_sharded
+
+
 def alloc_kv_buffer(cfg: LlamaConfig, shape, mesh) -> jax.Array:
     """One KV cache buffer (decode scalar cache or ring cache — they
     differ only in the batch/lane dim), sharded over the kv-head axis
@@ -79,14 +91,16 @@ def alloc_kv_buffer(cfg: LlamaConfig, shape, mesh) -> jax.Array:
     the wk/wv shard that fills it.  Indivisible kv heads leave the
     buffer replicated — the GSPMD einsum fallback handles it.  Callers
     allocate k and v separately: the jitted steps donate them as
-    distinct buffers."""
-    buf = jnp.zeros(shape, cfg.dtype)
+    distinct buffers.  The zeros are born on their shards: staged whole
+    on the first device and then re-laid, a pool sized to fill the mesh
+    would not fit."""
+    sharding = None
     if (mesh is not None and mesh_tp(mesh) > 1
             and cfg.n_kv_heads % mesh_tp(mesh) == 0):
         from paddle_operator_tpu.parallel.sharding import kv_cache_sharding
 
-        buf = jax.device_put(buf, kv_cache_sharding(mesh))
-    return buf
+        sharding = kv_cache_sharding(mesh)
+    return jnp.zeros(shape, cfg.dtype, device=sharding)
 
 
 def _rms(x: jax.Array, scale: jax.Array, eps: float, dtype) -> jax.Array:
@@ -326,11 +340,7 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
 
-    attn_impl = cfg.resolved_decode_attn()
-    tp = mesh_tp(mesh)
-    use_sharded = _use_sharded_kernel(cfg, mesh, attn_impl)
-    if tp > 1 and not use_sharded:
-        attn_impl = "xla"   # kernel can't split whole GQA groups: GSPMD
+    attn_impl, use_sharded = resolve_decode_attn(cfg, mesh)
     if tokens.shape[1] == 1 and use_sharded:
         # TP-sharded kernel: same stacked-cache scan as below, but the
         # attention + output projection run inside one manual region per

@@ -1054,8 +1054,9 @@ def _alloc_pool_buf(cfg: LlamaConfig, shape, dtype, mesh,
     """A pool-side buffer of arbitrary rank/dtype sharded over its
     kv-head axis under a serving mesh (the generalization of
     decode.alloc_kv_buffer the int8 codes/scales/tails need — their
-    ranks and dtypes differ from the bf16 pool's)."""
-    buf = jnp.zeros(shape, dtype)
+    ranks and dtypes differ from the bf16 pool's).  Like it, born on
+    its shards, never staged whole on the first device."""
+    sharding = None
     if (mesh is not None and D.mesh_tp(mesh) > 1
             and cfg.n_kv_heads % D.mesh_tp(mesh) == 0):
         from jax.sharding import NamedSharding
@@ -1064,9 +1065,8 @@ def _alloc_pool_buf(cfg: LlamaConfig, shape, dtype, mesh,
 
         spec = tuple("kv_heads" if i == head_axis else None
                      for i in range(len(shape)))
-        buf = jax.device_put(
-            buf, NamedSharding(mesh, logical_to_mesh(spec, None, mesh)))
-    return buf
+        sharding = NamedSharding(mesh, logical_to_mesh(spec, None, mesh))
+    return jnp.zeros(shape, dtype, device=sharding)
 
 
 def init_paged_cache(cfg: LlamaConfig, slots: int, total_blocks: int,
@@ -1317,10 +1317,7 @@ def paged_ring_forward(cfg: LlamaConfig, params: Dict[str, Any],
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
 
-    attn_impl = cfg.resolved_decode_attn()
-    use_sharded = D._use_sharded_kernel(cfg, mesh, attn_impl)
-    if D.mesh_tp(mesh) > 1 and not use_sharded:
-        attn_impl = "xla"
+    attn_impl, use_sharded = D.resolve_decode_attn(cfg, mesh)
     if quant:
         return _paged_ring_forward_quant(
             cfg, params, x, cache, table, pos, block_size, cos, sin,

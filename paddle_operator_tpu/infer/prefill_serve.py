@@ -977,33 +977,25 @@ def main() -> int:
     exit EXIT_PREEMPTED."""
     import os
 
-    import jax
-    import jax.numpy as jnp
-
     from paddle_operator_tpu.api.types import EXIT_PREEMPTED
     from paddle_operator_tpu.ft.preemption import PreemptionWatcher
-    from paddle_operator_tpu.infer.quant import serving_params
+    from paddle_operator_tpu.infer.serve import load_serving_params
     from paddle_operator_tpu.launch.launcher import JobEnv
-    from paddle_operator_tpu.models.llama import make_model
-    from paddle_operator_tpu.train import trainer as T
-    from paddle_operator_tpu.train.checkpoint import (
-        CheckpointManager,
-        resume_or_init,
-    )
+    from paddle_operator_tpu.models.llama import CONFIGS
+    from paddle_operator_tpu.train.checkpoint import CheckpointManager
+    from paddle_operator_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     env = JobEnv.from_env()
-    model, cfg = make_model(os.environ.get("MODEL_PRESET", "7b"))
-    opt = T.make_optimizer()
+    cfg = CONFIGS[os.environ.get("MODEL_PRESET", "7b")]
+    mesh = None
+    tp = int(os.environ.get("SERVE_TP", "1"))
+    if tp > 1:
+        from paddle_operator_tpu.parallel.mesh import make_serving_mesh
 
-    def init():
-        params = model.init(jax.random.PRNGKey(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-        return T.TrainState(step=jnp.zeros((), jnp.int32),
-                            params=params, opt_state=opt.init(params))
-
-    ckpt = CheckpointManager()
-    state, resumed = resume_or_init(ckpt, init)
-    params = serving_params(state.params, cfg.dtype)
+        mesh = make_serving_mesh(tp)
+    params, resumed = load_serving_params(cfg, CheckpointManager(),
+                                          mesh=mesh)
     # SERVE_WEIGHT_QUANT=int8|int4: match the decode fleet's weight
     # quantization — handed-off KV is a function of the weights that
     # produced it, so a mixed fleet breaks token-identity with the
@@ -1018,12 +1010,6 @@ def main() -> int:
         )
 
         params = quantize_params(params, cfg, mode=wq, skip=SERVING_SKIP)
-    mesh = None
-    tp = int(os.environ.get("SERVE_TP", "1"))
-    if tp > 1:
-        from paddle_operator_tpu.parallel.mesh import make_serving_mesh
-
-        mesh = make_serving_mesh(tp)
     max_len = int(os.environ.get("SERVE_MAX_LEN", "0")) \
         or cfg.max_seq_len
     kv_quant = os.environ.get("SERVE_KV_QUANT", "none")

@@ -220,34 +220,65 @@ class ContinuousGenerator:
         self.batcher.close()
 
 
-def _load_swap_checkpoint(path: str, cfg) -> Any:
-    """Restore a TRAINING checkpoint's params for serving — the same
-    restore + dtype-convert the entrypoint runs at boot — from the
-    ``/v1/swap`` handler thread (ISSUE 19): the expensive half of a
-    live swap happens HERE, off the ring loop, while the old
-    generation keeps serving.  Raises when nothing restores (a swap
-    must never silently flip to fresh-init weights)."""
+def load_serving_params(cfg: LlamaConfig, ckpt, *, seed: int = 0,
+                        mesh=None):
+    """``(params, resumed)`` for a server: what is served, in the served
+    dtype and layout, and nothing else on the device.
+
+    With a checkpoint (`ckpt` a CheckpointManager; None means there is
+    none to look for), only the ``params`` subtree of the saved
+    TrainState is read (``CheckpointManager.restore_params``), cast to
+    ``cfg.dtype`` on the way in and placed straight onto the serving
+    layout — so the optimizer the job trained with (f32 or int8 moments)
+    is irrelevant, and a tp>1 mesh never sees the whole tree on its
+    first device.  Without one (smoke mode) the same tree is initialised
+    from `seed` under one jit with the cast inside.  No TrainState, no
+    optimizer state, no second copy."""
     from paddle_operator_tpu.infer.quant import serving_params
-    from paddle_operator_tpu.models.llama import Llama
-    from paddle_operator_tpu.train import trainer as T
-    from paddle_operator_tpu.train.checkpoint import (
-        CheckpointManager,
-        resume_or_init,
-    )
+    from paddle_operator_tpu.models.llama import Llama, partition_patterns
+    from paddle_operator_tpu.train.checkpoint import restore_newest
 
     model = Llama(cfg)
-    opt = T.make_optimizer()
 
-    def init():
-        p = model.init(jax.random.PRNGKey(0),
-                       jnp.zeros((1, 8), jnp.int32))["params"]
-        return T.TrainState(step=jnp.zeros((), jnp.int32), params=p,
-                            opt_state=opt.init(p))
+    def init(rng):
+        return serving_params(
+            model.init(rng, jnp.zeros((1, 8), jnp.int32))["params"],
+            cfg.dtype)
 
-    state, resumed = resume_or_init(CheckpointManager(path), init)
-    if not resumed:
+    shapes = jax.eval_shape(init, jax.random.PRNGKey(seed))
+    if mesh is not None and D.mesh_tp(mesh) > 1:
+        from paddle_operator_tpu.parallel.sharding import tree_shardings
+
+        shardings = tree_shardings(shapes, mesh, partition_patterns(cfg),
+                                   replicate_indivisible=True)
+    else:
+        one = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+        shardings = jax.tree.map(lambda _: one, shapes)
+    if (ckpt is not None and ckpt.enabled
+            and ckpt.latest_step() is not None):
+        like = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                               sharding=sh),
+            shapes, shardings)
+        return restore_newest(
+            ckpt, lambda step: ckpt.restore_params(like, step=step)), True
+    return jax.jit(init, out_shardings=shardings)(
+        jax.random.PRNGKey(seed)), False
+
+
+def _load_swap_checkpoint(path: str, cfg) -> Any:
+    """Restore a TRAINING checkpoint's params for serving — the same
+    restore the entrypoint runs at boot — from the ``/v1/swap`` handler
+    thread (ISSUE 19): the expensive half of a live swap happens HERE,
+    off the ring loop, while the old generation keeps serving.  Raises
+    when nothing restores (a swap must never silently flip to
+    fresh-init weights)."""
+    from paddle_operator_tpu.train.checkpoint import CheckpointManager
+
+    ckpt = CheckpointManager(path)
+    if ckpt.latest_step() is None:
         raise ValueError(f"no checkpoint restorable at {path}")
-    return serving_params(state.params, cfg.dtype)
+    return load_serving_params(cfg, ckpt)[0]
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -959,32 +990,26 @@ def main() -> int:
     import os
 
     from paddle_operator_tpu.launch.launcher import JobEnv
-    from paddle_operator_tpu.models.llama import Llama, make_model
-    from paddle_operator_tpu.train import trainer as T
-    from paddle_operator_tpu.train.checkpoint import (
-        CheckpointManager,
-        resume_or_init,
-    )
+    from paddle_operator_tpu.models.llama import CONFIGS
+    from paddle_operator_tpu.train.checkpoint import CheckpointManager
+    from paddle_operator_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     env = JobEnv.from_env()
-    model, cfg = make_model(os.environ.get("MODEL_PRESET", "7b"))
-    opt = T.make_optimizer()
+    cfg = CONFIGS[os.environ.get("MODEL_PRESET", "7b")]
+    # SERVE_TP=n: tensor-parallel serving over the pod's first n chips
+    # (weights a single chip cannot hold — the 7B-on-v5e case).  The
+    # mesh carries only the tp axis; DP is separate server replicas.
+    mesh = None
+    tp = int(os.environ.get("SERVE_TP", "1"))
+    if tp > 1:
+        from paddle_operator_tpu.parallel.mesh import make_serving_mesh
 
-    def init():
-        params = model.init(jax.random.PRNGKey(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-        # full TrainState structure so a TRAINING checkpoint restores
-        # cleanly; only params are served
-        return T.TrainState(step=jnp.zeros((), jnp.int32), params=params,
-                            opt_state=opt.init(params))
-
-    ckpt = CheckpointManager()   # TPUJOB_CHECKPOINT_PATH
-    state, resumed = resume_or_init(ckpt, init)
-    from paddle_operator_tpu.infer.quant import serving_params
-
-    # training checkpoints hold f32 master params; serving them unconverted
-    # would stream double the weight bytes every decode step
-    params = serving_params(state.params, cfg.dtype)
+        mesh = make_serving_mesh(tp)
+    # TPUJOB_CHECKPOINT_PATH; restored (or smoke-initialised) in the
+    # served dtype, straight onto the serving layout
+    params, resumed = load_serving_params(cfg, CheckpointManager(),
+                                          mesh=mesh)
     if os.environ.get("QUANTIZE", "") == "int8":
         from paddle_operator_tpu.infer.quant import quantize_params
 
@@ -1222,20 +1247,10 @@ def main() -> int:
             )
 
             check_draft_compat(cfg, dcfg)
-            dmodel = Llama(dcfg)
-
-            def dinit():
-                dp = dmodel.init(jax.random.PRNGKey(1),
-                                 jnp.zeros((1, 8), jnp.int32))["params"]
-                return T.TrainState(step=jnp.zeros((), jnp.int32),
-                                    params=dp, opt_state=opt.init(dp))
-
             dpath = os.environ.get("TPUJOB_DRAFT_CHECKPOINT_PATH")
-            if dpath:
-                dstate, _ = resume_or_init(CheckpointManager(dpath), dinit)
-            else:
-                dstate = dinit()
-            dparams = serving_params(dstate.params, dcfg.dtype)
+            dparams, _ = load_serving_params(
+                dcfg, CheckpointManager(dpath) if dpath else None,
+                seed=1, mesh=mesh)
             if swap_base is not None:
                 swap_base["draft_params"] = jax.device_get(dparams)
                 swap_base["draft_quant"] = (
@@ -1257,17 +1272,14 @@ def main() -> int:
                                           skip=SERVING_SKIP)
             ring_kw.update(
                 draft_params=dparams, draft_cfg=dcfg, spec_k=spec_k)
-    # SERVE_TP=n: tensor-parallel serving over the pod's first n chips
-    # (weights a single chip cannot hold — the 7B-on-v5e case).  The
-    # mesh carries only the tp axis; DP is separate server replicas.
-    mesh = None
-    tp = int(os.environ.get("SERVE_TP", "1"))
-    if tp > 1:
-        from paddle_operator_tpu.parallel.mesh import make_serving_mesh
-
-        mesh = make_serving_mesh(tp)
+    # the decode path this process will trace and the device it runs on:
+    # a deployment that landed on the wrong one says so in its first line
+    decode_attn, _ = D.resolve_decode_attn(cfg, mesh)
+    dev = jax.devices()[0]
     print(f"serving {os.environ.get('MODEL_PRESET', '7b')} "
-          f"(resumed={resumed}, "
+          f"(platform={dev.platform}, device_kind={dev.device_kind!r}, "
+          f"devices={len(jax.devices())}, decode_attn={decode_attn}, "
+          f"resumed={resumed}, "
           f"quantize={os.environ.get('QUANTIZE', 'off')}, "
           f"weight_quant={wq}, "
           f"draft_quant={os.environ.get('SERVE_DRAFT_QUANT', 'none') or 'none'}, "
@@ -1287,6 +1299,11 @@ def main() -> int:
                       **ring_kw)
     # the /v1/swap handler reaches the retained base via self.server
     srv.swap_base = swap_base if continuous else None
+    # weights + KV pool are resident now; under SERVE_TP no device may
+    # hold more than its shard (memory_stats is None on the CPU backend)
+    in_use = [(d.memory_stats() or {}).get("bytes_in_use")
+              for d in (mesh.devices.flat if mesh is not None else [dev])]
+    print(f"serving ready: device_bytes_in_use={in_use}", flush=True)
     # SIGTERM drain (docs/fault-tolerance.md, serving pods): the SAME
     # PreemptionWatcher contract the trainer uses — stop admissions
     # (503 + Retry-After), finish in-flight lanes within the drain

@@ -146,9 +146,9 @@ def initialize(env: Optional[JobEnv] = None, *, force: bool = False) -> JobEnv:
         )
         # Export the slice-local host list for the libtpu runtime.  Set
         # unconditionally: the job contract is authoritative for operator-
-        # managed pods — a default leaked by a base image or site hook
-        # (e.g. TPU_WORKER_HOSTNAMES=localhost) would silently break
-        # multi-host topology discovery.
+        # managed pods — a default leaked by a base image (e.g.
+        # TPU_WORKER_HOSTNAMES=localhost) would silently break multi-host
+        # topology discovery.
         hosts = env.slice_local_hosts()
         if hosts:
             os.environ["TPU_WORKER_HOSTNAMES"] = ",".join(hosts)

@@ -168,6 +168,12 @@ class LlamaConfig:
 CONFIGS = {
     "tiny": LlamaConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4,
                         n_kv_heads=2, ffn_dim=128, max_seq_len=128),
+    # tiny in float32: what the real serving CLI can run on the CPU
+    # backend — XLA:CPU (jaxlib 0.9.0) refuses the bf16 x bf16 -> f32
+    # probs @ V dot inside the decode layer scan
+    "tiny-f32": LlamaConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4,
+                            n_kv_heads=2, ffn_dim=128, max_seq_len=128,
+                            dtype=jnp.float32),
     "tiny-moe": LlamaConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4,
                             n_kv_heads=2, ffn_dim=128, max_seq_len=128,
                             n_experts=4),
@@ -234,9 +240,12 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
 
 class Attention(nn.Module):
     cfg: LlamaConfig
-    # When set (and its cp axis > 1), attention runs as ring attention over
-    # the cp mesh axis — sequence sharded, K/V rotating on ICI
-    # (parallel/ring_attention.py).  None => single-sequence attention.
+    # The job mesh the activations are sharded over; None => one device.
+    # With cp > 1 attention runs as ring attention over the cp axis —
+    # sequence sharded, K/V rotating on ICI (parallel/ring_attention.py);
+    # otherwise the flash kernel enters the mesh through shard_map over
+    # the batch and head axes (ops/attention.py) — a Mosaic kernel cannot
+    # be partitioned by GSPMD, so a multi-chip TPU job MUST pass it.
     mesh: Optional[Any] = None
 
     @nn.compact
@@ -281,7 +290,8 @@ class Attention(nn.Module):
                 out = make_ring_attention_fn(
                     self.mesh, causal=True)(q, k, v, segment_ids)
         else:
-            out = attention(q, k, v, causal=True, segment_ids=segment_ids)
+            out = attention(q, k, v, causal=True, segment_ids=segment_ids,
+                            mesh=self.mesh)
         # Tag for remat_policy="save_attn": under that policy the flash
         # kernel is not re-run in the backward pass.  Under the default
         # full-remat policy the tag is a no-op and attention recomputes —
@@ -421,7 +431,7 @@ def lm_head_module(cfg: LlamaConfig, name: Optional[str] = None) -> nn.DenseGene
 
 class Llama(nn.Module):
     cfg: LlamaConfig
-    mesh: Optional[Any] = None   # enables ring attention when cp > 1
+    mesh: Optional[Any] = None   # the job mesh (see Attention.mesh)
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
@@ -496,7 +506,8 @@ def partition_patterns(cfg: LlamaConfig):
 
 
 def make_model(preset: str = "tiny", mesh=None, **overrides) -> Tuple[Llama, LlamaConfig]:
-    """`mesh` activates context parallelism (ring attention) when its cp
-    axis is > 1; otherwise it is inert."""
+    """`mesh` is the job mesh (see ``Attention.mesh``): context
+    parallelism when its cp axis is > 1, and the flash kernel's way onto
+    a multi-chip TPU mesh."""
     cfg = dataclasses.replace(CONFIGS[preset], **overrides)
     return Llama(cfg, mesh), cfg

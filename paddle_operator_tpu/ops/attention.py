@@ -1,12 +1,13 @@
 """Attention ops.
 
-The single entry point :func:`attention` dispatches to the fastest available
-implementation:
+The single entry point :func:`attention` picks the implementation from the
+backend and the shapes, before the call:
 
-- TPU: the pallas flash-attention kernel (ops/pallas_attention.py) — tiled
-  online-softmax, O(S) memory, MXU-shaped blocks.
-- elsewhere (CPU tests, dryrun): a reference XLA implementation with f32
-  softmax accumulation.
+- TPU, shapes the kernel tiles: the pallas flash-attention kernel
+  (ops/pallas_attention.py) — tiled online-softmax, O(S) memory,
+  MXU-shaped blocks.
+- elsewhere (CPU tests, dryrun, shapes that do not tile): a reference XLA
+  implementation with f32 softmax accumulation.
 
 Shapes follow the [batch, seq, heads, head_dim] convention throughout the
 framework.  GQA is handled here (kv heads repeated to query heads) so model
@@ -19,6 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 def _repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
@@ -52,20 +54,90 @@ def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def _flash_axes(mesh, n_heads: int, n_kv_heads: int):
+    """``(mesh_or_None, manual_axes, batch_axes, head_axis)`` for running
+    the flash kernel on `mesh`, or None where the heads cannot split over
+    ``tp`` in whole GQA groups.  Batch rides (dp, fsdp) and heads ride tp
+    — the shardings q/k/v already carry out of the projections.  The
+    region is manual over EVERY axis not already manual around it (inside
+    the pipeline's pp region the ambient mesh is inherited): with any
+    axis left to GSPMD, even one of size 1, XLA refuses the kernel."""
+    from paddle_operator_tpu.parallel.mesh import (
+        DATA_AXES,
+        resolve_shard_map_mesh,
+    )
+
+    use_mesh, sizes = resolve_shard_map_mesh(mesh)
+    tp = sizes.get("tp", 1)
+    if n_heads % tp or n_kv_heads % tp:
+        return None
+    manual = frozenset(sizes)
+    if use_mesh is None:        # nested: the ambient mesh says what is taken
+        manual -= set(jax.sharding.get_abstract_mesh().manual_axes)
+    batch_axes = tuple(a for a in DATA_AXES if sizes.get(a, 1) > 1)
+    return use_mesh, manual, batch_axes or None, "tp" if tp > 1 else None
+
+
+def sharded_flash_attention(mesh, q: jax.Array, k: jax.Array, v: jax.Array,
+                            *, causal: bool = True,
+                            segment_ids: Optional[jax.Array] = None,
+                            interpret: bool = False) -> jax.Array:
+    """The flash kernel on a multi-device mesh.  A Mosaic kernel cannot be
+    partitioned by GSPMD ("wrap the call in a shard_map"), and attention
+    needs no cross-shard term over batch or heads: each shard runs the
+    kernel on its own batch rows and whole GQA groups."""
+    from paddle_operator_tpu.ops.pallas_attention import flash_attention
+
+    axes = _flash_axes(mesh, q.shape[2], k.shape[2])
+    if axes is None:
+        raise NotImplementedError(
+            f"flash attention cannot split heads {q.shape[2]}/{k.shape[2]} "
+            "over tp in whole GQA groups")
+    use_mesh, manual, batch_axes, head_axis = axes
+    spec = P(batch_axes, None, head_axis, None)
+    args, in_specs = (q, k, v), (spec, spec, spec)
+    if segment_ids is not None:
+        args, in_specs = args + (segment_ids,), in_specs + (
+            P(batch_axes, None),)
+
+    def local(q, k, v, seg=None):
+        return flash_attention(q, k, v, causal=causal, segment_ids=seg,
+                               interpret=interpret)
+
+    return jax.shard_map(local, mesh=use_mesh, in_specs=in_specs,
+                         out_specs=spec, axis_names=manual,
+                         check_vma=False)(*args)
+
+
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               *, causal: bool = True,
               segment_ids: Optional[jax.Array] = None,
-              use_pallas: Optional[bool] = None) -> jax.Array:
-    """Dispatching attention.  [B, S, H, D] inputs, head-count ratio = GQA."""
-    if use_pallas is None:
-        use_pallas = jax.devices()[0].platform == "tpu"
-    if use_pallas:
-        try:
-            from paddle_operator_tpu.ops.pallas_attention import flash_attention
+              use_pallas: Optional[bool] = None,
+              mesh=None) -> jax.Array:
+    """Dispatching attention.  [B, S, H, D] inputs, head-count ratio = GQA.
 
-            return flash_attention(q, k, v, causal=causal,
+    ``use_pallas=None`` decides before the call, from the backend and the
+    shapes: the flash kernel on TPU wherever it tiles
+    (:func:`pallas_attention.flash_tiles`) and, on a `mesh`, the heads
+    split over tp — the reference elsewhere.  ``use_pallas=True`` asks for
+    the kernel: shapes it cannot take raise instead of quietly running
+    the O(S^2) reference.  `mesh` is the job mesh the arrays are sharded
+    over (None: one device)."""
+    from paddle_operator_tpu.ops.pallas_attention import (
+        flash_attention,
+        flash_tiles,
+    )
+
+    if use_pallas is None:
+        use_pallas = (
+            jax.default_backend() == "tpu"
+            and flash_tiles(q.shape, k.shape, segment_ids)
+            and (mesh is None
+                 or _flash_axes(mesh, q.shape[2], k.shape[2]) is not None))
+    if not use_pallas:
+        return reference_attention(q, k, v, causal=causal,
                                    segment_ids=segment_ids)
-        except (ImportError, NotImplementedError):
-            pass
-    return reference_attention(q, k, v, causal=causal,
-                               segment_ids=segment_ids)
+    if mesh is not None:
+        return sharded_flash_attention(mesh, q, k, v, causal=causal,
+                                       segment_ids=segment_ids)
+    return flash_attention(q, k, v, causal=causal, segment_ids=segment_ids)

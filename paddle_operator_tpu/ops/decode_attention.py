@@ -296,24 +296,26 @@ def _paged_kernel_quant(len_ref, tbl_ref, *refs, scale: float,
     """Paged kernel over the INT8 pool with the dequant fused into the
     cell (SERVE_KV_QUANT=int8, infer/paged.py): the K/V tiles stream
     from HBM as int8 codes (half the bytes of the bf16 kernel — the
-    capacity story in the module header), the per-(block, kv-head) f32
-    scales ride the SAME table-driven index map as their codes, and the
-    lane's bf16 staging tail (the one partial write block, quantized
-    only on completion) substitutes for the cell at the write frontier
-    — so full blocks are read quantized and the in-progress block is
-    read exact, matching the einsum fallback's view
-    (infer/paged.py ``_gather_lane_view_quant``) element for element.
-    Compute after dequant is byte-for-byte :func:`_cell_softmax`."""
+    capacity story in the module header), the lane's per-(block,
+    kv-head) f32 scales sit in SMEM (gathered through the block table
+    by the wrapper — a ``[1, hkv]`` VMEM window per pool block is a
+    shape Mosaic's (8, 128) tiling refuses), and the lane's bf16
+    staging tail (the one partial write block, quantized only on
+    completion) substitutes for the cell at the write frontier — so
+    full blocks are read quantized and the in-progress block is read
+    exact, matching the einsum fallback's view (infer/paged.py
+    ``_gather_lane_view_quant``) element for element.  Either way the
+    cell's tile lands in a compute-dtype VMEM scratch; compute after
+    that is byte-for-byte :func:`_cell_softmax`."""
     del tbl_ref
     if stacked:
         (_lay, qt_ref, k_ref, v_ref, ks_ref, vs_ref, kt_ref, vt_ref,
-         o_ref, acc_ref, m_ref, l_ref) = refs
+         o_ref, acc_ref, m_ref, l_ref, kd_ref, vd_ref) = refs
         k_ref, v_ref = k_ref.at[0], v_ref.at[0]
-        ks_ref, vs_ref = ks_ref.at[0], vs_ref.at[0]
         kt_ref, vt_ref = kt_ref.at[0], vt_ref.at[0]
     else:
         (qt_ref, k_ref, v_ref, ks_ref, vs_ref, kt_ref, vt_ref,
-         o_ref, acc_ref, m_ref, l_ref) = refs
+         o_ref, acc_ref, m_ref, l_ref, kd_ref, vd_ref) = refs
     b = pl.program_id(0)
     ik, nk = pl.program_id(1), pl.num_programs(1)
     length = len_ref[b]
@@ -328,25 +330,28 @@ def _paged_kernel_quant(len_ref, tbl_ref, *refs, scale: float,
 
     @pl.when(ik * block_k < length)
     def _compute():
-        qt = qt_ref[0]                               # [d, hq]
-        dtype = qt.dtype
         # the lane's write-frontier block: its rows live in the bf16
         # staging tail (quantize-on-completion), not the int8 pool
         wb = jnp.maximum(length - 1, 0) // block_k
-        # per-row scale: row r of the collapsed [hkv*bk, d] tile
-        # belongs to head r // block_k
-        sk = jnp.broadcast_to(ks_ref[0].reshape(hkv, 1),
-                              (hkv, block_k)).reshape(rows, 1)
-        sv = jnp.broadcast_to(vs_ref[0].reshape(hkv, 1),
-                              (hkv, block_k)).reshape(rows, 1)
-        kq = k_ref[0].reshape(rows, -1).astype(jnp.float32) * sk
-        vq = v_ref[0].reshape(rows, -1).astype(jnp.float32) * sv
-        ktl = kt_ref[0].reshape(rows, -1).astype(jnp.float32)
-        vtl = vt_ref[0].reshape(rows, -1).astype(jnp.float32)
-        k2 = jnp.where(ik == wb, ktl, kq).astype(dtype)
-        v2 = jnp.where(ik == wb, vtl, vq).astype(dtype)
-        _cell_softmax(qt, k2, v2, ik, length, scale, block_k, n_rep,
-                      acc_ref, m_ref, l_ref)
+
+        @pl.when(ik == wb)
+        def _tail():
+            kd_ref[...] = kt_ref[0].astype(kd_ref.dtype)
+            vd_ref[...] = vt_ref[0].astype(vd_ref.dtype)
+
+        @pl.when(ik != wb)
+        def _dequant():
+            # one scalar scale per head: a static unroll of
+            # [block_k, d] tile x SMEM scalar multiplies
+            for h in range(hkv):
+                kd_ref[h] = (k_ref[0, h].astype(jnp.float32)
+                             * ks_ref[0, ik, h]).astype(kd_ref.dtype)
+                vd_ref[h] = (v_ref[0, h].astype(jnp.float32)
+                             * vs_ref[0, ik, h]).astype(vd_ref.dtype)
+
+        _cell_softmax(qt_ref[0], kd_ref[...].reshape(rows, -1),
+                      vd_ref[...].reshape(rows, -1), ik, length, scale,
+                      block_k, n_rep, acc_ref, m_ref, l_ref)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -389,13 +394,14 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     ``k_scale``/``v_scale``/``k_tail``/``v_tail`` (all four together)
     select the QUANTIZED-pool variant (SERVE_KV_QUANT=int8): pools are
     int8 codes, scales are f32 ``[N, Hkv]`` (or ``[L, N, Hkv]``
-    stacked) riding the same table-driven index map, and the tails are
-    the per-lane bf16 staging blocks ``[lanes+1, Hkv, bs, D]`` (or
-    stacked with L) whose row ``b`` substitutes for lane b's one
-    partial write block — constant-in-ik index map, so Mosaic fetches
-    each lane's tail once and skips the repeat.  Dequant happens in
-    the cell (:func:`_paged_kernel_quant`); HBM streams half the
-    bytes."""
+    stacked), and the tails are the per-lane bf16 staging blocks
+    ``[lanes+1, Hkv, bs, D]`` (or stacked with L) whose row ``b``
+    substitutes for lane b's one partial write block — constant-in-ik
+    index map, so Mosaic fetches each lane's tail once and skips the
+    repeat.  The scales are gathered through the block table here
+    (``[B, M, Hkv]``, a few KB) and each lane's slab rides into SMEM,
+    where the cell reads one scalar per head.  Dequant happens in the
+    cell (:func:`_paged_kernel_quant`); HBM streams half the bytes."""
     b, hq, d = q.shape
     quant = k_scale is not None
     if quant and (v_scale is None or k_tail is None or v_tail is None):
@@ -429,10 +435,6 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
             (1, 1, hkv, block_k, d),
             lambda b, ik, lens, tbl, lay: (lay[0], blk(ik, lens, tbl, b),
                                            0, 0, 0))
-        scale_spec = pl.BlockSpec(
-            (1, 1, hkv),
-            lambda b, ik, lens, tbl, lay: (lay[0], blk(ik, lens, tbl, b),
-                                           0))
         tail_spec = pl.BlockSpec(
             (1, 1, hkv, block_k, d),
             lambda b, ik, lens, tbl, lay: (lay[0], b, 0, 0, 0))
@@ -445,8 +447,6 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         cache_spec = pl.BlockSpec(
             (1, hkv, block_k, d),
             lambda b, ik, lens, tbl: (blk(ik, lens, tbl, b), 0, 0, 0))
-        scale_spec = pl.BlockSpec(
-            (1, hkv), lambda b, ik, lens, tbl: (blk(ik, lens, tbl, b), 0))
         tail_spec = pl.BlockSpec(
             (1, hkv, block_k, d), lambda b, ik, lens, tbl: (b, 0, 0, 0))
         q_spec = pl.BlockSpec((1, d, hq), lambda b, ik, lens, tbl: (b, 0, 0))
@@ -455,29 +455,50 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         num_prefetch, extra = 2, ()
 
     in_specs = [q_spec, cache_spec, cache_spec]
+    scratch_shapes = [
+        pltpu.VMEM((hq, d), jnp.float32),        # acc
+        pltpu.VMEM((hq, 128), jnp.float32),      # m (col 0 live)
+        pltpu.VMEM((hq, 128), jnp.float32),      # l (col 0 live)
+    ]
     quant_operands = ()
     kernel_body = _paged_kernel
+    compiler_params = None
     if quant:
+        def lane_scales(plane):
+            if stacked:
+                plane = jax.lax.dynamic_index_in_dim(
+                    plane, lay[0], 0, keepdims=False)
+            return plane.astype(jnp.float32)[block_table]   # [B, M, Hkv]
+
+        # lane b's whole [M, hkv] slab: constant in ik, fetched once
+        scale_spec = pl.BlockSpec((1, nk, hkv), lambda b, ik, *_: (b, 0, 0),
+                                  memory_space=pltpu.SMEM)
         in_specs += [scale_spec, scale_spec, tail_spec, tail_spec]
-        quant_operands = (k_scale.astype(jnp.float32),
-                          v_scale.astype(jnp.float32), k_tail, v_tail)
+        quant_operands = (lane_scales(k_scale), lane_scales(v_scale),
+                          k_tail, v_tail)
+        # the cell's dequantized (or tail-substituted) K/V tiles
+        scratch_shapes += [pltpu.VMEM((hkv, block_k, d), q.dtype)] * 2
         kernel_body = _paged_kernel_quant
+        # double-buffered code and tail windows plus the two scratch
+        # tiles: 16 MiB at 32 kv heads x 256 rows x 128, which is the
+        # whole default scoped-VMEM budget — ask for what the shapes need
+        tile = hkv * block_k * d
+        need = tile * (4 + 4 * k_tail.dtype.itemsize + 2 * q.dtype.itemsize)
+        compiler_params = pltpu.CompilerParams(
+            vmem_limit_bytes=need + (16 << 20))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_prefetch,
         grid=(b, nk),
         in_specs=in_specs,
         out_specs=out_spec,
-        scratch_shapes=[
-            pltpu.VMEM((hq, d), jnp.float32),        # acc
-            pltpu.VMEM((hq, 128), jnp.float32),      # m (col 0 live)
-            pltpu.VMEM((hq, 128), jnp.float32),      # l (col 0 live)
-        ],
+        scratch_shapes=scratch_shapes,
     )
     out = pl.pallas_call(
         functools.partial(kernel_body, scale=scale, block_k=block_k,
                           n_rep=n_rep, stacked=stacked),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        compiler_params=compiler_params,
         interpret=interpret,
     )(lengths, block_table, *extra, qt, k_pool, v_pool, *quant_operands)
     return out
@@ -506,10 +527,7 @@ def sharded_paged_decode_attention(mesh, q: jax.Array, k_pool: jax.Array,
     scales, ``k_tail``/``v_tail`` per-lane staging blocks) shard over
     the SAME kv-head axis as their codes — every shard dequantizes
     purely locally, and the psum is unchanged."""
-    from paddle_operator_tpu.parallel.mesh import (
-        compat_shard_map,
-        resolve_shard_map_mesh,
-    )
+    from paddle_operator_tpu.parallel.mesh import resolve_shard_map_mesh
     from jax.sharding import PartitionSpec as P
 
     use_mesh, sizes = resolve_shard_map_mesh(mesh)
@@ -560,7 +578,7 @@ def sharded_paged_decode_attention(mesh, q: jax.Array, k_pool: jax.Array,
     if stacked:
         in_specs += (P(),)
         args += (layer,)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=use_mesh,
         in_specs=in_specs,
         out_specs=P(None, None),
@@ -593,10 +611,7 @@ def sharded_decode_attention(mesh, q: jax.Array, k_cache: jax.Array,
     GSPMD.  Sharding is by WHOLE GQA groups: Hkv % tp must be 0 (then
     Hq = n_rep * Hkv splits with it) — LlamaConfig.decode_tp_compatible
     gates callers into the GSPMD einsum fallback otherwise."""
-    from paddle_operator_tpu.parallel.mesh import (
-        compat_shard_map,
-        resolve_shard_map_mesh,
-    )
+    from paddle_operator_tpu.parallel.mesh import resolve_shard_map_mesh
     from jax.sharding import PartitionSpec as P
 
     use_mesh, sizes = resolve_shard_map_mesh(mesh)
@@ -627,7 +642,7 @@ def sharded_decode_attention(mesh, q: jax.Array, k_cache: jax.Array,
             o = o @ wo.astype(dtype)
         return jax.lax.psum(o, axis_name)                # [B, E]
 
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body, mesh=use_mesh,
         in_specs=(head_spec, cache_spec, cache_spec, P(), wo_spec)
         + ((P(),) if stacked else ()),

@@ -466,6 +466,21 @@ _flash_seg.defvjp(_flash_seg_fwd, _flash_seg_bwd)
 # ---------------------------------------------------------------------------
 
 
+def flash_tiles(q_shape, k_shape, segment_ids=None,
+                block_q: int = DEFAULT_BLOCK_Q,
+                block_k: int = DEFAULT_BLOCK_K) -> bool:
+    """Whether the kernel can tile these ``[B, S, H, D]`` shapes — the
+    decision :func:`ops.attention.attention` makes before it calls."""
+    b, s, _, d = q_shape
+    sk = k_shape[1]
+    block_q = min(block_q, s)
+    block_k = min(block_k, sk)
+    if (s % block_q or sk % block_k or block_q % 128 or block_k % 128
+            or d not in (64, 128, 256)):
+        return False
+    return segment_ids is None or (segment_ids.shape == (b, s) and s == sk)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     *, causal: bool = True,
                     segment_ids: Optional[jax.Array] = None,
@@ -474,17 +489,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     interpret: bool = False) -> jax.Array:
     """[B, S, H, D] flash attention, optionally with packed-sequence
     ``segment_ids`` [B, S] (cross-document scores masked in-kernel).
-    Falls back (NotImplementedError) when the shape doesn't tile — the
-    dispatcher in ops.attention catches it and uses the reference path."""
+    Shapes that do not tile (:func:`flash_tiles`) raise
+    NotImplementedError: a caller that may meet them asks first."""
     b, s, hq, d = q.shape
     sk = k.shape[1]
+    if not flash_tiles(q.shape, k.shape, segment_ids, block_q, block_k):
+        raise NotImplementedError(
+            f"flash_attention cannot tile q{tuple(q.shape)} k{tuple(k.shape)}"
+            f" segment_ids="
+            f"{None if segment_ids is None else tuple(segment_ids.shape)}: "
+            "needs seq lengths in multiples of a >=128 block, head_dim in "
+            "(64, 128, 256), and segment_ids [B, S] with Sq == Sk")
     block_q = min(block_q, s)
     block_k = min(block_k, sk)
-    if (s % block_q or sk % block_k or block_q % 128 or block_k % 128
-            or d not in (64, 128, 256)):
-        raise NotImplementedError("shape does not tile")
-    if segment_ids is not None and (segment_ids.shape != (b, s) or s != sk):
-        raise NotImplementedError("segment_ids shape -> reference path")
     n_rep = hq // k.shape[2]
 
     qt = q.transpose(0, 2, 1, 3)

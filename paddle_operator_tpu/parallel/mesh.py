@@ -78,10 +78,8 @@ def make_serving_mesh(tp: int,
     """A single-axis ``("tp",)`` mesh for the serving path (infer/).
 
     Serving shards ONE way — tensor parallel over heads/ffn/vocab, the
-    Megatron recipe — so its mesh carries only the ``tp`` axis: the
-    decode kernel's shard_map is then full-manual, which every jax
-    version lowers (genuinely partial-manual regions CHECK-fail the old
-    partitioner, see :func:`compat_shard_map`).  Data parallelism in
+    Megatron recipe — so its mesh carries only the ``tp`` axis and the
+    decode kernel's shard_map is full-manual.  Data parallelism in
     serving is separate server replicas, not a mesh axis."""
     devs = list(devices) if devices is not None else list(jax.devices())
     if tp < 1 or tp > len(devs):
@@ -101,75 +99,8 @@ def resolve_shard_map_mesh(mesh: Mesh):
     context's abstract mesh must be inherited (pass None) instead of the
     concrete mesh.  Shared by the ring and Ulysses attention wrappers —
     the idiom is subtle enough that two copies would drift.  Returns
-    ``(mesh_or_None, axis_sizes_dict)``.
-
-    On jax versions predating ``jax.sharding.get_abstract_mesh`` there
-    is no ambient-mesh query; the concrete mesh is returned and nested
-    regions rely on it matching the enclosing one."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        ctx = get_abstract()
-        if ctx is not None and not ctx.empty:
-            return None, dict(ctx.shape)
+    ``(mesh_or_None, axis_sizes_dict)``."""
+    ctx = jax.sharding.get_abstract_mesh()
+    if ctx is not None and not ctx.empty:
+        return None, dict(ctx.shape)
     return mesh, dict(mesh.shape)
-
-
-def supports_partial_manual() -> bool:
-    """Whether this jax can lower a PARTIAL-manual shard_map (manual
-    axes alongside live auto axes) — requires the ``jax.shard_map`` API.
-    On older jax the experimental API's partitioner CHECK-fails on such
-    regions, so hybrid meshes (e.g. pp x dp with pp manual) must degrade
-    to single-live-axis meshes; :func:`compat_shard_map` enforces it."""
-    try:
-        from jax import shard_map  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs,
-                     axis_names=None, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions — the ONE import site.
-
-    The repo targets the current API (``mesh=`` possibly None to inherit
-    the ambient mesh, ``axis_names=`` naming the MANUAL axes,
-    ``check_vma=``).  Older jax ships
-    ``jax.experimental.shard_map.shard_map(..., auto=, check_rep=)``:
-    the manual-axis set is expressed as its complement (``auto``) and
-    the ambient-mesh form does not exist, so callers must pass the
-    concrete mesh (``resolve_shard_map_mesh`` already returns it on such
-    versions)."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        if mesh is None:
-            raise RuntimeError(
-                "ambient-mesh shard_map (mesh=None) needs jax.shard_map; "
-                "this jax only has the experimental API — pass the "
-                "concrete mesh")
-        manual = (frozenset(axis_names) if axis_names is not None
-                  else frozenset(mesh.axis_names))
-        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        auto = frozenset(a for a in mesh.axis_names if a not in manual)
-        if any(sizes.get(a, 1) > 1 for a in auto):
-            # The old partitioner CHECK-fails (a process abort, not an
-            # exception) on genuinely partial-manual regions; refuse
-            # loudly instead of taking the interpreter down.
-            raise RuntimeError(
-                "partial-manual shard_map over "
-                f"{sorted(manual)} with live auto axes "
-                f"{sorted(a for a in auto if sizes.get(a, 1) > 1)} is "
-                "unsupported on this jax (no jax.shard_map); use a mesh "
-                "whose non-manual axes are size 1")
-        # every non-manual axis is size 1: full-manual is semantically
-        # identical (a size-1 axis shards nothing)
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=bool(check_vma), auto=frozenset())
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    if axis_names is not None:
-        kwargs["axis_names"] = axis_names
-    return _sm(f, **kwargs)

@@ -161,8 +161,6 @@ def make_pipeline_fn(mesh: Mesh, layer_fn: Callable,
     - the stage block may open a nested manual region over ``cp``
       (ring attention does, via the context mesh).
     """
-    from paddle_operator_tpu.parallel.mesh import compat_shard_map
-
     in_specs = (P(axis_name), P()) + ((P(),) if with_extras else ())
     out_specs = (P(), P()) if has_aux else P()
 
@@ -174,7 +172,7 @@ def make_pipeline_fn(mesh: Mesh, layer_fn: Callable,
         compute_dtype = None
         if x.dtype == jnp.bfloat16:
             compute_dtype, x = x.dtype, x.astype(jnp.float32)
-        fn = compat_shard_map(
+        fn = jax.shard_map(
             functools.partial(pipeline_apply, layer_fn,
                               axis_name=axis_name,
                               num_microbatches=num_microbatches,
@@ -382,8 +380,6 @@ def make_pipeline_1f1b_fn(mesh: Mesh, stage_fn: Callable,
     """Partial-manual shard_map wrapper for :func:`pipeline_1f1b_grads`
     (same composition story as :func:`make_pipeline_fn`: only ``pp`` is
     manual; dp/fsdp/tp/cp stay auto under GSPMD)."""
-    from paddle_operator_tpu.parallel.mesh import compat_shard_map
-
     in_specs = (P(axis_name), P(), P(), P(), P(), P(), P()) \
         + ((P(),) if with_extras else ())
     out_specs = ((P(), P(axis_name), P(), P(), P()) if has_aux
@@ -394,7 +390,7 @@ def make_pipeline_1f1b_fn(mesh: Mesh, stage_fn: Callable,
         compute_dtype = None
         if xm.dtype == jnp.bfloat16:   # boundary dance, see make_pipeline_fn
             compute_dtype, xm = xm.dtype, xm.astype(jnp.float32)
-        fn = compat_shard_map(
+        fn = jax.shard_map(
             functools.partial(pipeline_1f1b_grads, stage_fn, head_loss_fn,
                               axis_name=axis_name,
                               has_aux=has_aux,

@@ -56,8 +56,6 @@ def make_ps_embedding(mesh: Mesh, vocab: int, dim: int,
     init_fn(rng) -> sharded [V, D] table (rows over `axis`);
     lookup_fn(table, ids[B]) -> [B, D] via shard_map+psum.
     """
-    from paddle_operator_tpu.parallel.mesh import compat_shard_map
-
     axis_size = dict(zip(mesh.axis_names, mesh.devices.shape)).get(axis, 1)
     if vocab % axis_size:
         raise ValueError(f"vocab {vocab} not divisible by {axis}={axis_size}")
@@ -71,7 +69,7 @@ def make_ps_embedding(mesh: Mesh, vocab: int, dim: int,
         )
         return init(rng)
 
-    lookup = compat_shard_map(
+    lookup = jax.shard_map(
         functools.partial(sharded_embedding_lookup, axis_name=axis),
         mesh=mesh,
         in_specs=(P(axis, None), P()),

@@ -135,10 +135,7 @@ def make_ring_attention_fn(mesh: Mesh, *, causal: bool = True,
     When the cp axis has size 1 this degrades to plain attention (the ring
     has one hop), so model code can call it unconditionally.
     """
-    from paddle_operator_tpu.parallel.mesh import (
-        compat_shard_map,
-        resolve_shard_map_mesh,
-    )
+    from paddle_operator_tpu.parallel.mesh import resolve_shard_map_mesh
 
     seq_spec = P(None, axis_name)
     use_mesh, sizes = resolve_shard_map_mesh(mesh)
@@ -146,13 +143,13 @@ def make_ring_attention_fn(mesh: Mesh, *, causal: bool = True,
 
     common = dict(mesh=use_mesh, out_specs=seq_spec,
                   axis_names=frozenset({axis_name}), check_vma=False)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis_name,
                           causal=causal),
         in_specs=(seq_spec, seq_spec, seq_spec, P(axis_name)),
         **common,
     )
-    fn_seg = compat_shard_map(
+    fn_seg = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis_name,
                           causal=causal),
         in_specs=(seq_spec, seq_spec, seq_spec, P(axis_name), seq_spec),

@@ -33,15 +33,16 @@ from paddle_operator_tpu.ops.attention import attention
 def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       segment_ids: Optional[jax.Array] = None,
                       *, axis_name: str = "cp",
-                      causal: bool = True) -> jax.Array:
+                      causal: bool = True, mesh=None) -> jax.Array:
     """Per-device body: local [B, S_loc, H, D] shards in, same shape out.
     Must run inside shard_map with `axis_name` bound.  segment_ids
     [B, S_loc] (packed sequences) are all-gathered to the full sequence —
     every device attends full-length for its head subset, so the mask is
-    applied by ordinary attention."""
+    applied by ordinary attention (on `mesh`, see ops.attention)."""
     n = jax.lax.psum(1, axis_name)
     if n == 1:
-        return attention(q, k, v, causal=causal, segment_ids=segment_ids)
+        return attention(q, k, v, causal=causal, segment_ids=segment_ids,
+                         mesh=mesh)
     # seq-sharded -> head-sharded: split heads (axis 2), gather seq (axis 1)
     qh = jax.lax.all_to_all(q, axis_name, split_axis=2, concat_axis=1,
                             tiled=True)
@@ -53,8 +54,8 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if segment_ids is not None:
         seg_full = jax.lax.all_gather(segment_ids, axis_name, axis=1,
                                       tiled=True)
-    out = attention(qh, kh, vh, causal=causal,
-                    segment_ids=seg_full)        # full-seq, H/cp heads
+    out = attention(qh, kh, vh, causal=causal, segment_ids=seg_full,
+                    mesh=mesh)                   # full-seq, H/cp heads
     # head-sharded -> seq-sharded: split seq, gather heads
     return jax.lax.all_to_all(out, axis_name, split_axis=1, concat_axis=2,
                               tiled=True)
@@ -67,8 +68,6 @@ def make_ulysses_attention_fn(mesh: Mesh, *, causal: bool = True,
     make_ring_attention_fn — only ``cp`` is manual, so batch/head dims keep
     their dp/fsdp/tp shardings and the wrapper nests inside other manual
     regions (the pp pipeline body)."""
-    from paddle_operator_tpu.parallel.mesh import compat_shard_map
-
     from paddle_operator_tpu.parallel.mesh import resolve_shard_map_mesh
 
     seq_spec = P(None, axis_name)
@@ -76,15 +75,15 @@ def make_ulysses_attention_fn(mesh: Mesh, *, causal: bool = True,
 
     common = dict(mesh=use_mesh, out_specs=seq_spec,
                   axis_names=frozenset({axis_name}), check_vma=False)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         functools.partial(ulysses_attention, axis_name=axis_name,
-                          causal=causal),
+                          causal=causal, mesh=mesh),
         in_specs=(seq_spec, seq_spec, seq_spec),
         **common,
     )
-    fn_seg = compat_shard_map(
+    fn_seg = jax.shard_map(
         functools.partial(ulysses_attention, axis_name=axis_name,
-                          causal=causal),
+                          causal=causal, mesh=mesh),
         in_specs=(seq_spec, seq_spec, seq_spec, seq_spec),
         **common,
     )

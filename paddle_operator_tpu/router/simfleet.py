@@ -28,6 +28,11 @@ from paddle_operator_tpu.router.router import (
     make_router_server,
 )
 
+# Subprocess replicas always run on the CPU backend: a chip belongs to
+# one process, and the parent that spawns them may hold it.  Benchmark
+# rows built on such replicas carry this value.
+REPLICA_PLATFORM = "cpu"
+
 _CLIENT_DIR = os.path.join(os.path.dirname(__file__), "..", "..",
                            "client")
 
@@ -308,7 +313,7 @@ class SimFleet:
         port = s.getsockname()[1]
         s.close()
         env = dict(os.environ,
-                   JAX_PLATFORMS="cpu",
+                   JAX_PLATFORMS=REPLICA_PLATFORM,
                    TPUJOB_REPLICA_PORT=str(port),
                    TPUJOB_REPLICA_ID=str(idx),
                    SIMFLEET_RING_KW=repr(self.ring_kw),

@@ -125,6 +125,30 @@ class CheckpointManager:
                 raise err
             return opt8bit.reblock_restored(raw, state_like)
 
+    def restore_params(self, params_like: Any,
+                       step: Optional[int] = None) -> Any:
+        """Restore ONLY the ``params`` subtree of a saved TrainState into
+        `params_like` — ``jax.ShapeDtypeStruct`` leaves carrying the
+        sharding AND the dtype wanted (orbax casts on the way in, so f32
+        masters land directly in a server's bf16).  The optimizer state
+        is never read: a server holds none, and whether the checkpoint
+        was trained with f32 or int8 moments does not matter to it."""
+        if not self._mgr:
+            raise RuntimeError("checkpointing disabled (no path)")
+        import orbax.checkpoint as ocp
+
+        step = step if step is not None else self._mgr.latest_step()
+        if step is None:
+            raise FileNotFoundError(f"no checkpoint under {self.path}")
+        restore_args = jax.tree.map(
+            lambda s: ocp.ArrayRestoreArgs(
+                sharding=s.sharding, dtype=s.dtype, global_shape=s.shape),
+            params_like)
+        return self._mgr.restore(step, args=ocp.args.PyTreeRestore(
+            item={"params": params_like},
+            restore_args={"params": restore_args},
+            partial_restore=True))["params"]
+
     def wait(self) -> None:
         """Block until pending async saves are durable (call before exit)."""
         if self._mgr:
@@ -140,37 +164,57 @@ class CheckpointManager:
             self._mgr.close()
 
 
-def resume_or_init(ckpt: CheckpointManager, init_fn, state_like=None, *,
-                   logger=None):
-    """The restart-recovery entry: restore the latest checkpoint if one
-    exists, else initialize fresh.  `init_fn()` builds a fresh sharded
-    state; `state_like` (defaults to the fresh state) pins structure and
-    shardings for restore.
+def abstract_like(tree: Any) -> Any:
+    """`tree` as a restore template: shape, dtype and sharding of every
+    array leaf, no buffers."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding)
+        if isinstance(x, jax.Array) else x, tree)
+
+
+def restore_newest(ckpt: CheckpointManager, restore_step, *, logger=None):
+    """``restore_step(step)`` over the committed steps, newest first.
 
     A corrupt/partial newest step (torn write during the kill that caused
     this very restart) falls back to the previous complete step with a
     logged warning instead of failing the whole restart; only when every
     step fails does the newest step's error surface."""
-    if ckpt.enabled and ckpt.latest_step() is not None:
-        if logger is None:
-            # The fallback must never be silent: rolling back to an
-            # older step re-does (or serves stale) work and the operator
-            # needs the trace even from callers that pass no logger.
-            from paddle_operator_tpu.utils.observability import get_logger
+    if logger is None:
+        # The fallback must never be silent: rolling back to an
+        # older step re-does (or serves stale) work and the operator
+        # needs the trace even from callers that pass no logger.
+        from paddle_operator_tpu.utils.observability import get_logger
 
-            logger = get_logger()
-        like = state_like if state_like is not None else init_fn()
-        steps = ckpt.all_steps() or [ckpt.latest_step()]
-        first_err: Optional[Exception] = None
-        for step in reversed(steps):
-            try:
-                return ckpt.restore(like, step=step), True
-            except Exception as err:
-                if first_err is None:
-                    first_err = err
-                logger.warning(
-                    f"checkpoint step {step} failed to restore "
-                    f"({type(err).__name__}: {err}); trying the "
-                    f"previous complete step")
-        raise first_err
+        logger = get_logger()
+    steps = ckpt.all_steps() or [ckpt.latest_step()]
+    first_err: Optional[Exception] = None
+    for step in reversed(steps):
+        try:
+            return restore_step(step)
+        except Exception as err:
+            if first_err is None:
+                first_err = err
+            logger.warning(
+                f"checkpoint step {step} failed to restore "
+                f"({type(err).__name__}: {err}); trying the "
+                f"previous complete step")
+    raise first_err
+
+
+def resume_or_init(ckpt: CheckpointManager, init_fn, state_like=None, *,
+                   logger=None):
+    """The restart-recovery entry: restore the latest checkpoint if one
+    exists (falling back over corrupt steps, :func:`restore_newest`), else
+    initialize fresh.  `init_fn()` builds a fresh sharded state;
+    `state_like` pins structure and shardings for restore — pass an
+    abstract one (``trainer.abstract_state``) where memory matters.
+    Without it the template is taken from ``init_fn()`` and the fresh
+    state is dropped BEFORE the restore, so the device never holds two
+    copies."""
+    if ckpt.enabled and ckpt.latest_step() is not None:
+        like = state_like if state_like is not None \
+            else abstract_like(init_fn())
+        return restore_newest(
+            ckpt, lambda step: ckpt.restore(like, step=step),
+            logger=logger), True
     return init_fn(), False

@@ -96,8 +96,8 @@ class NativeTokenFile:
             raise ValueError(f"unsupported token dtype {dtype}")
         lib_file = lib_path or _find_native_lib()
         if lib_file is None:
-            raise FileNotFoundError("native library not built "
-                                    "(run `make -C native`)")
+            raise FileNotFoundError(
+                "native library not found and could not be built")
         lib = ctypes.CDLL(lib_file)
         lib.dio_open.restype = ctypes.c_void_p
         lib.dio_open.argtypes = [ctypes.c_char_p, ctypes.c_int]
@@ -145,8 +145,9 @@ def mmap_token_batches(path: str, batch_size: int, seq_len: int,
     with per-process seeds the dp shards are disjoint in expectation.
 
     ``native``: use the C++ gather (native/dataio.cpp) — one call per
-    batch instead of a per-row python slice loop.  Default: native when
-    the library is built, python otherwise; pass True/False to force."""
+    batch instead of a per-row python slice loop.  Default: native (the
+    library is built on demand, controller/hostport.py), python where it
+    can be neither found nor built; pass True/False to force."""
     reader = None
     if native is not False:
         try:

@@ -108,6 +108,24 @@ def state_shardings(model: nn.Module, optimizer: optax.GradientTransformation,
     return shardings, init_fn
 
 
+def abstract_state(model: nn.Module,
+                   optimizer: optax.GradientTransformation, mesh: Mesh,
+                   partition_patterns: Sequence[Tuple[str, tuple]],
+                   example_inputs: Tuple[Any, ...],
+                   offload_opt_state: bool = False) -> TrainState:
+    """The TrainState :func:`create_state` would build, as
+    ``jax.ShapeDtypeStruct`` leaves carrying their shardings and no
+    buffers — the restore template (``checkpoint.resume_or_init``'s
+    ``state_like``) for a job that must not hold a second copy."""
+    shardings, init_fn = state_shardings(
+        model, optimizer, mesh, partition_patterns, example_inputs,
+        offload_opt_state=offload_opt_state)
+    shapes = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    return jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, shardings)
+
+
 def create_state(model: nn.Module, optimizer: optax.GradientTransformation,
                  mesh: Mesh,
                  partition_patterns: Sequence[Tuple[str, tuple]],

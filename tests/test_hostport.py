@@ -82,7 +82,8 @@ class TestAllocator:
 class TestNative:
     def test_native_lib_builds_and_loads(self):
         assert native_available(), (
-            "native allocator missing — run `make -C native`"
+            "native allocator neither found nor buildable on demand "
+            "(controller/hostport.py _find_native_lib)"
         )
 
     def test_make_allocator_prefers_native(self):

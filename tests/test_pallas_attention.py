@@ -68,11 +68,25 @@ def test_untileable_shapes_raise():
         flash_attention(q, k, v, block_q=128, block_k=128, interpret=True)
 
 
-def test_dispatcher_falls_back(monkeypatch):
+def test_dispatcher_refuses_a_kernel_that_cannot_run():
+    """Asking for the kernel on shapes it cannot tile is an error, not a
+    silent O(S^2) reference run."""
     from paddle_operator_tpu.ops import attention as A
 
+    q, k, v = rand_qkv(1, 100, 2, 2, 64)  # untileable
+    with pytest.raises(NotImplementedError, match="cannot tile"):
+        A.attention(q, k, v, use_pallas=True)
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_dispatcher_decides_from_shapes_before_the_call(monkeypatch, backend):
+    """Left to itself the dispatcher picks the reference for shapes the
+    kernel cannot tile — on any backend, by asking flash_tiles first."""
+    from paddle_operator_tpu.ops import attention as A
+
+    monkeypatch.setattr(A.jax, "default_backend", lambda: backend)
     q, k, v = rand_qkv(1, 100, 2, 2, 64)  # untileable -> reference path
-    out = A.attention(q, k, v, use_pallas=True)
+    out = A.attention(q, k, v)
     ref = A.reference_attention(q, k, v)
     np.testing.assert_allclose(out, ref, atol=1e-6)
 
@@ -130,3 +144,56 @@ def test_dispatcher_uses_pallas_for_segments():
         out = flash_attention(q, k, v, causal=True, segment_ids=seg,
                               block_q=128, block_k=128, interpret=True)
     assert out.shape == q.shape
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_sharded_flash_matches_reference_on_a_mesh(packed):
+    """The kernel on a multi-device mesh (ops.attention
+    sharded_flash_attention): batch over (dp, fsdp), heads over tp, each
+    shard its own kernel call — GSPMD cannot partition a Mosaic call, so
+    this is the only way the flash kernel reaches a multi-chip TPU job.
+    Values and gradients against the reference, GQA included."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_operator_tpu.api.types import MeshSpec
+    from paddle_operator_tpu.ops.attention import sharded_flash_attention
+    from paddle_operator_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(MeshSpec(dp=2, fsdp=2, tp=2))
+    q, k, v = rand_qkv(4, 128, 4, 2, 64)
+    sh = NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None))
+    q, k, v = (jax.device_put(x, sh) for x in (q, k, v))
+    seg = _seg_pattern(4, 128) if packed else None
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    def flash(q, k, v):
+        return sharded_flash_attention(mesh, q, k, v, segment_ids=seg,
+                                       interpret=True)
+
+    def ref(q, k, v):
+        return reference_attention(q, k, v, segment_ids=seg)
+
+    out = jax.jit(flash)(q, k, v)
+    assert out.sharding.is_equivalent_to(sh, out.ndim)
+    np.testing.assert_allclose(out, ref(q, k, v), atol=2e-5, rtol=2e-5)
+    got = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(want, got):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+
+
+def test_sharded_flash_refuses_heads_tp_cannot_split():
+    from paddle_operator_tpu.api.types import MeshSpec
+    from paddle_operator_tpu.ops import attention as A
+    from paddle_operator_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(MeshSpec(dp=2, tp=4))
+    q, k, v = rand_qkv(2, 128, 4, 2, 64)      # 2 kv heads over tp=4
+    with pytest.raises(NotImplementedError, match="GQA groups"):
+        A.sharded_flash_attention(mesh, q, k, v, interpret=True)
+    # left to itself the dispatcher sees it from the shapes and goes to
+    # the reference
+    np.testing.assert_allclose(A.attention(q, k, v, mesh=mesh),
+                               A.reference_attention(q, k, v), atol=1e-6)

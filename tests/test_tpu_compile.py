@@ -1,0 +1,225 @@
+"""AOT compiles for the chip, without the chip.
+
+The TPU's compiler is installed in the sandbox and compiles for a device
+that is described, not attached (guide ``on-chip-measurement`` section 2).
+Interpret mode cannot see what it refuses: a block shape the (8, 128)
+tiling rejects, a kernel over its VMEM budget, a Mosaic call GSPMD is asked
+to partition.  Every kernel of the main path at LLaMA-7B width (32 heads x
+128) and the train step that wraps them compile here, so a later PR that
+breaks one learns it at no chip time.
+
+Rules this file keeps (one process at a time may load the TPU library, and
+pytest-xdist workers all import every test file): the topology is described
+inside a module-scoped, non-autouse fixture of THIS file, never at import,
+in a ``skipif``, in ``parametrize`` or in ``conftest.py``; everything built
+from it is built in fixtures or tests; all such tests live in this one
+file; the persistent compile cache is off around them (a described-device
+executable is written but can never be read back).
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+H, D = 32, 128          # LLaMA-7B heads x head_dim
+B, S = 8, 2048          # lanes / batch, context
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tp_mesh(topo):
+    from paddle_operator_tpu.parallel.mesh import make_serving_mesh
+
+    return make_serving_mesh(4, devices=topo.devices)
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def kernel_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def test_flash_forward_and_backward(one_chip):
+    from paddle_operator_tpu.ops.pallas_attention import flash_attention
+
+    q = sds((B, S, H, D), jnp.bfloat16, one_chip)
+    seg = sds((B, S), jnp.int32, one_chip)
+
+    def loss(q, k, v, seg=None):
+        return flash_attention(q, k, v, segment_ids=seg).astype(
+            jnp.float32).sum()
+
+    assert kernel_calls(jax.jit(flash_attention).lower(q, q, q).compile()) == 1
+    # fwd (residuals) + dkv + dq
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    assert kernel_calls(grad.lower(q, q, q).compile()) == 3
+    assert kernel_calls(grad.lower(q, q, q, seg).compile()) == 3
+
+
+def test_contiguous_decode(one_chip):
+    from paddle_operator_tpu.ops.decode_attention import decode_attention
+
+    L = 4
+    c = jax.jit(lambda q, k, v, n, li: decode_attention(
+        q, k, v, n, layer=li)).lower(
+        sds((B, H, D), jnp.bfloat16, one_chip),
+        sds((L, B, H, S, D), jnp.bfloat16, one_chip),
+        sds((L, B, H, S, D), jnp.bfloat16, one_chip),
+        sds((B,), jnp.int32, one_chip), sds((), jnp.int32, one_chip),
+    ).compile()
+    assert kernel_calls(c) == 1
+
+
+def paged_args(sharding, block, quant):
+    L, M = 4, S // block
+    N = B * M + 1
+    pool_dt = jnp.int8 if quant else jnp.bfloat16
+    args = [sds((B, H, D), jnp.bfloat16, sharding["q"]),
+            sds((L, N, H, block, D), pool_dt, sharding["pool"]),
+            sds((L, N, H, block, D), pool_dt, sharding["pool"]),
+            sds((B, M), jnp.int32, sharding["rep"]),
+            sds((B,), jnp.int32, sharding["rep"]),
+            sds((), jnp.int32, sharding["rep"])]
+    if quant:
+        args += [sds((L, N, H), jnp.float32, sharding["scale"])] * 2
+        args += [sds((L, B + 1, H, block, D), jnp.bfloat16,
+                     sharding["pool"])] * 2
+    return args
+
+
+def paged_call(q, kp, vp, tbl, lens, li, *quant):
+    from paddle_operator_tpu.ops.decode_attention import (
+        paged_decode_attention,
+    )
+
+    kw = dict(zip(("k_scale", "v_scale", "k_tail", "v_tail"), quant))
+    return paged_decode_attention(q, kp, vp, tbl, lens, layer=li, **kw)
+
+
+@pytest.mark.parametrize("block", [256, 64])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_decode(one_chip, block, quant):
+    """The int8 variant is the one the compiler refused before PR 21: a
+    ``(1, 1, hkv)`` scale block on a ``[L, N, hkv]`` array."""
+    sh = dict.fromkeys(("q", "pool", "rep", "scale"), one_chip)
+    c = jax.jit(paged_call).lower(*paged_args(sh, block, quant)).compile()
+    assert kernel_calls(c) == 1
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_tp_paged_decode(tp_mesh, quant):
+    """SERVE_TP: the kernel enters the 4-chip mesh through shard_map —
+    custom call + all-reduce, and the pool is never all-gathered."""
+    from paddle_operator_tpu.ops.decode_attention import (
+        sharded_paged_decode_attention,
+    )
+
+    def ns(*spec):
+        return NamedSharding(tp_mesh, P(*spec))
+
+    sh = {"q": ns(None, "tp", None), "rep": ns(),
+          "pool": ns(None, None, "tp", None, None),
+          "scale": ns(None, None, "tp")}
+    q, kp, vp, tbl, lens, li, *qargs = paged_args(sh, 256, quant)
+    wo = sds((H * D, H * D), jnp.bfloat16, ns("tp", None))
+
+    def call(q, kp, vp, tbl, lens, wo, li, *quant):
+        kw = dict(zip(("k_scale", "v_scale", "k_tail", "v_tail"), quant))
+        return sharded_paged_decode_attention(
+            tp_mesh, q, kp, vp, tbl, lens, wo, layer=li, **kw)
+
+    text = jax.jit(call).lower(q, kp, vp, tbl, lens, wo, li,
+                               *qargs).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "all-reduce" in text and "all-gather" not in text
+
+
+def test_tp_contiguous_decode(tp_mesh):
+    from paddle_operator_tpu.ops.decode_attention import (
+        sharded_decode_attention,
+    )
+
+    def ns(*spec):
+        return NamedSharding(tp_mesh, P(*spec))
+
+    L = 4
+    cache = sds((L, B, H, S, D), jnp.bfloat16,
+                ns(None, None, "tp", None, None))
+    text = jax.jit(lambda q, k, v, n, wo, li: sharded_decode_attention(
+        tp_mesh, q, k, v, n, wo, layer=li)).lower(
+        sds((B, H, D), jnp.bfloat16, ns(None, "tp", None)), cache, cache,
+        sds((B,), jnp.int32, ns()),
+        sds((H * D, H * D), jnp.bfloat16, ns("tp", None)),
+        sds((), jnp.int32, ns()),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "all-reduce" in text and "all-gather" not in text
+
+
+@pytest.mark.parametrize("mesh_axes", [{}, {"fsdp": 2, "tp": 2}],
+                         ids=["one-chip", "fsdp2-tp2"])
+def test_train_step_7b_width(topo, monkeypatch, mesh_axes):
+    """One whole train step, 2 layers of LLaMA-7B width, bf16 params, int8
+    moments, batch 8 x 2048 — on one chip and on the ``fsdp=2, tp=2`` mesh
+    of ``deploy/examples``.  The dispatchers ask ``jax.default_backend()``
+    and here that is the CPU, so the test steers it (never the program).
+    On the mesh the flash kernel must enter through shard_map: GSPMD
+    refuses to partition a Mosaic call."""
+    from paddle_operator_tpu.api.types import MeshSpec
+    from paddle_operator_tpu.models import llama as L
+    from paddle_operator_tpu.parallel.mesh import make_mesh
+    from paddle_operator_tpu.parallel.sharding import batch_sharding
+    from paddle_operator_tpu.train import trainer as T
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = MeshSpec(**mesh_axes)
+    n = 4 if mesh_axes else 1
+    mesh = make_mesh(spec, devices=topo.devices[:n])
+    cfg = dataclasses.replace(L.CONFIGS["7b"], n_layers=2,
+                              param_dtype=jnp.bfloat16)
+    model = L.Llama(cfg, mesh)
+    opt = T.make_optimizer(moments="int8")
+    args = (model, opt, mesh, L.partition_patterns(cfg),
+            (jnp.zeros((B, 8), jnp.int32),))
+    shardings, _ = T.state_shardings(*args)
+    step = T.make_train_step(model, opt, mesh, shardings)
+    batch = {"tokens": sds((B, S + 1), jnp.int32,
+                           batch_sharding(mesh, extra_dims=1))}
+    compiled = step.lower(T.abstract_state(*args), batch).compile()
+    # forward, its remat re-run, dkv, dq
+    assert kernel_calls(compiled) == 4
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
