@@ -103,6 +103,16 @@ def alloc_kv_buffer(cfg: LlamaConfig, shape, mesh) -> jax.Array:
     return jnp.zeros(shape, cfg.dtype, device=sharding)
 
 
+# Named scopes put a layer's name into every device operation's
+# ``op_name``, one vocabulary wherever the block is written out (here,
+# infer/executor.py, infer/paged.py, infer/speculative.py,
+# models/llama.py): embed, norm, attn.qkv, attn.rope, cache_write,
+# attn.kernel, attn.out, ffn, lm_head, sample — and loss, opt_update in
+# the train step.  They change no computation and no compile-cache key
+# (the key strips debug information).
+
+
+@jax.named_scope("norm")
 def _rms(x: jax.Array, scale: jax.Array, eps: float, dtype) -> jax.Array:
     """models/llama.py RMSNorm math, f32 internals."""
     xf = x.astype(jnp.float32)
@@ -124,6 +134,23 @@ def _mm(x: jax.Array, kernel_leaf, dtype) -> jax.Array:
     return x @ kernel_leaf.astype(dtype)
 
 
+@jax.named_scope("embed")
+def _embed(cfg: LlamaConfig, params: Dict[str, Any],
+           tokens: jax.Array) -> jax.Array:
+    """Token ids (any shape) -> hidden states in the compute dtype."""
+    return params["tok_embed"]["embedding"].astype(cfg.dtype)[tokens]
+
+
+def _lm_head(cfg: LlamaConfig, params: Dict[str, Any],
+             x: jax.Array) -> jax.Array:
+    """Final norm + vocabulary projection -> f32 logits."""
+    x = _rms(x, params["final_norm"]["scale"], cfg.norm_eps, cfg.dtype)
+    with jax.named_scope("lm_head"):
+        return _mm(x, params["lm_head"]["kernel"],
+                   cfg.dtype).astype(jnp.float32)
+
+
+@jax.named_scope("attn.rope")
 def _rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
           pos: jax.Array) -> jax.Array:
     """Split-halves RoPE at dynamic offset ``pos`` (mirrors
@@ -193,9 +220,18 @@ def _qkv(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
     delta adds to the projection outputs BEFORE RoPE (qos.lora_qkv),
     so adapter KV enters the cache exactly as a merged-weight forward
     would produce it."""
-    b, t, _ = x.shape
-    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     h = _rms(x, lp["attn_norm"]["scale"], cfg.norm_eps, cfg.dtype)
+    q, k, v = _qkv_proj(cfg, lp, h, x.shape[1], lora)
+    return _rope(q, cos, sin, pos), _rope(k, cos, sin, pos), v
+
+
+@jax.named_scope("attn.qkv")
+def _qkv_proj(cfg: LlamaConfig, lp: Dict[str, Any], h: jax.Array, t: int,
+              lora=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The q/k/v projections of the normed hidden state ``h`` (plus the
+    LoRA deltas), split into heads: [B, t, H, D]."""
+    b = h.shape[0]
+    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = _mm(h, lp["attn"]["wq"]["kernel"], cfg.dtype)
     k = _mm(h, lp["attn"]["wk"]["kernel"], cfg.dtype)
     v = _mm(h, lp["attn"]["wv"]["kernel"], cfg.dtype)
@@ -203,10 +239,8 @@ def _qkv(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
         from paddle_operator_tpu.infer.qos import lora_qkv
 
         q, k, v = lora_qkv(h, lora[0], lora[1], q, k, v, cfg.dtype)
-    q = q.reshape(b, t, hq, d)
-    k = k.reshape(b, t, hkv, d)
-    v = v.reshape(b, t, hkv, d)
-    return _rope(q, cos, sin, pos), _rope(k, cos, sin, pos), v
+    return (q.reshape(b, t, hq, d), k.reshape(b, t, hkv, d),
+            v.reshape(b, t, hkv, d))
 
 
 def _ffn_residual(cfg: LlamaConfig, lp: Dict[str, Any],
@@ -217,21 +251,23 @@ def _ffn_residual(cfg: LlamaConfig, lp: Dict[str, Any],
     (attention out is head-sharded there; the wo contraction + psum is
     the Megatron row-parallel reduction) and re-enters GSPMD here."""
     n = _rms(x, lp["mlp_norm"]["scale"], cfg.norm_eps, cfg.dtype)
-    if cfg.n_experts > 0:
-        ffn = _moe_ffn(cfg, lp["moe"], n)
-    else:
-        gate = _mm(n, lp["mlp"]["w1"]["kernel"], cfg.dtype)
-        up = _mm(n, lp["mlp"]["w3"]["kernel"], cfg.dtype)
-        ffn = _mm(jax.nn.silu(gate) * up, lp["mlp"]["w2"]["kernel"],
-                  cfg.dtype)
-    return x + ffn
+    with jax.named_scope("ffn"):
+        if cfg.n_experts > 0:
+            ffn = _moe_ffn(cfg, lp["moe"], n)
+        else:
+            gate = _mm(n, lp["mlp"]["w1"]["kernel"], cfg.dtype)
+            up = _mm(n, lp["mlp"]["w3"]["kernel"], cfg.dtype)
+            ffn = _mm(jax.nn.silu(gate) * up, lp["mlp"]["w2"]["kernel"],
+                      cfg.dtype)
+        return x + ffn
 
 
 def _finish_layer(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
                   out: jax.Array) -> jax.Array:
     """Post-attention half: output projection + residual, then the
     (dense SwiGLU or MoE) FFN + residual."""
-    x = x + _mm(out, lp["attn"]["wo"]["kernel"], cfg.dtype)
+    with jax.named_scope("attn.out"):
+        x = x + _mm(out, lp["attn"]["wo"]["kernel"], cfg.dtype)
     return _ffn_residual(cfg, lp, x)
 
 
@@ -245,16 +281,25 @@ def _layer(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
     (unstacked); caches are head-major [B, H_kv, S, D] (init_cache).
     The pallas decode path does NOT go through here — it keeps the
     caches stacked (see _forward) so the kernel reads them copy-free."""
-    b, t, _ = x.shape
-    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q, k, v = _qkv(cfg, lp, x, cos, sin, pos, lora=lora)
 
     # [B, T, H, D] -> head-major [B, H, T, D] rows into the cache
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, k.transpose(0, 2, 1, 3), (0, 0, pos, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, v.transpose(0, 2, 1, 3), (0, 0, pos, 0))
+    with jax.named_scope("cache_write"):
+        k_cache = jax.lax.dynamic_update_slice(
+            k_cache, k.transpose(0, 2, 1, 3), (0, 0, pos, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            v_cache, v.transpose(0, 2, 1, 3), (0, 0, pos, 0))
+    out = _attend_cache(cfg, q, k_cache, v_cache, pos)
+    return _finish_layer(cfg, lp, x, out), k_cache, v_cache
 
+
+@jax.named_scope("attn.kernel")
+def _attend_cache(cfg: LlamaConfig, q: jax.Array, k_cache: jax.Array,
+                  v_cache: jax.Array, pos: jax.Array) -> jax.Array:
+    """The XLA einsum attention of [B, T] new positions starting at
+    ``pos`` against head-major caches [B, H_kv, S, D] -> [B, T, Hq*D]."""
+    b, t = q.shape[:2]
+    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     # GQA: group query heads onto kv heads; single-query (or prefill-
     # block) attention against the cache with a causal+fill mask.  The
     # einsums read the cache in its storage dtype and accumulate in f32
@@ -276,8 +321,7 @@ def _layer(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bthrs,bhsd->bthrd", probs.astype(cfg.dtype),
                      v_cache, preferred_element_type=jnp.float32)
-    out = out.reshape(b, t, hq * d).astype(cfg.dtype)
-    return _finish_layer(cfg, lp, x, out), k_cache, v_cache
+    return out.reshape(b, t, hq * d).astype(cfg.dtype)
 
 
 def _moe_ffn(cfg: LlamaConfig, mp: Dict[str, Any],
@@ -336,7 +380,7 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
     the layer scan as xs, per-row adapter ids (infer/qos.py)."""
     pos = cache["pos"]
     adp, aid = lora if lora is not None else (None, None)
-    x = params["tok_embed"]["embedding"].astype(cfg.dtype)[tokens]
+    x = _embed(cfg, params, tokens)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
 
@@ -432,9 +476,7 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
         x, (k_new, v_new) = jax.lax.scan(body, x, xs)
     if last_only:
         x = x[:, -1:]
-    x = _rms(x, params["final_norm"]["scale"], cfg.norm_eps, cfg.dtype)
-    logits = _mm(x, params["lm_head"]["kernel"],
-                 cfg.dtype).astype(jnp.float32)
+    logits = _lm_head(cfg, params, x)
     new_cache = {"k": k_new, "v": v_new,
                  "pos": pos + tokens.shape[1]}
     return logits, new_cache

@@ -136,6 +136,7 @@ def init_ring_cache(cfg: LlamaConfig, slots: int,
     }
 
 
+@jax.named_scope("cache_write")
 def _write_lane(cache_l: jax.Array, kv: jax.Array,
                 pos: jax.Array) -> jax.Array:
     """[B, H, S, D] cache layer <- [B, H, 1, D] new row at per-lane pos."""
@@ -155,29 +156,19 @@ def _qkv_ring(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
     arrays + the per-LANE adapter id vector; the batched gather +
     delta matmul (qos.lora_qkv) runs inside the same compiled step, so
     a mixed-adapter batch is still ONE dispatch."""
-    b = x.shape[0]
-    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     h = D._rms(x, lp["attn_norm"]["scale"], cfg.norm_eps, cfg.dtype)
-    q = D._mm(h, lp["attn"]["wq"]["kernel"], cfg.dtype)
-    k = D._mm(h, lp["attn"]["wk"]["kernel"], cfg.dtype)
-    v = D._mm(h, lp["attn"]["wv"]["kernel"], cfg.dtype)
-    if lora is not None:
-        from paddle_operator_tpu.infer.qos import lora_qkv
+    q, k, v = D._qkv_proj(cfg, lp, h, 1, lora)
+    with jax.named_scope("attn.rope"):
+        cos_b = cos[pos][:, None, None, :]          # [B, 1, 1, d/2]
+        sin_b = sin[pos][:, None, None, :]
 
-        q, k, v = lora_qkv(h, lora[0], lora[1], q, k, v, cfg.dtype)
-    q = q.reshape(b, 1, hq, d)
-    k = k.reshape(b, 1, hkv, d)
-    v = v.reshape(b, 1, hkv, d)
-    cos_b = cos[pos][:, None, None, :]          # [B, 1, 1, d/2]
-    sin_b = sin[pos][:, None, None, :]
+        def rot(t):
+            t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
+            return jnp.concatenate(
+                [t1 * cos_b - t2 * sin_b, t2 * cos_b + t1 * sin_b],
+                axis=-1).astype(t.dtype)
 
-    def rot(t):
-        t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
-        return jnp.concatenate(
-            [t1 * cos_b - t2 * sin_b, t2 * cos_b + t1 * sin_b],
-            axis=-1).astype(t.dtype)
-
-    return rot(q), rot(k), v
+        return rot(q), rot(k), v
 
 
 def _layer_step(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
@@ -195,32 +186,24 @@ def _layer_step(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
     k_cache = _write_lane(k_cache, k.transpose(0, 2, 1, 3), pos)
     v_cache = _write_lane(v_cache, v.transpose(0, 2, 1, 3), pos)
 
-    n_rep = hq // hkv
-    max_len = k_cache.shape[2]
-    qg = q.reshape(b, 1, hkv, n_rep, d)
-    scores = jnp.einsum("bthrd,bhsd->bthrs", qg, k_cache,
-                        preferred_element_type=jnp.float32) / jnp.sqrt(
-        jnp.float32(d))
-    # lane b may attend cache cols [0, pos_b] (its own new row incl.)
-    mask = jnp.arange(max_len)[None, :] <= pos[:, None]      # [B, S]
-    scores = jnp.where(mask[:, None, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bthrs,bhsd->bthrd", probs.astype(cfg.dtype),
-                     v_cache, preferred_element_type=jnp.float32)
-    out = out.reshape(b, 1, hq * d).astype(cfg.dtype)
-    x = x + D._mm(out, lp["attn"]["wo"]["kernel"], cfg.dtype)
-
-    n = D._rms(x, lp["mlp_norm"]["scale"], cfg.norm_eps, cfg.dtype)
-    if cfg.n_experts > 0:
-        ffn = D._moe_ffn(cfg, lp["moe"], n)
-    else:
-        gate = D._mm(n, lp["mlp"]["w1"]["kernel"], cfg.dtype)
-        up = D._mm(n, lp["mlp"]["w3"]["kernel"], cfg.dtype)
-        ffn = D._mm(jax.nn.silu(gate) * up, lp["mlp"]["w2"]["kernel"],
-                    cfg.dtype)
-    return x + ffn, k_cache, v_cache
+    with jax.named_scope("attn.kernel"):
+        n_rep = hq // hkv
+        max_len = k_cache.shape[2]
+        qg = q.reshape(b, 1, hkv, n_rep, d)
+        scores = jnp.einsum("bthrd,bhsd->bthrs", qg, k_cache,
+                            preferred_element_type=jnp.float32) / jnp.sqrt(
+            jnp.float32(d))
+        # lane b may attend cache cols [0, pos_b] (its own new row incl.)
+        mask = jnp.arange(max_len)[None, :] <= pos[:, None]      # [B, S]
+        scores = jnp.where(mask[:, None, None, None, :], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bthrs,bhsd->bthrd", probs.astype(cfg.dtype),
+                         v_cache, preferred_element_type=jnp.float32)
+        out = out.reshape(b, 1, hq * d).astype(cfg.dtype)
+    return D._finish_layer(cfg, lp, x, out), k_cache, v_cache
 
 
+@jax.named_scope("cache_write")
 def _write_lane_stacked(stack: jax.Array, kv: jax.Array, li: jax.Array,
                         pos: jax.Array) -> jax.Array:
     """[L, B, H, S, D] stacked cache <- [B, H, 1, D] new rows at layer
@@ -253,7 +236,7 @@ def _ring_forward(cfg: LlamaConfig, params: Dict[str, Any],
     index map already takes — replicated across shards)."""
     pos = cache["pos"]
     adp, aid = lora if lora is not None else (None, None)
-    x = params["tok_embed"]["embedding"].astype(cfg.dtype)[tok[:, None]]
+    x = D._embed(cfg, params, tok[:, None])
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
 
@@ -326,12 +309,11 @@ def _ring_forward(cfg: LlamaConfig, params: Dict[str, Any],
               if adp is not None
               else (params["layers"], cache["k"], cache["v"]))
         x, (k_new, v_new) = jax.lax.scan(body, x, xs)
-    x = D._rms(x, params["final_norm"]["scale"], cfg.norm_eps, cfg.dtype)
-    logits = D._mm(x, params["lm_head"]["kernel"],
-                   cfg.dtype).astype(jnp.float32)
-    return logits[:, 0], {"k": k_new, "v": v_new, "pos": pos + 1}
+    return (D._lm_head(cfg, params, x)[:, 0],
+            {"k": k_new, "v": v_new, "pos": pos + 1})
 
 
+@jax.named_scope("sample")
 def _sample_tokens(logits, temp, keys, pos, top_k, top_p):
     """THE per-lane sampling rule — shared by the chunk step and EVERY
     admission insert (inline, chunked final, suffix, disagg) so token 1

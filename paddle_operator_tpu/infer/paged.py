@@ -1111,6 +1111,7 @@ def init_paged_cache(cfg: LlamaConfig, slots: int, total_blocks: int,
     }
 
 
+@jax.named_scope("cache_write")
 def _write_token_paged(pool: jax.Array, kv: jax.Array, li: jax.Array,
                        table: jax.Array, pos: jax.Array,
                        block_size: int) -> jax.Array:
@@ -1126,6 +1127,7 @@ def _write_token_paged(pool: jax.Array, kv: jax.Array, li: jax.Array,
     return pool
 
 
+@jax.named_scope("cache_write")
 def _write_rows_paged(pool: jax.Array, kv: jax.Array, li: jax.Array,
                       table: jax.Array, pos: jax.Array, block_size: int,
                       limit: Optional[jax.Array] = None) -> jax.Array:
@@ -1148,6 +1150,7 @@ def _write_rows_paged(pool: jax.Array, kv: jax.Array, li: jax.Array,
     return pool
 
 
+@jax.named_scope("cache_write")
 def _write_blocks_paged(pool: jax.Array, kv: jax.Array, li: jax.Array,
                         table: jax.Array, pos: jax.Array,
                         block_size: int,
@@ -1182,6 +1185,7 @@ def _write_blocks_paged(pool: jax.Array, kv: jax.Array, li: jax.Array,
     return pool
 
 
+@jax.named_scope("cache_write")
 def _write_token_quant(pool: jax.Array, scales: jax.Array,
                        tail: jax.Array, kv: jax.Array, li: jax.Array,
                        table: jax.Array, pos: jax.Array,
@@ -1264,6 +1268,7 @@ def _gather_lane_view(pool: jax.Array, table: jax.Array,
     return v.reshape(b, h, m * bs, d)
 
 
+@jax.named_scope("attn.kernel")
 def _attend_einsum(cfg: LlamaConfig, q: jax.Array, k_view: jax.Array,
                    v_view: jax.Array, pos: jax.Array) -> jax.Array:
     """batcher._layer_step's attention block, lifted so the paged
@@ -1313,7 +1318,7 @@ def paged_ring_forward(cfg: LlamaConfig, params: Dict[str, Any],
     pos = cache["pos"]
     adp, aid = lora if lora is not None else (None, None)
     block_size = cache["k"].shape[3]
-    x = params["tok_embed"]["embedding"].astype(cfg.dtype)[tok[:, None]]
+    x = D._embed(cfg, params, tok[:, None])
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
 
@@ -1390,9 +1395,7 @@ def paged_ring_forward(cfg: LlamaConfig, params: Dict[str, Any],
 
     (x, k_new, v_new), _ = jax.lax.scan(
         body, (x, cache["k"], cache["v"]), xs)
-    x = D._rms(x, params["final_norm"]["scale"], cfg.norm_eps, cfg.dtype)
-    logits = D._mm(x, params["lm_head"]["kernel"],
-                   cfg.dtype).astype(jnp.float32)
+    logits = D._lm_head(cfg, params, x)
     return logits[:, 0], {"k": k_new, "v": v_new, "pos": pos + 1}
 
 
@@ -1493,9 +1496,7 @@ def _paged_ring_forward_quant(cfg, params, x, cache, table, pos,
     (x, k_new, v_new, ks_new, vs_new, kt_new, vt_new), _ = jax.lax.scan(
         body, (x, cache["k"], cache["v"], cache["ks"], cache["vs"],
                cache["kt"], cache["vt"]), xs)
-    x = D._rms(x, params["final_norm"]["scale"], cfg.norm_eps, cfg.dtype)
-    logits = D._mm(x, params["lm_head"]["kernel"],
-                   cfg.dtype).astype(jnp.float32)
+    logits = D._lm_head(cfg, params, x)
     return logits[:, 0], {"k": k_new, "v": v_new, "ks": ks_new,
                           "vs": vs_new, "kt": kt_new, "vt": vt_new,
                           "pos": pos + 1}
@@ -1644,6 +1645,7 @@ def make_paged_megastep(cfg: LlamaConfig, chunk_tokens: int,
     return jax.jit(mega, donate_argnums=(1,))
 
 
+@jax.named_scope("cache_write")
 def _scatter_prompt_blocks(pool: jax.Array, lane: jax.Array,
                            table_row: jax.Array,
                            block_size: int) -> jax.Array:

@@ -977,6 +977,8 @@ def main() -> int:
     exit EXIT_PREEMPTED."""
     import os
 
+    import jax
+
     from paddle_operator_tpu.api.types import EXIT_PREEMPTED
     from paddle_operator_tpu.ft.preemption import PreemptionWatcher
     from paddle_operator_tpu.infer.serve import load_serving_params
@@ -986,6 +988,10 @@ def main() -> int:
     from paddle_operator_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
+    # every phase of this process is a TraceMe on the profiler's host
+    # plane from here on (utils/tracing.py): whoever starts the profiler
+    # finds the loop's phases on the device's clock
+    TRC.set_annotator(jax.profiler.TraceAnnotation)
     env = JobEnv.from_env()
     cfg = CONFIGS[os.environ.get("MODEL_PRESET", "7b")]
     mesh = None

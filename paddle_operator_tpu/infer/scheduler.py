@@ -542,6 +542,16 @@ class ContinuousBatcher:
                       # the prompts prefilled off the ring thread.
                       "prefill_calls": 0, "prefill_tokens": 0,
                       "chunked_prefill_tokens": 0, "disagg_prefills": 0,
+                      # what the dispatched work was shaped like
+                      # (_count_prefill, the decode dispatch): the width
+                      # of every insert program dispatched, real tokens
+                      # or padding, by width; and decode iterations,
+                      # alone and times the lanes live in the plan —
+                      # the padding share and the lane occupancy are
+                      # ratios of these, taken by whoever reads them
+                      "prefill_bucket_tokens": 0,
+                      "prefill_calls_by_bucket": {},
+                      "decode_steps": 0, "decode_lane_steps": 0,
                       # cross-host disaggregation (ISSUE 13): cold
                       # prompts whose prefill ran in a PREFILL POOL
                       # pod and handed off over the wire
@@ -573,6 +583,11 @@ class ContinuousBatcher:
         # tokens since construction (the /metrics tokens-per-sec gauge)
         self._tokens_emitted = 0
         self._t_start = time.monotonic()
+        # where the loop thread's time goes (utils/tracing.py): its
+        # phases record here, tiled end to end by ``_tile`` — one ring,
+        # one table, however many rings a process (a test) holds
+        self.phases = TR.PhaseTable()
+        self._tile = TR.Tiling()
         # off-thread compile prewarm (opt-in param; serve.py flips it on
         # unless SERVE_PREWARM=0): without it the per-bucket insert (and
         # the chunked slice programs) compile lazily on the FIRST prompt
@@ -1050,6 +1065,29 @@ class ContinuousBatcher:
             "dispatchesPerToken": (
                 round(self.stats["chunks"] / self._tokens_emitted, 4)
                 if self._tokens_emitted else 0.0),
+            # raw cumulative counters, incremented where the work is
+            # dispatched and never a ratio (a reader takes the
+            # difference between two scrapes): decode dispatches, the
+            # device decode iterations they ran, those times the lanes
+            # live in each plan; insert programs dispatched, the real
+            # tokens they prefilled and the positions they computed
+            # (the program's width), by width; and the loop thread's
+            # self seconds and counts by phase
+            "dispatchesTotal": self.stats["chunks"],
+            "decodeStepsTotal": self.stats["decode_steps"],
+            "decodeLaneStepsTotal": self.stats["decode_lane_steps"],
+            "prefillCallsTotal": self.stats["prefill_calls"],
+            "prefillTokensTotal": pf_tok,
+            "prefillBucketTokensTotal":
+                self.stats["prefill_bucket_tokens"],
+            # (copied first: the ring thread adds a key the first
+            # time a width is dispatched)
+            "prefillCallsByBucket": {
+                str(k): v for k, v in
+                dict(self.stats["prefill_calls_by_bucket"]).items()},
+            "phaseSeconds": {k: round(v, 6) for k, v in
+                             self.phases.self_seconds().items()},
+            "phaseCounts": self.phases.counts(),
             # observability (ISSUE 15): the four latency histogram
             # snapshots (cumulative counts for /metrics exposition,
             # rolling-window counts for folding) and the window's TTFT
@@ -1468,6 +1506,19 @@ class ContinuousBatcher:
                 return b
         raise ValueError(f"no bucket fits prompt length {n}")
 
+    def _count_prefill(self, width: int, tokens: int, bucket=None) -> None:
+        """One insert program dispatched: ``tokens`` real prompt (or
+        suffix, or slice) tokens through a program ``width`` positions
+        wide — the full-prompt bucket, the suffix bucket or the chunked
+        slice — counted by width, or under ``bucket`` where given."""
+        st = self.stats
+        st["prefill_calls"] += 1
+        st["prefill_tokens"] += tokens
+        st["prefill_bucket_tokens"] += width
+        by = st["prefill_calls_by_bucket"]
+        key = width if bucket is None else bucket
+        by[key] = by.get(key, 0) + 1
+
     def _dispatch_cow(self, slot: int, cow, hit_len: int) -> None:
         """Dispatch the admission's copy-on-write block copies (codes +
         scales under SERVE_KV_QUANT=int8), then — quant only — seed the
@@ -1590,28 +1641,40 @@ class ContinuousBatcher:
         if self.paged:
             first = self._admit_paged(slot, req)
         elif self.spec_k:
-            (ex.cache, ex.dcache, ex.tok, ex.temp, ex.keys,
-             first) = ex.inserts[req.bucket](
-                ex.params, ex.draft_params, ex.cache, ex.dcache,
-                ex.tok, ex.temp, ex.keys, req.dev_prompt,
-                n, slot, float(req.temperature), req.seed)
-            self.stats["prefill_calls"] += 1
-            self.stats["prefill_tokens"] += n
+            with TR.phase("exec.insert", width=req.bucket, tokens=n):
+                (ex.cache, ex.dcache, ex.tok, ex.temp, ex.keys,
+                 first) = ex.inserts[req.bucket](
+                    ex.params, ex.draft_params, ex.cache, ex.dcache,
+                    ex.tok, ex.temp, ex.keys, req.dev_prompt,
+                    n, slot, float(req.temperature), req.seed)
+            self._count_prefill(req.bucket, n)
         else:
-            ex.cache, ex.tok, ex.temp, ex.keys, first = \
-                ex.inserts[req.bucket](
-                    ex.params, ex.cache, ex.tok, ex.temp,
-                    ex.keys, req.dev_prompt, n, slot,
-                    float(req.temperature), req.seed,
-                    *ex.lora_insert_tail(req.adapter_idx))
-            self.stats["prefill_calls"] += 1
-            self.stats["prefill_tokens"] += n
+            with TR.phase("exec.insert", width=req.bucket, tokens=n):
+                ex.cache, ex.tok, ex.temp, ex.keys, first = \
+                    ex.inserts[req.bucket](
+                        ex.params, ex.cache, ex.tok, ex.temp,
+                        ex.keys, req.dev_prompt, n, slot,
+                        float(req.temperature), req.seed,
+                        *ex.lora_insert_tail(req.adapter_idx))
+            self._count_prefill(req.bucket, n)
         # counted only once the insert dispatched: a NoFreeBlocks /
         # insert failure above fails the request and must not drift
         # ``admitted`` past real admissions (the slot-reuse tests and
         # the bench saturation wait both read it)
         self.stats["admitted"] += 1
         self._activate(slot, req, first)
+
+    def _pool_admit(self, slot: int, req: _Request) -> int:
+        """The pool's half of a paged admission: map the lane's blocks
+        (radix hits read-only, fresh for the rest) and dispatch the
+        copy-on-write copies and promotions the mapping asked for.
+        Returns the radix hit's length in tokens."""
+        with TR.phase("pool.admit", prompt_len=len(req.prompt)):
+            hit_len, cow = self.pool.admit(
+                slot, req.prompt,
+                max_suffix=self.SUFFIX_PREFILL_MAX_ROWS, ns=req.ns)
+            self._dispatch_cow(slot, cow, hit_len)
+        return hit_len
 
     def _admit_paged(self, slot: int, req: _Request):
         """Inline paged admission: map blocks (radix hits read-only,
@@ -1630,30 +1693,27 @@ class ContinuousBatcher:
         # through the cold block-granular scatter prefill; the
         # allocator then maps fresh blocks instead of the cached ones
         # (never written over) when spec mode is off
-        hit_len, cow = self.pool.admit(          # NoFreeBlocks -> req fails
-            slot, req.prompt, max_suffix=self.SUFFIX_PREFILL_MAX_ROWS,
-            ns=req.ns)
-        self._dispatch_cow(slot, cow, hit_len)
+        hit_len = self._pool_admit(slot, req)   # NoFreeBlocks -> req fails
         tbl_row = jnp.asarray(self.pool.table[slot])
         if self.spec_k:
-            (ex.cache, ex.dcache, ex.tok, ex.temp, ex.keys,
-             first) = ex.inserts[req.bucket](
-                ex.params, ex.draft_params, ex.cache, ex.dcache,
-                tbl_row, ex.tok, ex.temp, ex.keys, req.dev_prompt,
-                n, slot, float(req.temperature), req.seed)
-            self.stats["prefill_calls"] += 1
-            self.stats["prefill_tokens"] += n
+            with TR.phase("exec.insert", width=req.bucket, tokens=n):
+                (ex.cache, ex.dcache, ex.tok, ex.temp, ex.keys,
+                 first) = ex.inserts[req.bucket](
+                    ex.params, ex.draft_params, ex.cache, ex.dcache,
+                    tbl_row, ex.tok, ex.temp, ex.keys, req.dev_prompt,
+                    n, slot, float(req.temperature), req.seed)
+            self._count_prefill(req.bucket, n)
         elif hit_len:
             first = self._suffix_admit(slot, req, tbl_row, hit_len)
         else:
-            ex.cache, ex.tok, ex.temp, ex.keys, first = \
-                ex.inserts[req.bucket](
-                    ex.params, ex.cache, tbl_row, ex.tok,
-                    ex.temp, ex.keys, req.dev_prompt, n, slot,
-                    float(req.temperature), req.seed,
-                    *ex.lora_insert_tail(req.adapter_idx))
-            self.stats["prefill_calls"] += 1
-            self.stats["prefill_tokens"] += n
+            with TR.phase("exec.insert", width=req.bucket, tokens=n):
+                ex.cache, ex.tok, ex.temp, ex.keys, first = \
+                    ex.inserts[req.bucket](
+                        ex.params, ex.cache, tbl_row, ex.tok,
+                        ex.temp, ex.keys, req.dev_prompt, n, slot,
+                        float(req.temperature), req.seed,
+                        *ex.lora_insert_tail(req.adapter_idx))
+            self._count_prefill(req.bucket, n)
         # register this lane's full prompt blocks for future admissions
         # (content is valid for any later dispatch — same device stream;
         # adapter lanes publish under their namespace, so reuse happens
@@ -1671,13 +1731,14 @@ class ContinuousBatcher:
         ins = ex.suffix_insert(sb)
         padded = np.zeros((1, sb), np.int32)
         padded[0, :len(suffix)] = suffix
-        ex.cache, ex.tok, ex.temp, ex.keys, first = ins(
-            ex.params, ex.cache, tbl_row, ex.tok, ex.temp,
-            ex.keys, jnp.asarray(padded), len(suffix), hit_len,
-            slot, float(req.temperature), req.seed,
-            *ex.lora_insert_tail(req.adapter_idx))
-        self.stats["prefill_calls"] += 1
-        self.stats["prefill_tokens"] += len(suffix)
+        with TR.phase("exec.insert", width=sb, tokens=len(suffix),
+                      hit_len=hit_len):
+            ex.cache, ex.tok, ex.temp, ex.keys, first = ins(
+                ex.params, ex.cache, tbl_row, ex.tok, ex.temp,
+                ex.keys, jnp.asarray(padded), len(suffix), hit_len,
+                slot, float(req.temperature), req.seed,
+                *ex.lora_insert_tail(req.adapter_idx))
+        self._count_prefill(sb, len(suffix))
         return first
 
     def _admit_chunked(self, slot: int, req: _Request) -> None:
@@ -1688,10 +1749,7 @@ class ContinuousBatcher:
         ex = self.executor
         hit_len = 0
         if self.paged:
-            hit_len, cow = self.pool.admit(
-                slot, req.prompt, max_suffix=self.SUFFIX_PREFILL_MAX_ROWS,
-                ns=req.ns)
-            self._dispatch_cow(slot, cow, hit_len)
+            hit_len = self._pool_admit(slot, req)
             lane_k = lane_v = None
         else:
             lane_k, lane_v = ex.make_staging(req.bucket)
@@ -1710,36 +1768,55 @@ class ContinuousBatcher:
         n = len(req.prompt)
         sb = ex.prefill_chunk
         remaining = n - st.start
-        t_slice0 = time.monotonic()
         if remaining > sb:
             # intermediate slice: KV only, no logits, no lane state
             toks = np.zeros((1, sb), np.int32)
             toks[0, :] = req.prompt[st.start:st.start + sb]
-            if self.paged:
-                tbl_row = jnp.asarray(self.pool.table[slot])
-                args = (ex.params, ex.cache, tbl_row, jnp.asarray(toks),
-                        st.start, st.start + sb)
-                if ex.quant:    # quant slices address the lane's tail
-                    args += (slot,)
-                ex.cache = ex.chunk_prog(None)(
-                    *args, *ex.lora_insert_tail(req.adapter_idx))
-            else:
-                sl = ex.staging_len(req.bucket)
-                st.lane_k, st.lane_v = ex.chunk_prog(sl)(
-                    ex.params, st.lane_k, st.lane_v, jnp.asarray(toks),
-                    st.start, *ex.lora_insert_tail(req.adapter_idx))
+            with TR.phase("exec.insert", width=sb, tokens=sb) as ph:
+                if self.paged:
+                    tbl_row = jnp.asarray(self.pool.table[slot])
+                    args = (ex.params, ex.cache, tbl_row,
+                            jnp.asarray(toks), st.start, st.start + sb)
+                    if ex.quant:  # quant slices address the lane's tail
+                        args += (slot,)
+                    ex.cache = ex.chunk_prog(None)(
+                        *args, *ex.lora_insert_tail(req.adapter_idx))
+                else:
+                    sl = ex.staging_len(req.bucket)
+                    st.lane_k, st.lane_v = ex.chunk_prog(sl)(
+                        ex.params, st.lane_k, st.lane_v,
+                        jnp.asarray(toks), st.start,
+                        *ex.lora_insert_tail(req.adapter_idx))
             st.start += sb
-            self.stats["prefill_calls"] += 1
-            self.stats["prefill_tokens"] += sb
+            self._count_prefill(sb, sb)
             self.stats["chunked_prefill_tokens"] += sb
             if req.trace is not None:
-                req.trace.add("prefill_slice", t_slice0,
+                req.trace.add("prefill_slice", ph.t0, ph.t1,
                               start=st.start - sb, tokens=sb)
             return
         # final slice
         toks = np.zeros((1, sb), np.int32)
         toks[0, :remaining] = req.prompt[st.start:]
         toks = jnp.asarray(toks)
+        with TR.phase("exec.insert", width=sb, tokens=remaining) as ph:
+            first = self._final_slice(slot, st, toks, remaining)
+        self._count_prefill(sb, remaining)
+        self.stats["chunked_prefill_tokens"] += remaining
+        if req.trace is not None:
+            req.trace.add("prefill_slice", ph.t0, ph.t1, start=st.start,
+                          tokens=remaining, final=True)
+        del self._prefilling[slot]
+        if self.paged:
+            self.pool.publish(slot, req.prompt, ns=req.ns)
+        self._activate(slot, req, first)
+
+    def _final_slice(self, slot: int, st: _PrefillState, toks,
+                     remaining: int):
+        """Dispatch the chunked prefill's LAST slice — the final insert
+        of this ring's kind (paged or staged, plain or speculative) —
+        and return the first sampled token (a device future)."""
+        ex, req = self.executor, st.req
+        n = len(req.prompt)
         if self.paged and not self.spec_k:
             ins = ex.final_insert(None)
             ex.cache, ex.tok, ex.temp, ex.keys, first = ins(
@@ -1770,16 +1847,7 @@ class ContinuousBatcher:
                 ex.temp, ex.keys, toks, remaining, st.start, n, slot,
                 float(req.temperature), req.seed,
                 *ex.lora_insert_tail(req.adapter_idx))
-        self.stats["prefill_calls"] += 1
-        self.stats["prefill_tokens"] += remaining
-        self.stats["chunked_prefill_tokens"] += remaining
-        if req.trace is not None:
-            req.trace.add("prefill_slice", t_slice0, start=st.start,
-                          tokens=remaining, final=True)
-        del self._prefilling[slot]
-        if self.paged:
-            self.pool.publish(slot, req.prompt, ns=req.ns)
-        self._activate(slot, req, first)
+        return first
 
     def _admit_disagg(self, slot: int, req: _Request) -> None:
         """Disaggregated admission: a radix prefix HIT admits inline
@@ -1788,24 +1856,21 @@ class ContinuousBatcher:
         fresh decode-pool blocks now (reserved — the handoff can never
         fail on NoFreeBlocks) and ships the prefill to the executor
         thread; the loop attaches the lane when the result lands."""
-        hit_len, cow = self.pool.admit(
-            slot, req.prompt, max_suffix=self.SUFFIX_PREFILL_MAX_ROWS,
-            ns=req.ns)
+        # the post-admit hook (_dispatch_cow) runs on the cold path
+        # too: a hit_len-0 PARTIAL-tail hit can map (and host-promote)
+        # one block whose upload/CoW must not stay pending — the
+        # handoff overwrites the lane's view, but the promoted entry
+        # re-anchored in the radix cache and a later hit on it must
+        # read real bytes
+        hit_len = self._pool_admit(slot, req)
         if hit_len and not self.spec_k:
-            self._dispatch_cow(slot, cow, hit_len)
             first = self._suffix_admit(
                 slot, req, jnp.asarray(self.pool.table[slot]), hit_len)
             self.pool.publish(slot, req.prompt, ns=req.ns)
             self._activate(slot, req, first)
             return
         # cold: fresh blocks are already mapped by admit (hit_len == 0
-        # here unless spec, whose prefix cache is off -> also 0).  The
-        # post-admit hook still runs: a hit_len-0 PARTIAL-tail hit can
-        # map (and host-promote) one block whose upload/CoW must not
-        # stay pending — the handoff overwrites the lane's view, but
-        # the promoted entry re-anchored in the radix cache and a later
-        # hit on it must read real bytes
-        self._dispatch_cow(slot, cow, hit_len)
+        # here unless spec, whose prefix cache is off -> also 0)
         ex = self.executor
         if ex.prefill_remote and req.adapter_idx:
             # remote prefill pods serve the BASE param set: an adapter
@@ -1815,15 +1880,15 @@ class ContinuousBatcher:
             # correctness first; adapter traffic simply skips the
             # remote TTFT win.
             n = len(req.prompt)
-            ex.cache, ex.tok, ex.temp, ex.keys, first = \
-                ex.inserts[req.bucket](
-                    ex.params, ex.cache,
-                    jnp.asarray(self.pool.table[slot]), ex.tok,
-                    ex.temp, ex.keys, req.dev_prompt, n, slot,
-                    float(req.temperature), req.seed,
-                    *ex.lora_insert_tail(req.adapter_idx))
-            self.stats["prefill_calls"] += 1
-            self.stats["prefill_tokens"] += n
+            with TR.phase("exec.insert", width=req.bucket, tokens=n):
+                ex.cache, ex.tok, ex.temp, ex.keys, first = \
+                    ex.inserts[req.bucket](
+                        ex.params, ex.cache,
+                        jnp.asarray(self.pool.table[slot]), ex.tok,
+                        ex.temp, ex.keys, req.dev_prompt, n, slot,
+                        float(req.temperature), req.seed,
+                        *ex.lora_insert_tail(req.adapter_idx))
+            self._count_prefill(req.bucket, n)
             self.pool.publish(slot, req.prompt, ns=req.ns)
             self._activate(slot, req, first)
             return
@@ -2030,8 +2095,9 @@ class ContinuousBatcher:
              ex.keys) = ex._attach(
                 ex.cache["pos"], ex.tok, ex.temp, ex.keys, slot,
                 first, n, float(req.temperature), req.seed)
-        self.stats["prefill_calls"] += 1
-        self.stats["prefill_tokens"] += n
+        # the attach computes no position here (the prefill engine
+        # did): the prompt's tokens, no padding, under their own key
+        self._count_prefill(n, n, bucket="handoff")
         self.stats["disagg_prefills"] += 1
         if req.trace is not None:
             req.trace.add("handoff_attach", t_att0, slot=slot)
@@ -2191,10 +2257,10 @@ class ContinuousBatcher:
         if self._lane_left[slot] <= 0 or req.done.is_set():
             self._evict(slot)       # finished at the boundary anyway
             return
-        t_sp0 = time.monotonic()
-        spill = self.executor.spill_lane(slot)
+        with TR.phase("exec.spill", slot=slot) as ph:
+            spill = self.executor.spill_lane(slot)
         if req.trace is not None:
-            req.trace.add("spill", t_sp0,
+            req.trace.add("spill", ph.t0, ph.t1,
                           pos=int(self._lane_pos[slot]))
         self.flightrec.record("preempt", rid=req.request_id,
                               slot=slot, prio=req.priority)
@@ -2225,14 +2291,14 @@ class ContinuousBatcher:
                 self._finish(req)
             return True
         slot = self.lane.index(None)
-        t_rs0 = time.monotonic()
-        try:
-            self.executor.restore_lane(slot, pk.spill)
-        except self.executor._pg.NoFreeBlocks:
-            self.pool.retire(slot)  # roll back ensure's partial mapping
-            return False
+        with TR.phase("exec.restore", slot=slot) as ph:
+            try:
+                self.executor.restore_lane(slot, pk.spill)
+            except self.executor._pg.NoFreeBlocks:
+                self.pool.retire(slot)  # roll back ensure's partial mapping
+                return False
         if req.trace is not None:
-            req.trace.add("restore", t_rs0, slot=slot)
+            req.trace.add("restore", ph.t0, ph.t1, slot=slot)
         self._parked.remove(pk)
         self.lane[slot] = req
         self._lane_out[slot] = pk.out
@@ -2580,6 +2646,7 @@ class ContinuousBatcher:
                 except Exception as e:
                     self._fault = e
                     return
+                self._tile.to("sched.preempt", lanes=len(todo))
                 for i in todo:
                     r = self.lane[i]
                     if (r is not None and not r.done.is_set()
@@ -2599,6 +2666,7 @@ class ContinuousBatcher:
                 self._kick_migration(pk)
 
     def _loop(self) -> None:
+        TR.use_table(self.phases)
         try:
             self._loop_body()
         except Exception as e:       # unrecoverable failure: fail loudly
@@ -2620,6 +2688,7 @@ class ContinuousBatcher:
             self._finish(pk.req, ShuttingDown("batcher closed"))
         self._parked.clear()
         self._shed_queue(ShuttingDown("batcher closed"))
+        self._tile.close()
 
     def _scrub_lane_blocks(self, slot: int, req=None) -> None:
         """Zero lane ``slot``'s PRIVATE pool blocks before they return
@@ -2762,6 +2831,9 @@ class ContinuousBatcher:
         stuck.  The watchdog region scales with the dispatch's fused
         iteration count — a legal N-step wait is ~N x a 1-step one."""
         chunk_reqs, res, t0 = pending.pop(0)
+        # the host blocked on the device: everything between here and
+        # the next phase is the completion wait
+        self._tile.to("sched.consume_wait", n_steps=res.n_steps)
         wd = self._watchdog
         if wd is not None:
             wd.begin(scale=res.n_steps)
@@ -2773,24 +2845,25 @@ class ContinuousBatcher:
         finally:
             if wd is not None:
                 wd.end()
+        # token bookkeeping, stream puts, evictions — until the caller
+        # moves the loop on
+        t1 = self._tile.to("sched.consume").t0
         # per-iteration wall estimate for the deadline-tick budget:
         # dispatch->consume covers the pipeline wait too, so the EMA
         # overestimates — conservative (a lane freezes a little early
         # and resumes next dispatch, never late)
-        per = (time.monotonic() - t0) / res.n_steps
+        per = (t1 - t0) / res.n_steps
         self._step_s_est = (per if not self._step_s_est
                             else 0.8 * self._step_s_est + 0.2 * per)
         # decode-phase spans (ISSUE 15): one span per consumed
-        # dispatch per traced lane, covering dispatch -> completion
-        # wait — megastep-granular by construction, and bounded by the
+        # dispatch per traced lane, covering dispatch (exec.dispatch's
+        # start) -> completion wait (sched.consume_wait's end) —
+        # megastep-granular by construction, and bounded by the
         # RequestTrace span cap on long generations
-        if any(r is not None and r.trace is not None
-               for _, r in chunk_reqs):
-            t1 = time.monotonic()
-            for _, r in chunk_reqs:
-                if r is not None and r.trace is not None:
-                    r.trace.add("decode_dispatch", t0, t1,
-                                steps=res.n_steps)
+        for _, r in chunk_reqs:
+            if r is not None and r.trace is not None:
+                r.trace.add("decode_dispatch", t0, t1,
+                            steps=res.n_steps)
         if self._fault is not None:
             return              # stall-failed chunks must not apply
         if res.n_steps == 1:
@@ -2822,8 +2895,17 @@ class ContinuousBatcher:
         # chunks N+1..N+depth.  Without this the ring serializes the
         # host's share with compute.  Depth 2 by default; whether depth
         # 1 suffices on a directly attached chip is not re-measured.
-        pending: List[tuple] = []   # [(chunk_reqs, toks, counts, ok)]
+        pending: List[tuple] = []   # [(chunk_reqs, res, t_dispatch)]
+        # the loop thread's time, tiled: at every moment it is inside
+        # exactly one top-level phase (utils/tracing.py Tiling), so the
+        # phase table's self seconds are shares of this loop's wall
+        # time and every gap on the device's clock has a name.  A
+        # ``continue`` lands in sched.housekeeping again.
+        tile = self._tile
         while not self._stop.is_set():
+            # fleet-KV pump, deadlines, cancels, handoff drain, and the
+            # admission loop's own checks
+            tile.to("sched.housekeeping")
             # re-bound every pass: a live swap (ISSUE 19) may have
             # replaced the executor object at the previous boundary
             ex = self.executor
@@ -2835,6 +2917,7 @@ class ContinuousBatcher:
             if self._fault is not None:
                 err, self._fault = self._fault, None
                 pending.clear()
+                tile.to("sched.heal")
                 if not self._heal(err):
                     raise err
                 continue
@@ -2896,6 +2979,7 @@ class ContinuousBatcher:
                         self._fault = e
                         continue
                     if self._fault is None:
+                        tile.to("sched.swap")
                         self._do_swap()
                     continue
                 # lanes still prefilling: fall through (slices advance,
@@ -2941,16 +3025,13 @@ class ContinuousBatcher:
                     self._finish(req)
                     continue
                 slot = self.lane.index(None)
-                t_admit0 = time.monotonic()
+                # one per admitted request; pool.admit (block mapping,
+                # CoW) and exec.insert (the insert program's dispatch,
+                # which carries ``hit_len``) nest inside
+                ph = tile.to("sched.admit", bucket=req.bucket,
+                             prompt_len=len(req.prompt))
                 try:
                     self._admit(slot, req)
-                    if req.trace is not None:
-                        # host time of the admission dispatch (inline:
-                        # the one compiled insert; chunked/disagg: the
-                        # block map/reserve — the slices/handoff get
-                        # their own spans)
-                        req.trace.add("admit", t_admit0, slot=slot,
-                                      mode=self.prefill_mode)
                 except Exception as e:          # bad request: fail it only
                     self._finish(req, e)
                     self.lane[slot] = None
@@ -2962,6 +3043,14 @@ class ContinuousBatcher:
                         # dispatch failed — unmap them (no-op when the
                         # allocator itself rejected)
                         self.pool.retire(slot)
+                t1 = tile.to("sched.housekeeping").t0
+                if req.trace is not None and req.error is None:
+                    # host time of the admission dispatch (inline:
+                    # the one compiled insert; chunked/disagg: the
+                    # block map/reserve — the slices/handoff get
+                    # their own spans)
+                    req.trace.add("admit", ph.t0, t1, slot=slot,
+                                  mode=self.prefill_mode)
             # preemptive lane spill (ISSUE 10): more urgent work is
             # waiting and every lane is busy — quiesce the dispatch
             # pipeline (THE chunk boundary: device state and host
@@ -2978,6 +3067,7 @@ class ContinuousBatcher:
                 if self._fault is None:
                     victim = self._preempt_victim()
                     if victim is not None:
+                        tile.to("sched.preempt", lanes=1)
                         self._preempt(victim)
                 continue
 
@@ -2988,6 +3078,7 @@ class ContinuousBatcher:
                 slot = min(self._prefilling,
                            key=lambda s: self._prefilling[s].seq)
                 req = self._prefilling[slot].req
+                tile.to("sched.prefill_slice", slot=slot)
                 wd = self._watchdog
                 if wd is not None:
                     wd.begin()
@@ -3014,15 +3105,22 @@ class ContinuousBatcher:
                     # no decode work, but prefill in flight: spin the
                     # loop (chunked slices run back-to-back; disagg
                     # handoffs land as soon as they arrive)
+                    tile.to("sched.idle.prefill_pending")
                     self._wake.wait(timeout=0.002)
                     self._wake.clear()
                     continue
+                # nothing resident, nothing queued: the ring waits for
+                # an arrival — at a fixed arrival rate, its headroom
+                tile.to("sched.idle.no_work")
                 self._wake.wait(timeout=0.1)
                 self._wake.clear()
                 continue
             self.stats["max_active"] = max(self.stats["max_active"],
                                            len(active_idx))
 
+            # pool.ensure for the active lanes, the table snapshot, the
+            # ExecPlan's fill
+            tile.to("sched.plan", lanes_live=len(active_idx))
             n_mega = self.megastep
             advance = (self.spec_k + 1) if self.spec_k else self.chunk
             tbl_np = None
@@ -3116,6 +3214,8 @@ class ContinuousBatcher:
             # a synchronous-dispatch backend) wedges HERE — and any
             # raise becomes a ring fault handled at the loop top (fail
             # resident requests retriably, rebuild, back off).
+            ph = tile.to("exec.dispatch", n_steps=n_mega,
+                         lanes_live=len(active_idx))
             wd = self._watchdog
             if wd is not None:
                 wd.begin(scale=n_mega)
@@ -3128,6 +3228,12 @@ class ContinuousBatcher:
                 if wd is not None:
                     wd.end()
             self.stats["chunks"] += 1
+            # device decode iterations this dispatch runs (a spec
+            # round counts its K+1 positions), alone and times the
+            # lanes live in the plan
+            self.stats["decode_steps"] += n_mega * advance
+            self.stats["decode_lane_steps"] += (
+                n_mega * advance * len(active_idx))
             # kick the device->host copy NOW, before the consume wait:
             # by consume time the tokens are already on the wire and
             # np.asarray is a cheap completion wait instead of a full
@@ -3138,7 +3244,7 @@ class ContinuousBatcher:
                 except AttributeError:  # None / interpret-mode ndarray
                     pass
             pending.append(([(i, self.lane[i]) for i in active_idx],
-                            res, time.monotonic()))
+                            res, ph.t0))
             if len(pending) >= self.pipeline_depth:
                 try:
                     self._consume_oldest(pending)

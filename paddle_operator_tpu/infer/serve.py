@@ -992,9 +992,14 @@ def main() -> int:
     from paddle_operator_tpu.launch.launcher import JobEnv
     from paddle_operator_tpu.models.llama import CONFIGS
     from paddle_operator_tpu.train.checkpoint import CheckpointManager
+    from paddle_operator_tpu.utils import tracing as TR
     from paddle_operator_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
+    # every phase of this process is a TraceMe on the profiler's host
+    # plane from here on (utils/tracing.py): whoever starts the profiler
+    # finds the loop's phases on the device's clock
+    TR.set_annotator(jax.profiler.TraceAnnotation)
     env = JobEnv.from_env()
     cfg = CONFIGS[os.environ.get("MODEL_PRESET", "7b")]
     # SERVE_TP=n: tensor-parallel serving over the pod's first n chips

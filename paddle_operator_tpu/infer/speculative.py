@@ -87,6 +87,7 @@ def check_draft_compat(cfg: LlamaConfig, draft_cfg: LlamaConfig) -> None:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("cache_write")
 def _write_rows(cache_l: jax.Array, kv: jax.Array,
                 pos: jax.Array) -> jax.Array:
     """[B, H, S, D] cache layer <- [B, H, T, D] new rows at per-lane
@@ -176,7 +177,7 @@ def _multi_forward(cfg: LlamaConfig, params: Dict[str, Any],
     over a whole slice are the biggest tensor in the prefill path."""
     pos = cache["pos"]
     adp, aid = lora if lora is not None else (None, None)
-    x = params["tok_embed"]["embedding"].astype(cfg.dtype)[toks]
+    x = D._embed(cfg, params, toks)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
 
@@ -198,9 +199,7 @@ def _multi_forward(cfg: LlamaConfig, params: Dict[str, Any],
     new_cache = {"k": k_new, "v": v_new, "pos": pos + toks.shape[1]}
     if not head:
         return None, new_cache
-    x = D._rms(x, params["final_norm"]["scale"], cfg.norm_eps, cfg.dtype)
-    logits = D._mm(x, params["lm_head"]["kernel"],
-                   cfg.dtype).astype(jnp.float32)
+    logits = D._lm_head(cfg, params, x)
     return logits, new_cache
 
 
@@ -408,7 +407,7 @@ def _multi_forward_paged(cfg: LlamaConfig, params: Dict[str, Any],
     :func:`_layer_multi_paged`."""
     pos = cache["pos"]
     adp, aid = lora if lora is not None else (None, None)
-    x = params["tok_embed"]["embedding"].astype(cfg.dtype)[toks]
+    x = D._embed(cfg, params, toks)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                 cfg.rope_theta)
     xs = ((params["layers"], adp, jnp.arange(cfg.n_layers))
@@ -453,9 +452,7 @@ def _multi_forward_paged(cfg: LlamaConfig, params: Dict[str, Any],
         new_cache = {"k": k_new, "v": v_new, "pos": pos + toks.shape[1]}
     if not head:
         return None, new_cache
-    x = D._rms(x, params["final_norm"]["scale"], cfg.norm_eps, cfg.dtype)
-    logits = D._mm(x, params["lm_head"]["kernel"],
-                   cfg.dtype).astype(jnp.float32)
+    logits = D._lm_head(cfg, params, x)
     return logits, new_cache
 
 

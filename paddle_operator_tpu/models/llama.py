@@ -201,6 +201,7 @@ class RMSNorm(nn.Module):
     param_dtype: Any
 
     @nn.compact
+    @jax.named_scope("norm")
     def __call__(self, x: jax.Array) -> jax.Array:
         scale = self.param(
             "scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype
@@ -257,15 +258,19 @@ class Attention(nn.Module):
             param_dtype=cfg.param_dtype,
             kernel_init=nn.initializers.normal(0.02),
         )
+        # the scopes name the same pieces as the serving block's
+        # (infer/decode.py); the module's own name gives the outer "attn"
         b, s, _ = x.shape
-        q = dense("wq", cfg.n_heads * cfg.head_dim)(x)
-        k = dense("wk", cfg.n_kv_heads * cfg.head_dim)(x)
-        v = dense("wv", cfg.n_kv_heads * cfg.head_dim)(x)
-        q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
-        k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-        v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        with jax.named_scope("attn.qkv"):
+            q = dense("wq", cfg.n_heads * cfg.head_dim)(x)
+            k = dense("wk", cfg.n_kv_heads * cfg.head_dim)(x)
+            v = dense("wv", cfg.n_kv_heads * cfg.head_dim)(x)
+            q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
+            k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+            v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        with jax.named_scope("attn.rope"):
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         cp = 1
         if self.mesh is not None:
             cp = dict(zip(self.mesh.axis_names,
@@ -299,8 +304,9 @@ class Attention(nn.Module):
         from jax.ad_checkpoint import checkpoint_name
 
         out = checkpoint_name(out, "attn_out")
-        out = out.reshape(b, s, cfg.n_heads * cfg.head_dim)
-        return dense("wo", cfg.dim)(out)
+        with jax.named_scope("attn.out"):
+            out = out.reshape(b, s, cfg.n_heads * cfg.head_dim)
+            return dense("wo", cfg.dim)(out)
 
 
 class MLP(nn.Module):
@@ -334,18 +340,20 @@ class DecoderLayer(nn.Module):
                     name="attn_norm")(x), cos, sin, segment_ids)
         normed = RMSNorm(cfg.norm_eps, cfg.dtype, cfg.param_dtype,
                          name="mlp_norm")(h)
-        if cfg.n_experts > 0:
-            from paddle_operator_tpu.models.moe import MoEConfig, MoELayer
+        with jax.named_scope("ffn"):
+            if cfg.n_experts > 0:
+                from paddle_operator_tpu.models.moe import MoEConfig, MoELayer
 
-            ffn_out, aux = MoELayer(MoEConfig(
-                dim=cfg.dim, ffn_dim=cfg.ffn_dim, n_experts=cfg.n_experts,
-                capacity_factor=cfg.moe_capacity_factor,
-                top_k=cfg.moe_top_k,
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            ), name="moe")(normed)
-        else:
-            ffn_out, aux = MLP(cfg, name="mlp")(normed), None
-        out = h + ffn_out
+                ffn_out, aux = MoELayer(MoEConfig(
+                    dim=cfg.dim, ffn_dim=cfg.ffn_dim,
+                    n_experts=cfg.n_experts,
+                    capacity_factor=cfg.moe_capacity_factor,
+                    top_k=cfg.moe_top_k,
+                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                ), name="moe")(normed)
+            else:
+                ffn_out, aux = MLP(cfg, name="mlp")(normed), None
+            out = h + ffn_out
         # (carry, scan-output) pair — the scan axis carries the hidden
         # state; the per-layer MoE aux loss rides the scan output (stacked
         # [n_layers] by nn.scan, summed in Llama.__call__).
@@ -441,7 +449,8 @@ class Llama(nn.Module):
         is the summed per-layer load-balancing loss scaled by
         cfg.moe_aux_weight, to be ADDED to the task loss by the trainer."""
         cfg = self.cfg
-        x = embed_module(cfg, name="tok_embed")(tokens)
+        with jax.named_scope("embed"):
+            x = embed_module(cfg, name="tok_embed")(tokens)
         cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                     cfg.rope_theta)
 

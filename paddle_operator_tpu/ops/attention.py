@@ -109,6 +109,7 @@ def sharded_flash_attention(mesh, q: jax.Array, k: jax.Array, v: jax.Array,
                          check_vma=False)(*args)
 
 
+@jax.named_scope("attn.kernel")
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               *, causal: bool = True,
               segment_ids: Optional[jax.Array] = None,

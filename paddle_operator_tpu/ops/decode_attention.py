@@ -188,6 +188,7 @@ def _kernel(len_ref, *refs, scale: float, block_k: int, n_rep: int,
                              o).astype(o_ref.dtype)
 
 
+@jax.named_scope("attn.kernel")
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      lengths: jax.Array, *, scale: Optional[float] = None,
                      layer: Optional[jax.Array] = None,
@@ -361,6 +362,7 @@ def _paged_kernel_quant(len_ref, tbl_ref, *refs, scale: float,
                              o).astype(o_ref.dtype)
 
 
+@jax.named_scope("attn.kernel")
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, block_table: jax.Array,
                            lengths: jax.Array, *,
@@ -562,12 +564,13 @@ def sharded_paged_decode_attention(mesh, q: jax.Array, k_pool: jax.Array,
                                      layer=rest[0] if stacked else None,
                                      interpret=interpret,
                                      **qkw)                 # [B, Hq/tp, D]
-        o = out.reshape(b, -1)
-        if isinstance(wo, dict):
-            o = (o @ wo["q"].astype(dtype)) * wo["s"][..., 0, :].astype(dtype)
-        else:
-            o = o @ wo.astype(dtype)
-        return jax.lax.psum(o, axis_name)                   # [B, E]
+        with jax.named_scope("attn.out"):
+            o = out.reshape(b, -1)
+            if isinstance(wo, dict):
+                o = (o @ wo["q"].astype(dtype)) * wo["s"][..., 0, :].astype(dtype)
+            else:
+                o = o @ wo.astype(dtype)
+            return jax.lax.psum(o, axis_name)                   # [B, E]
 
     in_specs = (head_spec, pool_spec, pool_spec, P(), P(), wo_spec)
     args = (q, k_pool, v_pool, block_table.astype(jnp.int32),
@@ -635,12 +638,13 @@ def sharded_decode_attention(mesh, q: jax.Array, k_cache: jax.Array,
         out = decode_attention(q, kc, vc, lens,
                                layer=lay[0] if stacked else None,
                                interpret=interpret)      # [B, Hq/tp, D]
-        o = out.reshape(b, -1)
-        if isinstance(wo, dict):
-            o = (o @ wo["q"].astype(dtype)) * wo["s"][..., 0, :].astype(dtype)
-        else:
-            o = o @ wo.astype(dtype)
-        return jax.lax.psum(o, axis_name)                # [B, E]
+        with jax.named_scope("attn.out"):
+            o = out.reshape(b, -1)
+            if isinstance(wo, dict):
+                o = (o @ wo["q"].astype(dtype)) * wo["s"][..., 0, :].astype(dtype)
+            else:
+                o = o @ wo.astype(dtype)
+            return jax.lax.psum(o, axis_name)                # [B, E]
 
     fn = jax.shard_map(
         body, mesh=use_mesh,

@@ -23,6 +23,8 @@ from typing import Any, Optional
 
 import jax
 
+from paddle_operator_tpu.utils import tracing as TR
+
 
 class CheckpointManager:
     """Thin orbax wrapper bound to the injected checkpoint path."""
@@ -77,15 +79,20 @@ class CheckpointManager:
 
         will_save = force or getattr(self._mgr, "should_save",
                                      lambda s: True)(step)
-        if will_save and jax.default_backend() == "cpu" \
-                and jax.process_count() == 1:
-            import numpy as np
+        if not will_save:
+            return self._mgr.save(step, args=ocp.args.StandardSave(state),
+                                  force=force)
+        # what the caller's thread pays for a save: the snapshot and
+        # whatever of the copy-out the async writer does before returning
+        with TR.phase("train.checkpoint_save", step=step):
+            if jax.default_backend() == "cpu" and jax.process_count() == 1:
+                import numpy as np
 
-            state = jax.tree_util.tree_map(
-                lambda x: np.array(x) if isinstance(x, jax.Array) else x,
-                state)
-        return self._mgr.save(step, args=ocp.args.StandardSave(state),
-                              force=force)
+                state = jax.tree_util.tree_map(
+                    lambda x: np.array(x) if isinstance(x, jax.Array)
+                    else x, state)
+            return self._mgr.save(step, args=ocp.args.StandardSave(state),
+                                  force=force)
 
     def restore(self, state_like: Any, step: Optional[int] = None) -> Any:
         """Restore into the sharding/structure of `state_like` (an abstract

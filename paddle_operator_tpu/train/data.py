@@ -25,6 +25,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding
 
 from paddle_operator_tpu.parallel.sharding import batch_sharding
+from paddle_operator_tpu.utils import tracing as TR
 
 
 def synthetic_lm_batches(batch_size: int, seq_len: int, vocab: int,
@@ -193,12 +194,13 @@ class DevicePrefetcher:
 
     def _place(self, batch: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
         out = {}
-        for k, v in batch.items():
-            if jax.process_count() > 1:
-                out[k] = jax.make_array_from_process_local_data(
-                    self.sharding, v)
-            else:
-                out[k] = jax.device_put(v, self.sharding)
+        with TR.phase("data.place"):    # on the prefetcher's thread
+            for k, v in batch.items():
+                if jax.process_count() > 1:
+                    out[k] = jax.make_array_from_process_local_data(
+                        self.sharding, v)
+                else:
+                    out[k] = jax.device_put(v, self.sharding)
         return out
 
     def _fill(self) -> None:

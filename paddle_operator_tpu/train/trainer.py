@@ -16,6 +16,7 @@ and TPU-shaped:
 
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import flax.linen as nn
@@ -26,6 +27,7 @@ from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddle_operator_tpu.parallel.sharding import batch_sharding, tree_shardings
+from paddle_operator_tpu.utils import tracing as TR
 
 
 class TrainState(struct.PyTreeNode):
@@ -155,6 +157,7 @@ def create_state(model: nn.Module, optimizer: optax.GradientTransformation,
         return jax.jit(init_fn, out_shardings=shardings)(rng)
 
 
+@jax.named_scope("loss")
 def cross_entropy_loss(logits: jax.Array, targets: jax.Array,
                        mask: Optional[jax.Array] = None
                        ) -> Tuple[jax.Array, jax.Array]:
@@ -200,9 +203,10 @@ def make_grads_train_step(compute_grads,
             opt_state = jax.tree_util.tree_map(
                 jax.device_put, opt_state, dev_opt_sh)
         metrics, grads = compute_grads(state.params, batch)
-        updates, new_opt = optimizer.update(grads, opt_state,
-                                            state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("opt_update"):
+            updates, new_opt = optimizer.update(grads, opt_state,
+                                                state.params)
+            new_params = optax.apply_updates(state.params, updates)
         if in_jit_offload:
             new_opt = jax.tree_util.tree_map(
                 jax.device_put, new_opt, host_opt_sh)
@@ -680,11 +684,18 @@ def fit(state: TrainState, step_fn: Callable, batches,
     the final state and the per-step float metrics history.
     """
     raw_history: List[Dict[str, Any]] = []
+    # this process holds jax: its phases (utils/tracing.py) are profiler
+    # annotations too from here on, on the device's clock whenever
+    # anyone starts the profiler
+    TR.set_annotator(jax.profiler.TraceAnnotation)
     # One sync up front; per-step host conversion would block on every
     # step's completion and defeat async dispatch + prefetch overlap.
     start_step = int(state.step)
     step_no = start_step
     it = iter(batches)
+    # the last log line's time and phase seconds: the next one says what
+    # share of the interval went to waiting for data and to saving
+    logged = (time.monotonic(), TR.PHASES.seconds())
     if goodput is not None:
         # Disarm the step clock: the gap since the tracker's last tick
         # (init, restore, a previous fit segment's drain) is not
@@ -697,30 +708,43 @@ def fit(state: TrainState, step_fn: Callable, batches,
         if preemption is not None and preemption.draining:
             break
         try:
-            batch = next(it)
+            with TR.phase("train.data_wait"):
+                batch = next(it)
         except StopIteration:
             break
-        state, metrics = step_fn(state, batch)
+        step_no = start_step + i + 1
+        with jax.profiler.StepTraceAnnotation("train", step_num=step_no), \
+                TR.phase("train.step_dispatch"):
+            state, metrics = step_fn(state, batch)
         if timer is not None:
             timer.tick()
         if goodput is not None:
             goodput.tick()
-        step_no = start_step + i + 1
         if eval_fn is not None and eval_every and step_no % eval_every == 0:
             metrics = dict(metrics)
-            metrics.update({f"eval_{k}": v
-                            for k, v in eval_fn(state).items()})
+            with TR.phase("train.eval"):
+                metrics.update({f"eval_{k}": v
+                                for k, v in eval_fn(state).items()})
             if goodput is not None:
                 goodput.pause()   # eval gap is not productive step time
         raw_history.append(metrics)   # device scalars: no host sync
         if checkpoint is not None and checkpoint.enabled:
-            checkpoint.save(step_no, state)
+            checkpoint.save(step_no, state)     # train.checkpoint_save
         if logger is not None and log_every and (i + 1) % log_every == 0:
-            msg = (f"step={step_no} "
-                   f"loss={float(metrics.get('loss', float('nan'))):.4f}")
-            if timer is not None:
-                msg += " " + timer.report()
-            logger.info(msg)
+            # the loss's float() is this loop's one device sync
+            with TR.phase("train.log"):
+                msg = (f"step={step_no} loss="
+                       f"{float(metrics.get('loss', float('nan'))):.4f}")
+                if timer is not None:
+                    msg += " " + timer.report()
+                now = (time.monotonic(), TR.PHASES.seconds())
+                wall = max(now[0] - logged[0], 1e-9)
+                for name in ("data_wait", "checkpoint_save"):
+                    spent = (now[1].get("train." + name, 0.0)
+                             - logged[1].get("train." + name, 0.0))
+                    msg += f" {name}={100.0 * spent / wall:.1f}%"
+                logged = now
+                logger.info(msg)
     if preemption is not None and preemption.draining:
         # Drain sequence (docs/fault-tolerance.md): the step that was in
         # flight when the signal landed has completed above; force a

@@ -7,13 +7,15 @@ kit:
 - :func:`get_logger` — structured (key=value) logging with rank prefix.
 - :class:`StepTimer` — rolling step-time/throughput/MFU accounting for
   training loops (what bench.py measures, as a reusable component).
-- :func:`trace` — context manager around ``jax.profiler`` writing a
-  TensorBoard-loadable trace (XLA ops, fusion view) to a directory.
+- :func:`serving_gauges` / :func:`histogram_exposition` — the
+  ``tpujob_serve_*`` Prometheus rendering of a ``status.serving`` block.
+
+Spans live in ``utils/tracing.py`` (:func:`~tracing.phase`); a profile
+of a live process is started from outside it (docs/observability.md).
 """
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
 import time
@@ -120,6 +122,7 @@ def serving_gauges(status_serving: dict, job: str,
     block always did, so existing dashboards keep reading."""
     out = _serving_gauges_one(status_serving, job, replica)
     _qos_gauges(out, status_serving, job, replica)
+    _counter_gauges(out, status_serving, job, replica)
     for rid, blk in sorted(
             (status_serving.get("replicas") or {}).items()):
         if isinstance(blk, dict):
@@ -185,6 +188,38 @@ def _qos_gauges(out: dict, status_serving: dict, job: str,
     for name in status_serving.get("adapterNames") or ():
         out[("tpujob_serve_adapter_loaded"
              f'{{job="{job}"{rep},adapter="{name}"}}')] = 1.0
+
+
+def _counter_gauges(out: dict, status_serving: dict, job: str,
+                    replica: str = None) -> None:
+    """The ring's raw cumulative counters (top-level block only, like
+    the QoS gauges): decode dispatches, the device decode iterations
+    they ran and those times the lanes live in each plan; insert
+    programs dispatched (by program width), the real tokens they
+    prefilled and the positions they computed; and the loop thread's
+    self seconds and counts by phase.  Counters, so a dashboard takes
+    ``rate()`` and forms the ratios itself (padding share, lane
+    occupancy, the loop's time by phase: docs/observability.md)."""
+    rep = f',replica="{replica}"' if replica else ""
+    lbl = f'{{job="{job}"{rep}}}'
+    for key, name in (
+            ("dispatchesTotal", "dispatches"),
+            ("decodeStepsTotal", "decode_steps"),
+            ("decodeLaneStepsTotal", "decode_lane_steps"),
+            ("prefillTokensTotal", "prefill_tokens"),
+            ("prefillBucketTokensTotal", "prefill_bucket_tokens")):
+        out[f"tpujob_serve_{name}_total{lbl}"] = \
+            float(status_serving.get(key, 0.0))
+    for bucket, n in (status_serving.get("prefillCallsByBucket")
+                      or {}).items():
+        out[("tpujob_serve_prefill_calls_total"
+             f'{{job="{job}"{rep},bucket="{bucket}"}}')] = float(n)
+    counts = status_serving.get("phaseCounts") or {}
+    for ph, sec in (status_serving.get("phaseSeconds") or {}).items():
+        plbl = f'{{job="{job}"{rep},phase="{ph}"}}'
+        out[f"tpujob_serve_phase_seconds_total{plbl}"] = float(sec)
+        out[f"tpujob_serve_phase_count_total{plbl}"] = \
+            float(counts.get(ph, 0))
 
 
 def _serving_gauges_one(status_serving: dict, job: str,
@@ -381,16 +416,3 @@ def render_histogram_lines(name: str, entry: dict,
     lines.append(f'{name}_count{labels} '
                  f'{int(entry.get("count", 0))}')
     return lines
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """``with trace('/tmp/trace'):`` profiles the enclosed steps; load the
-    result in TensorBoard (or xprof) for the XLA op/fusion timeline."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

@@ -32,6 +32,15 @@ observability kit the whole stack wires through:
   transitions, chaos injection) that dumps JSON on watchdog restart,
   chaos injection and SIGTERM, and is served at ``/debug/flightrec``.
 
+- **Phases** — :func:`phase` is the one span every hot loop of the
+  program uses (the scheduler's loop, the trainer's ``fit``, the
+  prefetcher's thread).  A phase is at once an entry in a
+  :class:`PhaseTable` (count, inclusive and SELF seconds by name — the
+  raw counters ``/statusz`` exports), a profiler annotation on the
+  device's clock once a jax-holding process has called
+  :func:`set_annotator`, and the pair of timestamps the request spans
+  above are stamped from.
+
 Everything here is stdlib-only — the router and controller processes
 import it without jax.
 """
@@ -243,6 +252,142 @@ class Tracer:
         tid, parent = ctx if ctx is not None else (None, None)
         return RequestTrace(trace_id=tid, parent=parent, pod=self.pod,
                             request_id=request_id)
+
+
+# ---------------------------------------------------------------------------
+# Phases
+# ---------------------------------------------------------------------------
+
+
+class PhaseTable:
+    """Per phase name: how often it ran, its inclusive seconds and its
+    SELF seconds (its duration less what its child phases covered), all
+    cumulative.  Self seconds of one thread's phases add up to the time
+    that thread spent inside any phase, however they nest — which is
+    what makes them shares of a loop's wall time."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._rows: Dict[str, List[float]] = {}   # [count, incl, self]
+
+    def add(self, name: str, seconds: float, self_seconds: float) -> None:
+        with self._lock:
+            row = self._rows.get(name)
+            if row is None:
+                row = self._rows[name] = [0, 0.0, 0.0]
+            row[0] += 1
+            row[1] += seconds
+            row[2] += self_seconds
+
+    def _column(self, i: int) -> Dict[str, Any]:
+        with self._lock:
+            return {k: r[i] for k, r in sorted(self._rows.items())}
+
+    def counts(self) -> Dict[str, int]:
+        return self._column(0)
+
+    def seconds(self) -> Dict[str, float]:
+        return self._column(1)
+
+    def self_seconds(self) -> Dict[str, float]:
+        return self._column(2)
+
+
+# the process's table; a thread that serves one of several rings in a
+# process (tests run many) records into that ring's own (use_table)
+PHASES = PhaseTable()
+
+_annotator = None               # set_annotator(); None: no profiler spans
+_local = threading.local()      # .stack of open phases, .table
+
+
+def set_annotator(factory) -> None:
+    """``factory(name, **attrs)`` returns a context manager entered and
+    left with every phase.  The processes that hold jax install
+    ``jax.profiler.TraceAnnotation`` once, at start-up: every phase is
+    then a TraceMe on the profiler's host plane, on one clock with the
+    device's planes whenever anyone starts the profiler — and costs a
+    flag check when nobody has.  ``None`` takes it out again."""
+    global _annotator
+    _annotator = factory
+
+
+def use_table(table: Optional[PhaseTable]) -> None:
+    """The calling thread's phases go to ``table`` from here on (None:
+    back to :data:`PHASES`)."""
+    _local.table = table
+
+
+class phase:
+    """``with phase("sched.admit", bucket=256) as ph:`` — one span of
+    the calling thread.  Phases nest through a per-thread stack; on the
+    way out the phase adds itself to the thread's table and its
+    duration to its parent's children.  ``ph.t0``/``ph.t1`` are the
+    ``time.monotonic()`` stamps, for the request span of the same
+    interval (``RequestTrace.add(name, ph.t0, ph.t1)``).  Never a
+    device sync: a phase sits where the host already calls, dispatches
+    or blocks."""
+
+    __slots__ = ("name", "attrs", "t0", "t1", "_children", "_ann")
+
+    def __init__(self, name: str, **attrs) -> None:
+        self.name = name
+        self.attrs = attrs
+        self.t0 = self.t1 = None
+        self._children = 0.0
+        self._ann = None
+
+    def __enter__(self) -> "phase":
+        return self._begin(time.monotonic())
+
+    def __exit__(self, *exc) -> None:
+        self._end(time.monotonic())
+
+    def _begin(self, now: float) -> "phase":
+        stack = getattr(_local, "stack", None)
+        if stack is None:
+            stack = _local.stack = []
+        stack.append(self)
+        if _annotator is not None:
+            self._ann = _annotator(self.name, **self.attrs)
+            self._ann.__enter__()
+        self.t0 = now
+        return self
+
+    def _end(self, now: float) -> None:
+        self.t1 = now
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        stack = _local.stack
+        stack.pop()
+        seconds = now - self.t0
+        if stack:
+            stack[-1]._children += seconds
+        (getattr(_local, "table", None) or PHASES).add(
+            self.name, seconds, seconds - self._children)
+
+
+class Tiling:
+    """A loop thread's top-level phases laid end to end: :meth:`to`
+    ends the open phase and begins the next on ONE clock reading, so
+    the thread is inside exactly one at every moment and the self
+    seconds of its phases add up to its wall time.  Phases opened with
+    ``with phase(...)`` meanwhile nest inside the open one."""
+
+    def __init__(self) -> None:
+        self.open: Optional[phase] = None
+
+    def to(self, name: str, **attrs) -> phase:
+        now = time.monotonic()
+        if self.open is not None:
+            self.open._end(now)
+        self.open = phase(name, **attrs)._begin(now)
+        return self.open
+
+    def close(self) -> None:
+        if self.open is not None:
+            self.open._end(time.monotonic())
+            self.open = None
 
 
 # ---------------------------------------------------------------------------
@@ -471,8 +616,6 @@ class FlightRecorder:
         self.pod = pod or str(os.getpid())
         self._ring: "deque[Dict[str, Any]]" = deque(maxlen=capacity)
         self._lock = threading.Lock()
-        self.dumps = 0
-        self.last_dump_path: Optional[str] = None
 
     def record(self, kind: str, **detail) -> None:
         ev = {"t": round(time.time(), 3), "kind": str(kind)}
@@ -508,8 +651,6 @@ class FlightRecorder:
             os.replace(tmp, path)
         except OSError:
             return None
-        self.dumps += 1
-        self.last_dump_path = path
         return path
 
 

@@ -217,7 +217,38 @@ class TestGaugeNaming:
             'tpujob_serve_generation{job="default/j"}',
             'tpujob_serve_tp{job="default/j"}',
             'tpujob_serve_weight_swaps_total{job="default/j"}',
+            # raw counters (ISSUE 26): always rendered; the per-bucket
+            # and per-phase series only with their sub-blocks
+            'tpujob_serve_dispatches_total{job="default/j"}',
+            'tpujob_serve_decode_steps_total{job="default/j"}',
+            'tpujob_serve_decode_lane_steps_total{job="default/j"}',
+            'tpujob_serve_prefill_tokens_total{job="default/j"}',
+            'tpujob_serve_prefill_bucket_tokens_total'
+            '{job="default/j"}',
         }
+
+    def test_counter_gauges_by_bucket_and_phase(self):
+        """ISSUE 26: the raw counters render as ``_total`` series, the
+        per-width insert counts labeled ``bucket`` and the loop
+        thread's self seconds and counts labeled ``phase``."""
+        g = serving_gauges(
+            {"dispatchesTotal": 7, "decodeStepsTotal": 56,
+             "decodeLaneStepsTotal": 600, "prefillTokensTotal": 900,
+             "prefillBucketTokensTotal": 4608,
+             "prefillCallsByBucket": {"512": 1, "4096": 1},
+             "phaseSeconds": {"sched.idle.no_work": 1.5,
+                              "exec.dispatch": 0.25},
+             "phaseCounts": {"sched.idle.no_work": 15,
+                             "exec.dispatch": 7}}, "ns/x", replica="r0")
+        lbl = 'job="ns/x",replica="r0"'
+        assert g[f"tpujob_serve_dispatches_total{{{lbl}}}"] == 7.0
+        assert g[f"tpujob_serve_decode_lane_steps_total{{{lbl}}}"] == 600.0
+        assert g["tpujob_serve_prefill_calls_total"
+                 f'{{{lbl},bucket="4096"}}'] == 1.0
+        assert g["tpujob_serve_phase_seconds_total"
+                 f'{{{lbl},phase="sched.idle.no_work"}}'] == 1.5
+        assert g["tpujob_serve_phase_count_total"
+                 f'{{{lbl},phase="exec.dispatch"}}'] == 7.0
 
     def test_fleet_block_adds_replica_labeled_gauges(self):
         """ISSUE 9: per-replica blocks under ``replicas`` render with a
@@ -410,7 +441,25 @@ class TestBatcherServingStatus:
                            "watchdogRestarts", "quarantinedLanes",
                            # live weight swap block (ISSUE 19)
                            "weightGeneration", "servingTp",
-                           "weightSwaps"}
+                           "weightSwaps",
+                           # raw counters and the loop's phase table
+                           # (ISSUE 26)
+                           "dispatchesTotal", "decodeStepsTotal",
+                           "decodeLaneStepsTotal", "prefillCallsTotal",
+                           "prefillTokensTotal",
+                           "prefillBucketTokensTotal",
+                           "prefillCallsByBucket", "phaseSeconds",
+                           "phaseCounts"}
+        # one 3-token prompt through the 16-wide insert, 3 more tokens
+        # from 2-tick chunks on the one lane
+        assert st["prefillCallsTotal"] == 1
+        assert st["prefillTokensTotal"] == 3
+        assert st["prefillBucketTokensTotal"] == 16
+        assert st["prefillCallsByBucket"] == {"16": 1}
+        assert st["decodeStepsTotal"] == 2 * st["dispatchesTotal"]
+        assert st["decodeLaneStepsTotal"] == st["decodeStepsTotal"] >= 3
+        assert st["phaseCounts"]["sched.admit"] == 1
+        assert st["phaseSeconds"]["exec.dispatch"] > 0
         assert st["prefillMode"] == "inline"
         assert st["prefillQueueDepth"] == 0
         assert st["kvQuantMode"] == "none"     # bf16 default

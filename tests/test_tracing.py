@@ -260,7 +260,7 @@ class TestFlightRecorder:
         with pytest.raises(RuntimeError, match="chaos"):
             batcher.executor.replay("p1")                # dispatch 1
         assert inj.fired == [("dispatch_fail", 1)]
-        dump = json.loads(Path(fr.last_dump_path).read_text())
+        dump = json.loads(Path(fr.default_path()).read_text())
         assert dump["reason"] == "chaos:dispatch_fail"
         ev = [e for e in dump["events"]
               if e["kind"] == "chaos_injected"]
@@ -343,6 +343,8 @@ def _rendered_metric_names():
     sample = {
         "prefillMode": "chunked", "kvQuantMode": "int8",
         "priorityQueueDepth": [1], "adapterNames": ["a"],
+        "prefillCallsByBucket": {"256": 1},
+        "phaseSeconds": {"sched.admit": 1.0},
         "fleet": {"replicasDesired": 1, "prefillReplicasDesired": 1,
                   "generationMin": 0},
     }
