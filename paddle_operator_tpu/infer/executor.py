@@ -1183,20 +1183,13 @@ class PrefillExecutor:
                 self._upload_prog = PG.make_promote_blocks(
                     self.block_size, quant=self.quant, donate=False)
         else:
-            # the prefill engine's OWN bucket ladder, FINER than the
-            # ring's (block-multiple powers of two up to the ring's
-            # largest bucket): prefill is stateless-per-job, so it can
-            # afford shapes near the prompt length — a 300-token cold
-            # prompt runs a 512-row forward instead of the ring's
-            # padded 2048-row bucket.  Phases shaping independently is
+            # the prefill engine's OWN ladder, by the ring's rule
+            # (_default_buckets) up to the ring's largest bucket:
+            # prefill is stateless-per-job, so it can afford shapes
+            # near the prompt length whatever coarser ladder the ring
+            # was given explicitly.  Phases shaping independently is
             # the DistServe argument.
-            cap = max(buckets)
-            ladder = []
-            b = self.block_size
-            while b < cap:
-                ladder.append(b)
-                b *= 2
-            self.buckets = tuple(ladder) + (cap,)
+            self.buckets = _default_buckets(max(buckets), self.block_size)
             self._progs = {b: make_disagg_prefill(
                 cfg, b, self.block_size, top_k, top_p, mesh=mesh,
                 quant=self.quant) for b in self.buckets}
@@ -1681,7 +1674,7 @@ class RingExecutor:
         self.check_finite = check_finite
         self.prefill_mode = prefill_mode
         self.buckets = tuple(sorted(prefill_buckets)) or _default_buckets(
-            max_len)
+            max_len, int(block_size) if paged else 1)
         self.top_k, self.top_p = top_k, top_p
         self.paged = bool(paged)
         self.pool: Optional[Any] = None
@@ -1747,6 +1740,9 @@ class RingExecutor:
         self.megastep = max(1, int(megastep))
         self._mega: Dict[int, Any] = {}
         self._suffix_inserts: Dict[int, Any] = {}
+        # the jitted inserts that compile_inserts replaced by their
+        # executables (empty: ``inserts`` still holds the lazy jits)
+        self._insert_jits: Dict[int, Any] = {}
         # chunked-prefill compile caches: intermediate slice + final
         # insert programs, keyed by staging length (contiguous) or just
         # the fixed slice bucket (paged — writes are table-driven)
@@ -1933,6 +1929,13 @@ class RingExecutor:
                 draft_params = D.shard_params_for_serving(
                     draft_params, self.draft_cfg, self.mesh)
         old, old_draft = self.params, self.draft_params
+        if self._insert_jits and (_abstract_tree((params, draft_params))
+                                  != _abstract_tree((old, old_draft))):
+            # the executables were compiled for the old tree (another
+            # weight-quant mode has other leaves): back to the jitted
+            # functions, which re-trace lazily like every other program
+            self.inserts.update(self._insert_jits)
+            self._insert_jits = {}
         self.params = params
         if self.spec_k:
             self.draft_params = draft_params
@@ -2367,6 +2370,47 @@ class RingExecutor:
         return (D.alloc_kv_buffer(self.cfg, shape, self.mesh),
                 D.alloc_kv_buffer(self.cfg, shape, self.mesh))
 
+    # -- every rung ready before the ring is ------------------------------
+
+    def _insert_operands(self, cache, dcache, row, tok, temp, keys,
+                         prompt, tail) -> tuple:
+        """A whole-prompt insert's operands in the order every call
+        site passes them (scheduler ``_admit``/``_admit_paged``), for
+        this ring's mode: prompt length 1, lane 0, greedy, seed 0."""
+        head = ((self.params, self.draft_params, cache, dcache)
+                if self.spec_k else (self.params, cache))
+        if self.paged:
+            head += (row,)
+        return head + (tok, temp, keys, prompt, 1, 0, 0.0, 0) + tuple(tail)
+
+    def compile_inserts(self) -> None:
+        """Make every rung's insert an executable NOW: lowered from the
+        abstract shapes of the ring's own state (no buffers, no second
+        pool) and compiled, and ``self.inserts`` then holds the
+        executables, so the first prompt on a rung neither traces nor
+        asks the compile cache.  For the serving entry point, before
+        it listens (infer/serve.py main; whatever SERVE_PREWARM says) —
+        a ring built directly stays lazy and pays only for the rungs it
+        is sent.  Only inline rings dispatch ``inserts``: chunked and
+        disaggregated rings admit through their own programs.
+
+        An executable is bound to its operands' shapes, dtypes and
+        tree; :meth:`swap_weights` puts the jitted functions back when
+        a new checkpoint's tree differs."""
+        if self.prefill_mode != "inline" or self._insert_jits:
+            return
+        row = (jax.ShapeDtypeStruct((self.pool.max_blocks,), jnp.int32)
+               if self.paged else None)
+        tail = self.lora_insert_tail(0)
+        jits = dict(self.inserts)
+        for b, fn in jits.items():
+            operands = self._insert_operands(
+                self.cache, self.dcache, row, self.tok, self.temp,
+                self.keys, jax.ShapeDtypeStruct((1, b), jnp.int32), tail)
+            self.inserts[b] = fn.lower(
+                *jax.tree.map(_abstract, operands)).compile()
+        self._insert_jits = jits
+
     # -- prewarm -----------------------------------------------------------
 
     def prewarm(self) -> None:
@@ -2435,26 +2479,18 @@ class RingExecutor:
                 out = prog(self.params, cache, tok, temp, keys, active,
                            eos, left, stp, *st)
                 cache, tok = out[0], out[1]
+        row = (jnp.zeros((self.pool.max_blocks,), jnp.int32)
+               if self.paged else None)
         for b in self.buckets:
-            prompt = jnp.zeros((1, b), jnp.int32)
-            if self.spec_k and self.paged:
-                row = jnp.zeros((self.pool.max_blocks,), jnp.int32)
-                cache, dcache, tok, temp, keys, _ = self.inserts[b](
-                    self.params, self.draft_params, cache, dcache, row,
-                    tok, temp, keys, prompt, 1, 0, 0.0, 0)
-            elif self.spec_k:
-                cache, dcache, tok, temp, keys, _ = self.inserts[b](
-                    self.params, self.draft_params, cache, dcache, tok,
-                    temp, keys, prompt, 1, 0, 0.0, 0)
-            elif self.paged:
-                row = jnp.zeros((self.pool.max_blocks,), jnp.int32)
-                cache, tok, temp, keys, _ = self.inserts[b](
-                    self.params, cache, row, tok, temp, keys, prompt,
-                    1, 0, 0.0, 0, *it)
+            if b in self._insert_jits:
+                continue        # an executable already (compile_inserts)
+            out = self.inserts[b](*self._insert_operands(
+                cache, dcache, row, tok, temp, keys,
+                jnp.zeros((1, b), jnp.int32), it))
+            if self.spec_k:
+                cache, dcache, tok, temp, keys = out[:5]
             else:
-                cache, tok, temp, keys, _ = self.inserts[b](
-                    self.params, cache, tok, temp, keys, prompt, 1, 0,
-                    0.0, 0, *it)
+                cache, tok, temp, keys = out[:4]
         if self.paged and not self.spec_k:
             # the SUFFIX-insert ladder: a radix prefix hit (even a
             # partial-tail one on an otherwise cold prompt) admits
@@ -2645,13 +2681,38 @@ class RingExecutor:
                         cache, tok, temp, keys = out[:4]
 
 
-def _default_buckets(max_len: int) -> Tuple[int, ...]:
-    """2-3 prefill compile buckets, always ending at max_len so every
-    admissible prompt has a bucket."""
+def _abstract(x: Any) -> Any:
+    """What ``jit`` would see of an insert's operand: a device array's
+    shape, dtype and (where it is committed to one) sharding; a Python
+    scalar weakly typed, as a traced call takes it."""
+    if isinstance(x, jax.ShapeDtypeStruct):
+        return x
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+    return jax.ShapeDtypeStruct((), jnp.result_type(x), weak_type=True)
+
+
+def _abstract_tree(tree: Any) -> tuple:
+    leaves, treedef = jax.tree.flatten(tree)
+    return treedef, [_abstract(x) for x in leaves]
+
+
+def _default_buckets(max_len: int, block: int = 1) -> Tuple[int, ...]:
+    """The prefill ladder of a ring ``max_len`` long: from 64 (rounded
+    up to whole blocks of ``block``) doubling while below ``max_len``,
+    then ``max_len`` itself, so every admissible prompt has a rung and
+    none computes more than twice its own positions beyond the first.
+    Where the top step is a whole doubling its 3:2 midpoint is a rung
+    too: the widest programs cost the most a position (attention is
+    quadratic in the width), so padding is dearest there.
+    Block 256, ``max_len`` 4096: 256, 512, 1024, 2048, 3072, 4096."""
     out: List[int] = []
-    b = 64
-    while b < max_len and len(out) < 2:
+    b = -(-64 // block) * block
+    while b < max_len:
         out.append(b)
-        b *= 8
+        b *= 2
+    if out and max_len == 2 * out[-1] and (max_len * 3 // 4) % block == 0:
+        out.append(max_len * 3 // 4)
     out.append(max_len)
     return tuple(out)

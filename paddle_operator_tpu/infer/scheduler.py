@@ -594,11 +594,9 @@ class ContinuousBatcher:
         # that needs them, charging one unlucky request a full XLA
         # compile — tens of seconds for a big model.
         self.prewarmed = threading.Event()
+        self.prewarmed.set()
         if prewarm:
-            threading.Thread(target=self._prewarm, daemon=True,
-                             name="prefill-prewarm").start()
-        else:
-            self.prewarmed.set()
+            self.start_prewarm()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="decode-ring")
         self._thread.start()
@@ -688,6 +686,15 @@ class ContinuousBatcher:
     @property
     def _suffix_inserts(self):
         return self.executor._suffix_inserts
+
+    def start_prewarm(self) -> None:
+        """Run the executor's prewarm off-thread; ``prewarmed`` is set
+        again when it is done.  What ``prewarm=True`` does at
+        construction, for a caller that has something to do first
+        (infer/serve.py main compiles the inserts ahead)."""
+        self.prewarmed.clear()
+        threading.Thread(target=self._prewarm, daemon=True,
+                         name="prefill-prewarm").start()
 
     def _prewarm(self) -> None:
         try:

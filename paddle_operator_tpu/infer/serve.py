@@ -869,6 +869,17 @@ def make_server(host: str, port: int, params: Any, cfg: LlamaConfig,
     return srv
 
 
+def ready_ring(batcher, *, prewarm: bool) -> None:
+    """What the entry point does to a built ring before it listens, so
+    before ``/readyz`` can answer: every rung of the prefill ladder
+    gets its insert compiled ahead (``RingExecutor.compile_inserts``:
+    no first prompt on a rung traces or compiles), then the off-thread
+    prewarm of the other programs starts unless opted out."""
+    batcher.executor.compile_inserts()
+    if prewarm:
+        batcher.start_prewarm()
+
+
 def wire_fleet_kv_from_env(batcher, port: int) -> None:
     """Fleet-level KV client wiring (ISSUE 12, docs/serving.md
     "Fleet-level KV"): ``SERVE_KV_MIGRATE=1`` drains by MIGRATION
@@ -1191,10 +1202,6 @@ def main() -> int:
         megastep = int(os.environ.get("SERVE_MEGASTEP", "0") or 0)
         if megastep > 1:
             ring_kw["megastep"] = megastep
-        # SERVE_PREWARM=0 opts out of the off-thread compile prewarm
-        # (the first long prompt then pays the per-bucket insert
-        # compile — the lazy-compile cliff the prewarm exists to hide)
-        ring_kw["prewarm"] = os.environ.get("SERVE_PREWARM", "1") == "1"
         # SERVE_TRACE=1 (ISSUE 15, docs/observability.md): per-request
         # span capture — requests carry X-Tpujob-Trace contexts, phase
         # spans ride response metadata, and the router stitches
@@ -1321,6 +1328,12 @@ def main() -> int:
 
     batcher = srv.generator.batcher if continuous else None
     if batcher is not None:
+        # SERVE_PREWARM=0 opts out of the off-thread warm RUN of the
+        # step, suffix-ladder, megastep and chunked/disagg programs
+        # (and of its throwaway second pool); the prefill inserts are
+        # executables before this process listens either way
+        ready_ring(batcher,
+                   prewarm=os.environ.get("SERVE_PREWARM", "1") == "1")
         # TPUJOB_CHAOS: deterministic fault injection on the live ring
         # (smoke-testing a deployment's resilience end-to-end)
         maybe_install_from_env(batcher)
