@@ -378,6 +378,16 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
 
     ``lora``: ``(adp, aid)`` — stacked [L, ...] adapter arrays riding
     the layer scan as xs, per-row adapter ids (infer/qos.py)."""
+    if not isinstance(cfg, LlamaConfig):
+        # another architecture's block (the preset's type selects it)
+        from paddle_operator_tpu.infer import afmoe_serve as AF
+
+        AF.refuse_modes(cfg, {"SERVE_TP>1": mesh_tp(mesh) > 1,
+                              "SERVE_ADAPTERS": lora is not None})
+        logits, cache, _ = AF.forward(
+            cfg, params, tokens, cache,
+            head_at=tokens.shape[1] - 1 if last_only else None)
+        return logits, cache
     pos = cache["pos"]
     adp, aid = lora if lora is not None else (None, None)
     x = _embed(cfg, params, tokens)

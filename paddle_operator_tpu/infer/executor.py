@@ -103,14 +103,17 @@ class DispatchResult:
     commit counts (acceptance telemetry); ``ok`` the isfinite
     verdicts (check_finite only)."""
 
-    __slots__ = ("toks", "counts", "ok", "raw", "n_steps")
+    __slots__ = ("toks", "counts", "ok", "raw", "n_steps", "moe")
 
-    def __init__(self, toks, counts, ok, raw, n_steps):
+    def __init__(self, toks, counts, ok, raw, n_steps, moe=None):
         self.toks = toks
         self.counts = counts
         self.ok = ok
         self.raw = raw
         self.n_steps = n_steps
+        # routing counters of an expert architecture's dispatch
+        # (infer/afmoe_serve.py make_paged_chunk_step), else None
+        self.moe = moe
 
 
 # ---------------------------------------------------------------------------
@@ -1693,6 +1696,19 @@ class RingExecutor:
                              f"{_PGQ.KV_QUANT_MODES}")
         self.kv_quant = kv_quant
         self.quant = kv_quant == "int8"
+        # an architecture other than LLaMA's serves on the paged ring's
+        # plain mode alone; every other mode refuses it here, in one
+        # sentence (none may run the LLaMA block over its weights)
+        from paddle_operator_tpu.infer import afmoe_serve as AF
+
+        self.afmoe = AF.is_afmoe(cfg)
+        AF.refuse_modes(cfg, {
+            "SERVE_PAGED=0": not paged, "SERVE_TP>1": D.mesh_tp(mesh) > 1,
+            "SERVE_SPEC_K>0": spec_k, "SERVE_KV_QUANT=int8": self.quant,
+            f"SERVE_PREFILL={prefill_mode}": prefill_mode != "inline",
+            "SERVE_ADAPTERS": adapters is not None,
+            "SERVE_MEGASTEP>1": int(megastep) > 1,
+            "SERVE_PREFIX_CACHE=1": paged and prefix_cache})
         if self.quant and not self.paged:
             raise ValueError("kv_quant='int8' requires the paged ring "
                              "(the pool block is the quantization "
@@ -1793,7 +1809,14 @@ class RingExecutor:
         else:
             self.draft_params = None
             self.spec_step = None
-            if self.paged:
+            if self.afmoe:
+                self.step = AF.make_paged_chunk_step(
+                    cfg, chunk_tokens, top_k, top_p,
+                    check_finite=check_finite)
+                self.inserts = {b: AF.make_paged_prefill_insert(
+                    cfg, b, self.block_size, top_k, top_p)
+                    for b in self.buckets}
+            elif self.paged:
                 self.step = self._pg.make_paged_chunk_step(
                     cfg, chunk_tokens, top_k, top_p, mesh=mesh,
                     check_finite=check_finite, quant=self.quant)
@@ -2019,11 +2042,14 @@ class RingExecutor:
             else:
                 out = self.step(self.params, self.cache, self.tok,
                                 self.temp, self.keys, active, *plan.lora)
+            moe = None
+            if self.afmoe:      # the routing counters, last
+                out, moe = out[:-1], out[-1]
             if self.check_finite:
                 self.cache, self.tok, toks, ok = out
             else:
                 (self.cache, self.tok, toks), ok = out, None
-            return DispatchResult(toks, None, ok, None, 1)
+            return DispatchResult(toks, None, ok, None, 1, moe)
         prog = self.megastep_prog(plan.n_steps)
         eos = jnp.asarray(plan.eos, jnp.int32)
         left = jnp.asarray(plan.left, jnp.int32)
@@ -2453,6 +2479,11 @@ class RingExecutor:
             out = self.step(self.params, cache, tbl, tok, temp, keys,
                             active, *st)
             cache, tok = out[0], out[1]
+            if self.afmoe:
+                # its inserts are executables already (compile_inserts);
+                # the programs warmed below are the LLaMA block's, for
+                # modes that refuse this architecture
+                return
         else:
             out = self.step(self.params, cache, tok, temp, keys, active,
                             *st)

@@ -1090,11 +1090,16 @@ def init_paged_cache(cfg: LlamaConfig, slots: int, total_blocks: int,
     shape = (cfg.n_layers, total_blocks, cfg.n_kv_heads, block_size,
              cfg.head_dim)
     if quant == "none":
-        return {
+        cache = {
             "k": D.alloc_kv_buffer(cfg, shape, mesh),
             "v": D.alloc_kv_buffer(cfg, shape, mesh),
             "pos": jnp.zeros((slots,), jnp.int32),
         }
+        if not isinstance(cfg, LlamaConfig):
+            # infer/afmoe_serve.py: prefill assignments by expert since
+            # the last decode dispatch read them out
+            cache["moe_pf"] = jnp.zeros((cfg.n_experts,), jnp.int32)
+        return cache
     if quant != "int8":
         raise ValueError(f"kv_quant {quant!r} not in {KV_QUANT_MODES}")
     scale_shape = (cfg.n_layers, total_blocks, cfg.n_kv_heads)

@@ -994,6 +994,9 @@ def main() -> int:
     TRC.set_annotator(jax.profiler.TraceAnnotation)
     env = JobEnv.from_env()
     cfg = CONFIGS[os.environ.get("MODEL_PRESET", "7b")]
+    from paddle_operator_tpu.infer import afmoe_serve as AF
+
+    AF.refuse_modes(cfg, {"SERVE_PREFILL=disagg (a prefill pod)": True})
     mesh = None
     tp = int(os.environ.get("SERVE_TP", "1"))
     if tp > 1:

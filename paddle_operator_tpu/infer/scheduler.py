@@ -552,6 +552,12 @@ class ContinuousBatcher:
                       "prefill_bucket_tokens": 0,
                       "prefill_calls_by_bucket": {},
                       "decode_steps": 0, "decode_lane_steps": 0,
+                      # routing counters of an expert architecture
+                      # (infer/afmoe_serve.py): computed on the device,
+                      # added up as each dispatch's results are consumed
+                      "moe_layer_steps": 0, "moe_experts_touched": 0,
+                      "moe_expert_load": None,
+                      "moe_prefill_expert_load": None,
                       # cross-host disaggregation (ISSUE 13): cold
                       # prompts whose prefill ran in a PREFILL POOL
                       # pod and handed off over the wire
@@ -1092,6 +1098,7 @@ class ContinuousBatcher:
             "prefillCallsByBucket": {
                 str(k): v for k, v in
                 dict(self.stats["prefill_calls_by_bucket"]).items()},
+            **self._moe_status(),
             "phaseSeconds": {k: round(v, 6) for k, v in
                              self.phases.self_seconds().items()},
             "phaseCounts": self.phases.counts(),
@@ -2846,6 +2853,7 @@ class ContinuousBatcher:
             wd.begin(scale=res.n_steps)
         try:
             toks = np.asarray(res.toks)
+            moe = None if res.moe is None else np.asarray(res.moe)
             counts = None if res.counts is None else np.asarray(res.counts)
             ok = None if res.ok is None else np.asarray(res.ok)
             raw = None if res.raw is None else np.asarray(res.raw)
@@ -2873,6 +2881,8 @@ class ContinuousBatcher:
                             steps=res.n_steps)
         if self._fault is not None:
             return              # stall-failed chunks must not apply
+        if moe is not None:
+            self._count_moe(moe, res.n_steps)
         if res.n_steps == 1:
             if self.spec_k:
                 self._consume(chunk_reqs, toks, counts=counts, ok=ok,
@@ -2889,6 +2899,36 @@ class ContinuousBatcher:
             self._consume(chunk_reqs, toks[r], counts=counts[r],
                           ok=None if ok is None else ok[r],
                           spec_raw=None if raw is None else raw[r])
+
+    def _moe_status(self) -> dict:
+        """The routing counters' block of ``serving_status`` — raw and
+        cumulative; empty for an architecture without expert layers."""
+        load = self.stats["moe_expert_load"]
+        if load is None:
+            return {}
+        prefill = self.stats["moe_prefill_expert_load"]
+        return {"moeLayerStepsTotal": self.stats["moe_layer_steps"],
+                "moeAssignmentsTotal": int(load.sum()),
+                "moeExpertsTouchedTotal": self.stats["moe_experts_touched"],
+                "moeExpertLoadTotal": [int(n) for n in load],
+                "moePrefillAssignmentsTotal": int(prefill.sum()),
+                "moePrefillExpertLoadTotal": [int(n) for n in prefill]}
+
+    def _count_moe(self, moe: np.ndarray, n_steps: int) -> None:
+        """One consumed dispatch's routing counters into the cumulative
+        ones: the decode steps' assignments by expert and experts
+        touched over its layer-steps, and the assignments by expert of
+        the inserts since the dispatch before it."""
+        from paddle_operator_tpu.infer.afmoe_serve import split_moe
+
+        cfg = self.cfg
+        load, touched, prefill = split_moe(cfg, moe.astype(np.int64))
+        st = self.stats
+        st["moe_layer_steps"] += n_steps * self.chunk * cfg.n_moe_layers
+        st["moe_experts_touched"] += touched
+        for key, add in (("moe_expert_load", load),
+                         ("moe_prefill_expert_load", prefill)):
+            st[key] = add if st[key] is None else st[key] + add
 
     def _pending_prefill_slots(self) -> set:
         """Lanes reserved but not yet decode-active."""
@@ -3245,7 +3285,7 @@ class ContinuousBatcher:
             # by consume time the tokens are already on the wire and
             # np.asarray is a cheap completion wait instead of a full
             # round-trip on the ring's critical path
-            for dev in (res.toks, res.counts, res.ok, res.raw):
+            for dev in (res.toks, res.counts, res.ok, res.raw, res.moe):
                 try:
                     dev.copy_to_host_async()
                 except AttributeError:  # None / interpret-mode ndarray

@@ -234,10 +234,14 @@ def load_serving_params(cfg: LlamaConfig, ckpt, *, seed: int = 0,
     first device.  Without one (smoke mode) the same tree is initialised
     from `seed` under one jit with the cast inside.  No TrainState, no
     optimizer state, no second copy."""
+    from paddle_operator_tpu.infer import afmoe_serve as AF
     from paddle_operator_tpu.infer.quant import serving_params
     from paddle_operator_tpu.models.llama import Llama, partition_patterns
     from paddle_operator_tpu.train.checkpoint import restore_newest
 
+    if AF.is_afmoe(cfg):        # the preset's type selects the tree
+        AF.refuse_modes(cfg, {"SERVE_TP>1": D.mesh_tp(mesh) > 1})
+        return AF.load_params(cfg, ckpt, seed)
     model = Llama(cfg)
 
     def init(rng):
@@ -1024,6 +1028,16 @@ def main() -> int:
         mesh = make_serving_mesh(tp)
     # TPUJOB_CHECKPOINT_PATH; restored (or smoke-initialised) in the
     # served dtype, straight onto the serving layout
+    from paddle_operator_tpu.infer import afmoe_serve as AF
+
+    # an architecture the quantizers and the draft model are not written
+    # for refuses them here, before anything is loaded
+    AF.refuse_modes(cfg, {
+        "QUANTIZE=int8": os.environ.get("QUANTIZE", "") == "int8",
+        "SERVE_WEIGHT_QUANT": (os.environ.get("SERVE_WEIGHT_QUANT", "none")
+                               or "none") != "none",
+        "SERVE_TP>1": tp > 1,
+        "SERVE_SPEC_K>0": int(os.environ.get("SERVE_SPEC_K", "0")) > 0})
     params, resumed = load_serving_params(cfg, CheckpointManager(),
                                           mesh=mesh)
     if os.environ.get("QUANTIZE", "") == "int8":

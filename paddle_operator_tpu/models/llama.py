@@ -189,6 +189,12 @@ CONFIGS = {
                        ffn_dim=13824),
 }
 
+# Presets of other architectures live beside LLaMA's, where MODEL_PRESET is
+# looked up; the preset's type selects the code that serves it.
+from paddle_operator_tpu.models.afmoe import CONFIGS as _AFMOE_CONFIGS  # noqa: E402
+
+CONFIGS.update(_AFMOE_CONFIGS)
+
 
 # ---------------------------------------------------------------------------
 # Building blocks
@@ -519,4 +525,9 @@ def make_model(preset: str = "tiny", mesh=None, **overrides) -> Tuple[Llama, Lla
     parallelism when its cp axis is > 1, and the flash kernel's way onto
     a multi-chip TPU mesh."""
     cfg = dataclasses.replace(CONFIGS[preset], **overrides)
+    if not isinstance(cfg, LlamaConfig):
+        raise ValueError(
+            f"preset {preset!r} ({type(cfg).__name__}) is served only "
+            "(infer/afmoe_serve.py): the trainer has no dropless expert "
+            "layer with a backward pass and no routing-bias update")
     return Llama(cfg, mesh), cfg

@@ -105,11 +105,12 @@ DEFAULT_BLOCK_K = 256
 
 
 def _cell_softmax(qt, k2, v2, ik, length, scale, block_k, n_rep,
-                  acc_ref, m_ref, l_ref):
+                  acc_ref, m_ref, l_ref, start=None):
     """One grid cell's score matmul + masked online-softmax update —
     the compute shared verbatim by the bf16 and the dequantizing
     kernels (factored, not changed: the bf16 op sequence is the one the
-    parity tests pin)."""
+    parity tests pin).  ``start`` (sliding-window layers): the lane's
+    first visible position; absent, the trace is the windowless one."""
     hq = qt.shape[1]
     rows = k2.shape[0]
     # every block row against EVERY query head in one MXU pass;
@@ -130,6 +131,8 @@ def _cell_softmax(qt, k2, v2, ik, length, scale, block_k, n_rep,
     col_iota = jax.lax.broadcasted_iota(jnp.int32, (hq, rows), 1)
     pos = ik * block_k + col_iota % block_k
     live = (row_h == col_iota // block_k) & (pos < length)
+    if start is not None:
+        live = live & (pos >= start)
     st = jnp.where(live, st, NEG_INF)
 
     m_prev = m_ref[:, 0]                                  # [hq]
@@ -146,13 +149,18 @@ def _cell_softmax(qt, k2, v2, ik, length, scale, block_k, n_rep,
 
 
 def _kernel(len_ref, *refs, scale: float, block_k: int, n_rep: int,
-            stacked: bool):
+            stacked: bool, windowed: bool = False):
+    b = pl.program_id(0)
+    start = None
+    if windowed:      # the LAST scalar-prefetch ref: lane b attends
+        n_pf = 2 if stacked else 1          # positions [start_b, length_b)
+        start = refs[n_pf - 1][b]
+        refs = refs[:n_pf - 1] + refs[n_pf:]
     if stacked:       # extra scalar-prefetch ref (layer index, unused
         _lay, qt_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
         k_ref, v_ref = k_ref.at[0], v_ref.at[0]   # in body; maps use it)
     else:
         qt_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-    b = pl.program_id(0)
     ik, nk = pl.program_id(1), pl.num_programs(1)
     length = len_ref[b]
     hkv = k_ref.shape[1]
@@ -166,8 +174,14 @@ def _kernel(len_ref, *refs, scale: float, block_k: int, n_rep: int,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     # Blocks at/after the fill boundary were index-remapped to the last
-    # live block (no new DMA); their compute is skipped outright.
-    @pl.when(ik * block_k < length)
+    # live block (no new DMA); their compute is skipped outright.  So
+    # are, on a sliding-window layer, the blocks wholly before ``start``
+    # (remapped to the first live block).
+    live_cell = ik * block_k < length
+    if windowed:
+        live_cell = live_cell & ((ik + 1) * block_k > start)
+
+    @pl.when(live_cell)
     def _compute():
         # the cell's whole K/V tile as one 2D matrix; rows are
         # (head-major) h*block_k + s — a pure leading-dim collapse of
@@ -176,7 +190,7 @@ def _kernel(len_ref, *refs, scale: float, block_k: int, n_rep: int,
         v2 = v_ref[0].reshape(rows, -1)
         qt = qt_ref[0]                               # [d, hq]
         _cell_softmax(qt, k2, v2, ik, length, scale, block_k, n_rep,
-                      acc_ref, m_ref, l_ref)
+                      acc_ref, m_ref, l_ref, start=start)
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -280,7 +294,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
 
 def _paged_kernel(len_ref, tbl_ref, *refs, scale: float, block_k: int,
-                  n_rep: int, stacked: bool):
+                  n_rep: int, stacked: bool, windowed: bool = False):
     """Paged-cache kernel body: identical compute to :func:`_kernel` —
     the block table participates only through the *index maps* (each
     grid cell's K/V window is looked up in ``tbl_ref`` instead of being
@@ -289,7 +303,7 @@ def _paged_kernel(len_ref, tbl_ref, *refs, scale: float, block_k: int,
     never reads."""
     del tbl_ref
     _kernel(len_ref, *refs, scale=scale, block_k=block_k, n_rep=n_rep,
-            stacked=stacked)
+            stacked=stacked, windowed=windowed)
 
 
 def _paged_kernel_quant(len_ref, tbl_ref, *refs, scale: float,
@@ -372,7 +386,9 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            k_scale: Optional[jax.Array] = None,
                            v_scale: Optional[jax.Array] = None,
                            k_tail: Optional[jax.Array] = None,
-                           v_tail: Optional[jax.Array] = None) -> jax.Array:
+                           v_tail: Optional[jax.Array] = None,
+                           starts: Optional[jax.Array] = None
+                           ) -> jax.Array:
     """:func:`decode_attention` over a PAGED cache: lane b's context
     lives in pool blocks ``block_table[b, 0..ceil(len_b/bs)-1]`` instead
     of one contiguous slab.
@@ -403,9 +419,22 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     repeat.  The scales are gathered through the block table here
     (``[B, M, Hkv]``, a few KB) and each lane's slab rides into SMEM,
     where the cell reads one scalar per head.  Dequant happens in the
-    cell (:func:`_paged_kernel_quant`); HBM streams half the bytes."""
+    cell (:func:`_paged_kernel_quant`); HBM streams half the bytes.
+
+    ``starts`` [B] (sliding-window layers; bf16 pool only): lane b
+    attends logical positions [starts[b], lengths[b]).  It rides as one
+    more scalar-prefetch operand: the mask drops the positions before
+    it, and the index map clamps blocks wholly before it to the lane's
+    first live block, as it clamps the unfilled tail to the last (a
+    repeated block is not fetched).  Absent, the traced program is the
+    windowless one, operand for operand."""
     b, hq, d = q.shape
     quant = k_scale is not None
+    windowed = starts is not None
+    if windowed and quant:
+        raise ValueError("a window over the int8 pool is not written "
+                         "(the staging tail's substitution assumes the "
+                         "whole prefix)")
     if quant and (v_scale is None or k_tail is None or v_tail is None):
         raise ValueError("quantized paged attention needs k_scale, "
                          "v_scale, k_tail and v_tail together")
@@ -425,13 +454,28 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     lengths = lengths.astype(jnp.int32)
     block_table = block_table.astype(jnp.int32)
 
-    def blk(ik, lens, tbl, bb):
+    def blk(ik, lens, tbl, bb, first=None):
         # pool id of this cell's window; dead tail cells repeat the
         # lane's last live entry (no new DMA, compute pl.when-skipped)
         live = jnp.minimum(ik, jnp.maximum(lens[bb] - 1, 0) // block_k)
+        if first is not None:       # and cells before the window its first
+            live = jnp.maximum(live, first[bb] // block_k)
         return tbl[bb, live]
 
-    if stacked:
+    if windowed:
+        if not stacked:
+            raise ValueError("the windowed kernel reads the stacked pool "
+                             "(pass layer=)")
+        lay = jnp.reshape(layer, (1,)).astype(jnp.int32)
+        cache_spec = pl.BlockSpec(
+            (1, 1, hkv, block_k, d),
+            lambda b, ik, lens, tbl, lay, first: (
+                lay[0], blk(ik, lens, tbl, b, first), 0, 0, 0))
+        tail_spec = None
+        q_spec = pl.BlockSpec((1, d, hq), lambda b, ik, *_: (b, 0, 0))
+        out_spec = pl.BlockSpec((1, hq, d), lambda b, ik, *_: (b, 0, 0))
+        num_prefetch, extra = 4, (lay, starts.astype(jnp.int32))
+    elif stacked:
         lay = jnp.reshape(layer, (1,)).astype(jnp.int32)
         cache_spec = pl.BlockSpec(
             (1, 1, hkv, block_k, d),
@@ -495,9 +539,10 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         out_specs=out_spec,
         scratch_shapes=scratch_shapes,
     )
+    kernel_kw = {"windowed": True} if windowed else {}
     out = pl.pallas_call(
         functools.partial(kernel_body, scale=scale, block_k=block_k,
-                          n_rep=n_rep, stacked=stacked),
+                          n_rep=n_rep, stacked=stacked, **kernel_kw),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
         compiler_params=compiler_params,

@@ -210,6 +210,22 @@ def _counter_gauges(out: dict, status_serving: dict, job: str,
             ("prefillBucketTokensTotal", "prefill_bucket_tokens")):
         out[f"tpujob_serve_{name}_total{lbl}"] = \
             float(status_serving.get(key, 0.0))
+    # routing counters, where the architecture has expert layers
+    # (infer/afmoe_serve.py): per-expert loads labeled ``expert``
+    for key, name in (
+            ("moeLayerStepsTotal", "moe_layer_steps"),
+            ("moeAssignmentsTotal", "moe_assignments"),
+            ("moeExpertsTouchedTotal", "moe_experts_touched"),
+            ("moePrefillAssignmentsTotal", "moe_prefill_assignments")):
+        if key in status_serving:
+            out[f"tpujob_serve_{name}_total{lbl}"] = \
+                float(status_serving[key])
+    for key, name in (("moeExpertLoadTotal", "moe_expert_load"),
+                      ("moePrefillExpertLoadTotal",
+                       "moe_prefill_expert_load")):
+        for e, n in enumerate(status_serving.get(key) or ()):
+            out[(f"tpujob_serve_{name}_total"
+                 f'{{job="{job}"{rep},expert="{e}"}}')] = float(n)
     for bucket, n in (status_serving.get("prefillCallsByBucket")
                       or {}).items():
         out[("tpujob_serve_prefill_calls_total"

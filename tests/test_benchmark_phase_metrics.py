@@ -133,9 +133,12 @@ def test_entries_of_record_name_layer_cells_and_readers():
         variant = m["name"].split(".")[1]
         cell = {"closed": "serve16l.closed16",
                 "open": "serve16l.open-bursty"}[variant]
-        assert m["workloads"] == [cell]
+        # the cell of record first; cells later PRs appended behind it
+        # (ISSUE 28's two closed cells) report the same end-to-end metric
+        assert m["workloads"][0] == cell
+        assert variant == "closed" or m["workloads"] == [cell]
         assert m["source"] == "program_counter" and m["layer"] in layers
-        assert cell in e2e[m["moves"]]["workloads"]
+        assert all(c in e2e[m["moves"]]["workloads"] for c in m["workloads"])
     line = R.read_metrics(rec(), [m for m in mine
                                   if m["name"].endswith(".closed")])
     assert sorted(line) == ["decode_lanes_live_pct.closed",

@@ -345,6 +345,11 @@ def _rendered_metric_names():
         "priorityQueueDepth": [1], "adapterNames": ["a"],
         "prefillCallsByBucket": {"256": 1},
         "phaseSeconds": {"sched.admit": 1.0},
+        # an expert architecture's routing counters (ISSUE 28)
+        "moeLayerStepsTotal": 4, "moeAssignmentsTotal": 8,
+        "moeExpertsTouchedTotal": 6, "moeExpertLoadTotal": [5, 3],
+        "moePrefillAssignmentsTotal": 2,
+        "moePrefillExpertLoadTotal": [1, 1],
         "fleet": {"replicasDesired": 1, "prefillReplicasDesired": 1,
                   "generationMin": 0},
     }
