@@ -15,8 +15,11 @@ framework also needs the serving-shaped path.  TPU-native design:
   tests/test_decode.py, which asserts decode logits match the training
   forward position-for-position.
 - Prefill processes the whole prompt in one pass (MXU-friendly [B, S]
-  matmuls + causal mask against the cache); the step loop then decodes
-  one token per scan tick with single-query attention over the cache.
+  matmuls; attention through the flash kernel over the prompt's own
+  q, k, v where it runs, else the einsum with a causal mask against the
+  cache just written — ``prefill_attn_impl``); the step loop then
+  decodes one token per scan tick with single-query attention over the
+  cache.
 
 MoE configs decode with exact no-drop top-1 routing (the training layer's
 capacity buffer is a static-shape device whose drops are an
@@ -276,28 +279,44 @@ def _layer(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
            v_cache: jax.Array, pos: jax.Array, lora=None
            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One decoder layer over [B, T] new positions starting at ``pos``,
-    attending to the cache's [0, pos+T), with the XLA einsum attention.
+    attending to the cache's [0, pos+T), with the XLA einsum attention:
+    every forward that must read what earlier calls wrote (a chunked
+    slice, any entry at ``pos > 0``), single-token decode where the
+    kernel is off, and whole-prompt prefill wherever the flash kernel
+    does not apply (:func:`prefill_attn_impl`).
     Returns (y, k_cache', v_cache').  lp is ONE layer's param subtree
     (unstacked); caches are head-major [B, H_kv, S, D] (init_cache).
     The pallas decode path does NOT go through here — it keeps the
-    caches stacked (see _forward) so the kernel reads them copy-free."""
+    caches stacked (see _forward) so the kernel reads them copy-free —
+    and neither does a whole-prompt prefill the flash kernel takes
+    (:func:`_prefill_layer`)."""
     q, k, v = _qkv(cfg, lp, x, cos, sin, pos, lora=lora)
-
-    # [B, T, H, D] -> head-major [B, H, T, D] rows into the cache
-    with jax.named_scope("cache_write"):
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k.transpose(0, 2, 1, 3), (0, 0, pos, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v.transpose(0, 2, 1, 3), (0, 0, pos, 0))
+    k_cache, v_cache = _write_rows(k_cache, v_cache, k, v, pos)
     out = _attend_cache(cfg, q, k_cache, v_cache, pos)
     return _finish_layer(cfg, lp, x, out), k_cache, v_cache
+
+
+@jax.named_scope("cache_write")
+def _write_rows(k_cache: jax.Array, v_cache: jax.Array, k: jax.Array,
+                v: jax.Array, pos: jax.Array
+                ) -> Tuple[jax.Array, jax.Array]:
+    """New [B, T, H, D] rows into one layer's head-major [B, H, S, D]
+    caches at ``pos``."""
+    k_cache = jax.lax.dynamic_update_slice(
+        k_cache, k.transpose(0, 2, 1, 3), (0, 0, pos, 0))
+    v_cache = jax.lax.dynamic_update_slice(
+        v_cache, v.transpose(0, 2, 1, 3), (0, 0, pos, 0))
+    return k_cache, v_cache
 
 
 @jax.named_scope("attn.kernel")
 def _attend_cache(cfg: LlamaConfig, q: jax.Array, k_cache: jax.Array,
                   v_cache: jax.Array, pos: jax.Array) -> jax.Array:
     """The XLA einsum attention of [B, T] new positions starting at
-    ``pos`` against head-major caches [B, H_kv, S, D] -> [B, T, Hq*D]."""
+    ``pos`` against head-major caches [B, H_kv, S, D] -> [B, T, Hq*D].
+    Quadratic in HBM for a multi-token block (float32 scores
+    [B, T, Hkv, n_rep, S]): whole-prompt prefill leaves it for the flash
+    kernel where that runs (:func:`_prefill_layer`)."""
     b, t = q.shape[:2]
     hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     # GQA: group query heads onto kv heads; single-query (or prefill-
@@ -322,6 +341,72 @@ def _attend_cache(cfg: LlamaConfig, q: jax.Array, k_cache: jax.Array,
     out = jnp.einsum("bthrs,bhsd->bthrd", probs.astype(cfg.dtype),
                      v_cache, preferred_element_type=jnp.float32)
     return out.reshape(b, t, hq * d).astype(cfg.dtype)
+
+
+# Below this width a whole-prompt prefill keeps the einsum.  Measured on
+# the v5e (PERF.md §6, PR 29: one paged insert of the 16-layer Mistral-7B
+# configuration, host clock, einsum -> kernel): 256: 15.0 -> 15.6 ms,
+# 512: 26.2 -> 26.7 (the [32, W, W] scores are small there and the
+# kernel's grid has a floor of its own); 1024: 53.7 -> 46.9,
+# 2048: 120.8 -> 92.6, 3072: 204.7 -> 140.0, 4096: 317.2 -> 201.7.
+_FLASH_MIN_WIDTH = 1024
+
+
+def _prefill_blocks(width: int) -> Optional[Tuple[int, int]]:
+    """The flash kernel's blocks for a whole-prompt prefill: the forward's
+    own (pallas_attention.FORWARD_BLOCK_*) where they divide the width,
+    else the kernel's defaults (a 3:2 midpoint rung such as 1536)."""
+    from paddle_operator_tpu.ops.pallas_attention import (
+        FORWARD_BLOCK_K,
+        FORWARD_BLOCK_Q,
+    )
+
+    if width % FORWARD_BLOCK_Q or width % FORWARD_BLOCK_K:
+        return None
+    return FORWARD_BLOCK_Q, FORWARD_BLOCK_K
+
+
+def prefill_attn_impl(cfg: LlamaConfig, width: int, mesh=None) -> str:
+    """Which attention a WHOLE-PROMPT prefill ``width`` positions wide
+    traces (:func:`_forward` with ``whole_prompt``): ``"flash"`` — the
+    pallas flash kernel over the prompt's own q, k, v — from
+    ``_FLASH_MIN_WIDTH`` up, wherever
+    :func:`ops.attention.picks_flash` says it runs (a TPU, a width and
+    head size the kernel tiles, heads a tp mesh splits in whole GQA
+    groups), else ``"einsum"`` (:func:`_attend_cache` over the lane
+    cache, the only path of every CPU program and of the tiny presets).
+    Static in (cfg, width, mesh): the ring reports it rung by rung as
+    ``prefillAttnByBucket`` from this same function."""
+    from paddle_operator_tpu.ops.attention import picks_flash
+
+    d = cfg.head_dim
+    flash = width >= _FLASH_MIN_WIDTH and picks_flash(
+        (1, width, cfg.n_heads, d), (1, width, cfg.n_kv_heads, d),
+        mesh=mesh, blocks=_prefill_blocks(width))
+    return "flash" if flash else "einsum"
+
+
+def _prefill_layer(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
+                   cos: jax.Array, sin: jax.Array, k_cache: jax.Array,
+                   v_cache: jax.Array, pos: jax.Array, lora=None, mesh=None
+                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One decoder layer of a whole-prompt prefill: :func:`_layer` for a
+    prompt that enters an EMPTY cache at position 0, so the cache holds
+    nothing the new k, v do not and causal self-attention over q, k, v
+    is the same mathematics as :func:`_attend_cache` over the cache just
+    written — without the float32 [B, T, H, S] scores in HBM and without
+    the half of them above the diagonal.  K and V still land in the
+    cache (the lane's decode steps read them); pad positions are
+    computed and thrown away as on the einsum path (a real row never
+    sees a pad: pads sit after it)."""
+    from paddle_operator_tpu.ops.attention import attention
+
+    q, k, v = _qkv(cfg, lp, x, cos, sin, pos, lora=lora)
+    k_cache, v_cache = _write_rows(k_cache, v_cache, k, v, pos)
+    out = attention(q, k, v, causal=True, use_pallas=True, mesh=mesh,
+                    blocks=_prefill_blocks(x.shape[1]))
+    out = out.reshape(*x.shape[:2], cfg.n_heads * cfg.head_dim)
+    return _finish_layer(cfg, lp, x, out.astype(cfg.dtype)), k_cache, v_cache
 
 
 def _moe_ffn(cfg: LlamaConfig, mp: Dict[str, Any],
@@ -358,7 +443,7 @@ def _moe_ffn(cfg: LlamaConfig, mp: Dict[str, Any],
 
 def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
              cache: Dict[str, jax.Array], *, last_only: bool = False,
-             mesh=None, lora=None
+             mesh=None, lora=None, whole_prompt: bool = False
              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """[B, T] new tokens at cache['pos'] -> ([B, T, vocab] logits,
     advanced cache).  Layers run under lax.scan over the stacked params
@@ -377,7 +462,19 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
     (decode_tp_compatible) fall back to the GSPMD einsum path whole.
 
     ``lora``: ``(adp, aid)`` — stacked [L, ...] adapter arrays riding
-    the layer scan as xs, per-row adapter ids (infer/qos.py)."""
+    the layer scan as xs, per-row adapter ids (infer/qos.py).
+
+    ``whole_prompt``: the caller made ``cache`` itself, in this same
+    call, with :func:`init_cache` — it is empty and at position 0, and
+    ``tokens`` is everything it will hold.  Set by :func:`prefill`,
+    :func:`paged_prefill` and the ring's whole-prompt inserts, never by
+    a forward that continues a cache.  Such a prefill has two attention
+    paths, chosen per width before tracing (:func:`prefill_attn_impl`):
+    the flash kernel over the prompt's own q, k, v
+    (:func:`_prefill_layer`), or, where the kernel does not run, the
+    einsum over the cache just written (:func:`_layer`) — the one path
+    of every other multi-token forward, which must read the earlier
+    cache."""
     if not isinstance(cfg, LlamaConfig):
         # another architecture's block (the preset's type selects it)
         from paddle_operator_tpu.infer import afmoe_serve as AF
@@ -469,6 +566,9 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
         (x, k_new, v_new), _ = jax.lax.scan(
             body, (x, cache["k"], cache["v"]), xs)
     else:
+        flash = whole_prompt and prefill_attn_impl(
+            cfg, tokens.shape[1], mesh) == "flash"
+
         def body(x, layer_in):
             if adp is not None:
                 lp, adp_l, k_c, v_c = layer_in
@@ -476,8 +576,12 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
             else:
                 lp, k_c, v_c = layer_in
                 lo = None
-            y, k_c, v_c = _layer(cfg, lp, x, cos, sin, k_c, v_c, pos,
-                                 lora=lo)
+            if flash:
+                y, k_c, v_c = _prefill_layer(cfg, lp, x, cos, sin, k_c,
+                                             v_c, pos, lora=lo, mesh=mesh)
+            else:
+                y, k_c, v_c = _layer(cfg, lp, x, cos, sin, k_c, v_c, pos,
+                                     lora=lo)
             return y, (k_c, v_c)
 
         xs = ((params["layers"], adp, cache["k"], cache["v"])
@@ -503,7 +607,7 @@ def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,
                          f"cache ({cache_len} positions)")
     cache = init_cache(cfg, tokens.shape[0], max_len, mesh=mesh)
     logits, cache = _forward(cfg, params, tokens, cache, last_only=True,
-                             mesh=mesh)
+                             mesh=mesh, whole_prompt=True)
     return logits[:, 0], cache
 
 
@@ -517,7 +621,10 @@ def paged_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     lane's ``table_row`` entries — the cold-admission half of paged
     serving.  The forward itself is exactly :func:`prefill`'s (same
     compiled ops — what keeps the paged ring's first token
-    bit-identical to the contiguous ring's); only the destination
+    bit-identical to the contiguous ring's — and the same choice of
+    attention by width, :func:`prefill_attn_impl`: the flash kernel
+    over the prompt's own q, k, v where it runs, else the einsum over
+    the lane cache); only the destination
     changes: block ``j`` of the lane cache lands in pool block
     ``table_row[j]``, pad blocks land wherever the table maps them
     (the trash block when unmapped — exactness-with-padding,
@@ -537,7 +644,7 @@ def paged_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     bs = block_size or pool_cache["k"].shape[3]
     lane = init_cache(cfg, 1, tokens.shape[1])
     logits, lane = _forward(cfg, params, tokens, lane, mesh=mesh,
-                            lora=lora)
+                            lora=lora, whole_prompt=True)
     if not quant:
         k = _scatter_prompt_blocks(pool_cache["k"], lane["k"], table_row,
                                    bs)

@@ -588,7 +588,8 @@ def make_prefill_insert(cfg: LlamaConfig, bucket: int,
         lane = D.init_cache(cfg, 1, bucket)
         logits, lane = D._forward(
             cfg, params, prompt, lane, mesh=mesh,
-            lora=tuple(lora_args) if lora_args else None)
+            lora=tuple(lora_args) if lora_args else None,
+            whole_prompt=True)
         logits = logits[0, prompt_len - 1]                  # last real row
         new_cache = _splice_lane(cache, lane, slot, prompt_len)
         # first token through the SHARED sampling rule (_sample_tokens),
@@ -624,12 +625,14 @@ def make_spec_prefill_insert(cfg: LlamaConfig, dcfg: LlamaConfig,
     def insert(params, dparams, cache, dcache, tok, temp, keys, prompt,
                prompt_len, slot, temp_val, seed):
         lane = D.init_cache(cfg, 1, bucket)
-        logits, lane = D._forward(cfg, params, prompt, lane, mesh=mesh)
+        logits, lane = D._forward(cfg, params, prompt, lane, mesh=mesh,
+                                  whole_prompt=True)
         logits = logits[0, prompt_len - 1]
         new_cache = _splice_lane(cache, lane, slot, prompt_len)
         dlane = D.init_cache(dcfg, 1, bucket)
         _, dlane = D._forward(dcfg, dparams, prompt, dlane,
-                              last_only=True, mesh=mesh)
+                              last_only=True, mesh=mesh,
+                              whole_prompt=True)
         new_dcache = _splice_lane(dcache, dlane, slot, prompt_len)
         key = jax.random.PRNGKey(seed)
         first = _sample_tokens(
@@ -748,7 +751,8 @@ def make_spec_chunked_final_insert(cfg: LlamaConfig, dcfg: LlamaConfig,
         new_cache = _splice_lane(cache, new_lane, slot, prompt_len)
         dlane = D.init_cache(dcfg, 1, bucket)
         _, dlane = D._forward(dcfg, dparams, prompt, dlane,
-                              last_only=True, mesh=mesh)
+                              last_only=True, mesh=mesh,
+                              whole_prompt=True)
         new_dcache = _splice_lane(dcache, dlane, slot, prompt_len)
         key = jax.random.PRNGKey(seed)
         first = _sample_tokens(
@@ -806,7 +810,8 @@ def make_spec_attach(cfg: LlamaConfig, dcfg: LlamaConfig, bucket: int,
                slot, first, temp_val, seed):
         dlane = D.init_cache(dcfg, 1, bucket)
         _, dlane = D._forward(dcfg, dparams, prompt, dlane,
-                              last_only=True, mesh=mesh)
+                              last_only=True, mesh=mesh,
+                              whole_prompt=True)
         new_dcache = _splice_lane(dcache, dlane, slot, prompt_len)
         return (new_dcache,
                 pos.at[slot].set(prompt_len),
@@ -1831,6 +1836,14 @@ class RingExecutor:
                 self.inserts = {b: make_prefill_insert(cfg, b, top_k,
                                                        top_p, mesh=mesh)
                                 for b in self.buckets}
+        # which attention each rung's whole-prompt insert traces, from
+        # the function the trace itself asks (another architecture's
+        # block attends in its own model file, an einsum): static, shown
+        # on /statusz next to the calls by rung
+        self.prefill_attn = {
+            b: ("einsum" if self.afmoe
+                else D.prefill_attn_impl(cfg, b, mesh))
+            for b in self.buckets}
 
         # the disaggregated prefill engine (prefill_mode="disagg"):
         # built here so its compile set and pool live with the rest of

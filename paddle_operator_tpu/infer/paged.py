@@ -1855,7 +1855,8 @@ def make_paged_spec_prefill_insert(cfg: LlamaConfig, dcfg: LlamaConfig,
         new_cache["pos"] = new_cache["pos"].at[slot].set(prompt_len)
         dlane = D.init_cache(dcfg, 1, bucket)
         _, dlane = D._forward(dcfg, dparams, prompt, dlane,
-                              last_only=True, mesh=mesh)
+                              last_only=True, mesh=mesh,
+                              whole_prompt=True)
         new_dcache = _splice_lane(dcache, dlane, slot, prompt_len)
         key = jax.random.PRNGKey(seed)
         first = _sample_tokens(
@@ -1967,7 +1968,8 @@ def make_paged_spec_suffix_insert(cfg: LlamaConfig, dcfg: LlamaConfig,
                 cache, new_lane, slot)
         dlane = D.init_cache(dcfg, 1, bucket)
         _, dlane = D._forward(dcfg, dparams, prompt, dlane,
-                              last_only=True, mesh=mesh)
+                              last_only=True, mesh=mesh,
+                              whole_prompt=True)
         new_dcache = _splice_lane(dcache, dlane, slot, prompt_len)
         key = jax.random.PRNGKey(seed)
         first = _sample_tokens(

@@ -1098,6 +1098,10 @@ class ContinuousBatcher:
             "prefillCallsByBucket": {
                 str(k): v for k, v in
                 dict(self.stats["prefill_calls_by_bucket"]).items()},
+            # static: the attention of each rung's whole-prompt insert,
+            # "flash" (the pallas kernel) or "einsum"
+            "prefillAttnByBucket": {
+                str(k): v for k, v in self.executor.prefill_attn.items()},
             **self._moe_status(),
             "phaseSeconds": {k: round(v, 6) for k, v in
                              self.phases.self_seconds().items()},

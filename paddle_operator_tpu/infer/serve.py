@@ -1348,6 +1348,10 @@ def main() -> int:
         # executables before this process listens either way
         ready_ring(batcher,
                    prewarm=os.environ.get("SERVE_PREWARM", "1") == "1")
+        # the other kernel choice this process made, rung by rung (the
+        # first line has the decode kernel's): /statusz carries the same
+        print("prefill inserts ready: prefill_attn="
+              f"{json.dumps(batcher.executor.prefill_attn)}", flush=True)
         # TPUJOB_CHAOS: deterministic fault injection on the live ring
         # (smoke-testing a deployment's resilience end-to-end)
         maybe_install_from_env(batcher)

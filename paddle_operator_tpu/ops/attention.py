@@ -78,10 +78,18 @@ def _flash_axes(mesh, n_heads: int, n_kv_heads: int):
     return use_mesh, manual, batch_axes or None, "tp" if tp > 1 else None
 
 
+def _block_kw(blocks) -> dict:
+    """``blocks`` = ``(block_q, block_k)`` as the kernel's keywords; None
+    leaves the kernel its defaults (the trainer's)."""
+    return {} if blocks is None else dict(block_q=blocks[0],
+                                          block_k=blocks[1])
+
+
 def sharded_flash_attention(mesh, q: jax.Array, k: jax.Array, v: jax.Array,
                             *, causal: bool = True,
                             segment_ids: Optional[jax.Array] = None,
-                            interpret: bool = False) -> jax.Array:
+                            interpret: bool = False,
+                            blocks=None) -> jax.Array:
     """The flash kernel on a multi-device mesh.  A Mosaic kernel cannot be
     partitioned by GSPMD ("wrap the call in a shard_map"), and attention
     needs no cross-shard term over batch or heads: each shard runs the
@@ -102,11 +110,27 @@ def sharded_flash_attention(mesh, q: jax.Array, k: jax.Array, v: jax.Array,
 
     def local(q, k, v, seg=None):
         return flash_attention(q, k, v, causal=causal, segment_ids=seg,
-                               interpret=interpret)
+                               interpret=interpret, **_block_kw(blocks))
 
     return jax.shard_map(local, mesh=use_mesh, in_specs=in_specs,
                          out_specs=spec, axis_names=manual,
                          check_vma=False)(*args)
+
+
+def picks_flash(q_shape, k_shape, segment_ids=None, mesh=None,
+                blocks=None) -> bool:
+    """What :func:`attention` decides when left to itself
+    (``use_pallas=None``), from the backend and the ``[B, S, H, D]``
+    shapes alone: the flash kernel on TPU wherever it tiles and, on a
+    `mesh`, the heads split over tp.  For a caller whose own fallback is
+    not the reference (infer/decode.py's whole-prompt prefill)."""
+    from paddle_operator_tpu.ops.pallas_attention import flash_tiles
+
+    return (jax.default_backend() == "tpu"
+            and flash_tiles(q_shape, k_shape, segment_ids,
+                            **_block_kw(blocks))
+            and (mesh is None
+                 or _flash_axes(mesh, q_shape[2], k_shape[2]) is not None))
 
 
 @jax.named_scope("attn.kernel")
@@ -114,31 +138,29 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               *, causal: bool = True,
               segment_ids: Optional[jax.Array] = None,
               use_pallas: Optional[bool] = None,
-              mesh=None) -> jax.Array:
+              mesh=None, blocks=None) -> jax.Array:
     """Dispatching attention.  [B, S, H, D] inputs, head-count ratio = GQA.
 
-    ``use_pallas=None`` decides before the call, from the backend and the
-    shapes: the flash kernel on TPU wherever it tiles
+    ``use_pallas=None`` decides before the call (:func:`picks_flash`):
+    the flash kernel on TPU wherever it tiles
     (:func:`pallas_attention.flash_tiles`) and, on a `mesh`, the heads
     split over tp — the reference elsewhere.  ``use_pallas=True`` asks for
     the kernel: shapes it cannot take raise instead of quietly running
     the O(S^2) reference.  `mesh` is the job mesh the arrays are sharded
-    over (None: one device)."""
-    from paddle_operator_tpu.ops.pallas_attention import (
-        flash_attention,
-        flash_tiles,
-    )
+    over (None: one device).  ``blocks``: the kernel's
+    ``(block_q, block_k)`` where the caller has measured its own (None:
+    the kernel's defaults, the trainer's)."""
+    from paddle_operator_tpu.ops.pallas_attention import flash_attention
 
     if use_pallas is None:
-        use_pallas = (
-            jax.default_backend() == "tpu"
-            and flash_tiles(q.shape, k.shape, segment_ids)
-            and (mesh is None
-                 or _flash_axes(mesh, q.shape[2], k.shape[2]) is not None))
+        use_pallas = picks_flash(q.shape, k.shape, segment_ids, mesh,
+                                 blocks)
     if not use_pallas:
         return reference_attention(q, k, v, causal=causal,
                                    segment_ids=segment_ids)
     if mesh is not None:
         return sharded_flash_attention(mesh, q, k, v, causal=causal,
-                                       segment_ids=segment_ids)
-    return flash_attention(q, k, v, causal=causal, segment_ids=segment_ids)
+                                       segment_ids=segment_ids,
+                                       blocks=blocks)
+    return flash_attention(q, k, v, causal=causal, segment_ids=segment_ids,
+                           **_block_kw(blocks))

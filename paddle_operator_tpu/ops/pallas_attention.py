@@ -17,11 +17,17 @@ kernel walks k-blocks to produce dk/dv, another walks q-blocks for dq.
 Causality is exploited at block granularity: fully-masked tiles are skipped
 with `pl.when` (half the work), the diagonal gets an elementwise mask.
 
-Serving-side siblings live in ops/decode_attention.py: the single-query
-filled-prefix kernel (contiguous ring cache) and its PAGED variant, whose
-index map walks a block table into a global KV pool (infer/paged.py) —
-same online-softmax discipline as here, with the DMA skip driven by the
-fill length / table instead of causality.
+Serving reaches this kernel too: a whole-prompt prefill (infer/decode.py
+_prefill_layer — the ring's inserts, ``generate``, the prefill pod)
+attends over the prompt's own q, k, v through ops/attention.py
+``attention``, forward only.  Its serving-side siblings live in
+ops/decode_attention.py: the single-query filled-prefix kernel
+(contiguous ring cache) and its PAGED variant, whose index map walks a
+block table into a global KV pool (infer/paged.py) — same online-softmax
+discipline as here, with the DMA skip driven by the fill length / table
+instead of causality.  A forward that continues a cache (chunked slice,
+suffix insert, speculative block) attends through the XLA einsum over
+the cache.
 """
 
 from __future__ import annotations
@@ -40,6 +46,15 @@ NEG_INF = -1e30
 # ~5% at dim-4096; q1024 ties q512 with twice the VMEM tile.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
+# The forward ALONE (serving's whole-prompt prefill, infer/decode.py
+# _prefill_layer) wants wider blocks than forward + backward do.
+# Measured on v5e, causal, q [1, W, 32, 128] over 8 kv heads, ms a call
+# at q512/k512 -> q1024/k1024: W 1024: 0.329 -> 0.251, 2048: 0.902 ->
+# 0.590, 3072: 1.750 -> 1.058 (q512/k1024 1.175, q1024/k512 1.848: it is
+# the key block that pays) — fewer, larger grid steps, though more of the
+# diagonal tiles' work is masked.  q2048/k2048 does not fit VMEM.
+FORWARD_BLOCK_Q = 1024
+FORWARD_BLOCK_K = 1024
 
 
 

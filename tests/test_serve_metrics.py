@@ -448,7 +448,8 @@ class TestBatcherServingStatus:
                            "decodeLaneStepsTotal", "prefillCallsTotal",
                            "prefillTokensTotal",
                            "prefillBucketTokensTotal",
-                           "prefillCallsByBucket", "phaseSeconds",
+                           "prefillCallsByBucket",
+                           "prefillAttnByBucket", "phaseSeconds",
                            "phaseCounts"}
         # one 3-token prompt through the 16-wide insert, 3 more tokens
         # from 2-tick chunks on the one lane
@@ -456,6 +457,7 @@ class TestBatcherServingStatus:
         assert st["prefillTokensTotal"] == 3
         assert st["prefillBucketTokensTotal"] == 16
         assert st["prefillCallsByBucket"] == {"16": 1}
+        assert st["prefillAttnByBucket"] == {"16": "einsum", "32": "einsum"}
         assert st["decodeStepsTotal"] == 2 * st["dispatchesTotal"]
         assert st["decodeLaneStepsTotal"] == st["decodeStepsTotal"] >= 3
         assert st["phaseCounts"]["sched.admit"] == 1

@@ -223,3 +223,64 @@ def test_train_step_7b_width(topo, monkeypatch, mesh_axes):
     assert kernel_calls(compiled) == 4
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+
+
+def _serving_shapes(cfg, sharding_of):
+    """The serving parameter tree as shapes, each leaf with the sharding
+    ``sharding_of(tree)`` gives it."""
+    from paddle_operator_tpu.infer.quant import serving_params
+    from paddle_operator_tpu.models import llama as L
+
+    shapes = jax.eval_shape(
+        lambda r: serving_params(
+            L.Llama(cfg).init(r, jnp.zeros((1, 8), jnp.int32))["params"],
+            cfg.dtype), jax.random.PRNGKey(0))
+    return jax.tree.map(lambda x, s: sds(x.shape, x.dtype, s), shapes,
+                        sharding_of(shapes))
+
+
+def _gqa_7b(n_layers=2):
+    from paddle_operator_tpu.models import llama as L
+
+    return dataclasses.replace(L.CONFIGS["7b"], n_layers=n_layers,
+                               n_kv_heads=8, max_seq_len=4096)
+
+
+@pytest.mark.parametrize("width,calls", [(3072, 1), (1536, 1), (512, 0)])
+def test_whole_prompt_prefill(one_chip, monkeypatch, width, calls):
+    """ISSUE 29: a whole-prompt prefill at 7B width, 32 heads over 8 kv
+    heads — the flash kernel (the layer scan's one custom call) with the
+    forward's 1024 blocks at 3072 and the kernel's defaults at a 3:2
+    midpoint, the einsum under the measured threshold."""
+    from paddle_operator_tpu.infer import decode as D
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _gqa_7b()
+    assert D.prefill_attn_impl(cfg, width) == ("flash" if calls
+                                                else "einsum")
+    params = _serving_shapes(
+        cfg, lambda t: jax.tree.map(lambda _: one_chip, t))
+    compiled = jax.jit(lambda p, t: D.prefill(p, cfg, t, width)).lower(
+        params, sds((1, width), jnp.int32, one_chip)).compile()
+    assert kernel_calls(compiled) == calls
+
+
+def test_tp_whole_prompt_prefill(tp_mesh, monkeypatch):
+    """Under SERVE_TP the prefill's kernel enters the mesh through
+    shard_map in whole GQA groups (32 / 8 heads over tp = 4): a custom
+    call, and k, v are never all-gathered around it."""
+    from paddle_operator_tpu.infer import decode as D
+    from paddle_operator_tpu.models.llama import partition_patterns
+    from paddle_operator_tpu.parallel.sharding import tree_shardings
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _gqa_7b()
+    assert D.prefill_attn_impl(cfg, 2048, tp_mesh) == "flash"
+    params = _serving_shapes(cfg, lambda t: tree_shardings(
+        t, tp_mesh, partition_patterns(cfg), replicate_indivisible=True))
+    text = jax.jit(lambda p, t: D.prefill(p, cfg, t, 2048, mesh=tp_mesh)
+                   ).lower(params, sds((1, 2048), jnp.int32,
+                                       NamedSharding(tp_mesh, P()))
+                           ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "all-reduce" in text and "all-gather" not in text
