@@ -263,7 +263,7 @@ def measure_ring_throughput(cfg, params, *, slots: int, requests: int,
                             long_prompt_len: int = None,
                             mesh=None) -> dict:
     """Served throughput through the continuous-batching decode ring
-    (infer/batcher.py) under saturation: `requests` concurrent clients
+    (infer/scheduler.py) under saturation: `requests` concurrent clients
     over `slots` lanes.  The VERDICT r3 item-5 'done' bar is served
     throughput within ~20% of the raw decode bench at the same batch —
     this measures it as artifact data.  Includes admission (bucketed
@@ -285,7 +285,7 @@ def measure_ring_throughput(cfg, params, *, slots: int, requests: int,
     and cache over the mesh's tp axis)."""
     import numpy as np
 
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
     buckets = (prompt_len,)
     if long_prompt_len and long_prompt_len > prompt_len:
@@ -451,7 +451,7 @@ def measure_paged_serving(cfg, params, *, slots: int = 4,
     (serve-paged line) — this function measures, it does not assert."""
     import numpy as np
 
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
     max_len = max_len or (max(prompt_lens) + new_tokens)
     rng = np.random.default_rng(0)
@@ -556,7 +556,7 @@ def measure_disagg_serving(cfg, params, *, slots: int = 4,
     assert."""
     import numpy as np
 
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
     max_len = max_len or (prompt_len + max(bg_new_tokens, 64))
     # deliberately COARSE buckets (the serve.py default shape): inline
@@ -664,7 +664,7 @@ def measure_quantized_pool(cfg, params, *, prompt_len: int = 16,
 
     import jax.numpy as jnp
 
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
     # a lane's worst-case block need (prompt + chunk-rounded budget,
     # plus one chunk of pipelined ensure() projection)
@@ -848,7 +848,7 @@ def measure_hierarchical_cache(cfg, params, *, n_prompts: int = 8,
     host bytes over host-hit admission seconds."""
     import numpy as np
 
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
     from paddle_operator_tpu.infer.paged import host_block_bytes
 
     max_len = max_len or (prompt_len + new_tokens)
@@ -963,7 +963,7 @@ def measure_kv_store(cfg, params, *, n_prompts: int = 6,
     import numpy as np
 
     from paddle_operator_tpu.infer import decode as ID
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
     from paddle_operator_tpu.infer.kvstore import DirBackend, KVBlockStore
 
     max_len = max_len or (prompt_len + new_tokens)
@@ -1118,7 +1118,7 @@ def measure_qos(cfg, params, *, slots: int = 2, prompt_len: int = 16,
     """
     import numpy as np
 
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
     from paddle_operator_tpu.infer.executor import RingExecutor
     from paddle_operator_tpu.infer.qos import AdapterRegistry
 
@@ -1515,7 +1515,7 @@ def measure_megastep(cfg, params, *, dcfg=None, dparams=None,
 
     import numpy as np
 
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
     rng = np.random.default_rng(7)
     rows = []
@@ -1559,7 +1559,7 @@ def _megastep_cell(cfg, params, dcfg, dparams, prompts, n, batch, spec,
                    block_size, repeats, host_load_threads):
     import os as _os
 
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
     kw = dict(slots=batch, max_len=max_len, chunk_tokens=chunk,
               prefill_buckets=(prompt_len, max_len), paged=True,
@@ -2024,7 +2024,7 @@ def measure_weight_swap(*, n_requests: int = 6, new_tokens: int = 4,
     import jax as _jax
     import jax.numpy as _jnp
 
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
     from paddle_operator_tpu.models.llama import make_model
 
     model, cfg = make_model("tiny", dtype=_jnp.float32)
@@ -2793,7 +2793,7 @@ def measure_prefill_pool(*, prompt_lens=(256, 2048), bursts=(16, 6),
     # overlapped term is host serialize + upload; in the DCN regime
     # the wire term dominates the monolithic tail and the win grows
     # with prompt length and link latency (docs/serving.md).
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
     from paddle_operator_tpu.infer.prefill_serve import (
         RemotePrefillClient,
         make_prefill_server,
@@ -3120,7 +3120,7 @@ def measure_trace_overhead(*, slots: int = 4, requests: int = 12,
     import jax.numpy as jnp
     import numpy as np
 
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
     from paddle_operator_tpu.models import llama as L
     from paddle_operator_tpu.utils import tracing as TR
 
@@ -3200,7 +3200,7 @@ def measure_resilience(fault_rates=(0, 1, 5), *, slots: int = 2,
     import jax.numpy as jnp
     import numpy as np
 
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
     from paddle_operator_tpu.infer.chaos import ChaosEvent, ChaosInjector
     from paddle_operator_tpu.infer.resilience import RingResilience
     from paddle_operator_tpu.models import llama as L

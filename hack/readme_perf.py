@@ -124,7 +124,7 @@ def render(t, source=None) -> str:
                 f"% of raw same-shape decode" if raw else "")
         lines.append(
             f"- served, through the continuous-batching ring "
-            f"(infer/batcher.py; 8 lanes, 16 concurrent requests, "
+            f"(infer/scheduler.py; 8 lanes, 16 concurrent requests, "
             f"chunk {ring['ring_chunk']}): "
             f"**{ring['ring_tok_per_sec']:.0f} tok/s**{frac}; "
             f"free-lane TTFT {ring['ring_ttft_ms']:.0f} ms "

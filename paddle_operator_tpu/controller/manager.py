@@ -287,7 +287,7 @@ class Manager:
         """Mirror each job's workload-published telemetry blocks into
         per-job gauges on ``/metrics``: ``status.goodput``
         (ft/goodput.py -> ``tpujob_goodput_*``/``tpujob_badput_seconds``)
-        and ``status.serving`` (infer/batcher.py serving_status ->
+        and ``status.serving`` (infer/scheduler.py serving_status ->
         ``tpujob_serve_tokens_per_sec``/``tpujob_serve_accept_rate``/
         ``tpujob_serve_queue_depth``, plus the fault-tolerance gauges
         ``tpujob_serve_watchdog_restarts``/``..._deadline_exceeded``/

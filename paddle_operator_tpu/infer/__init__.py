@@ -12,10 +12,9 @@ _DECODE_EXPORTS = (
     "init_cache",
     "make_decode_fn",
     "prefill",
-    "speculative_generate",
 )
 
-__all__ = list(_DECODE_EXPORTS)
+__all__ = [*_DECODE_EXPORTS, "speculative_generate"]
 
 
 def __getattr__(name):
@@ -23,4 +22,8 @@ def __getattr__(name):
         from paddle_operator_tpu.infer import decode
 
         return getattr(decode, name)
+    if name == "speculative_generate":
+        from paddle_operator_tpu.infer import speculative
+
+        return speculative.speculative_generate
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

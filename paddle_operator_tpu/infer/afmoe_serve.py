@@ -27,6 +27,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from paddle_operator_tpu.infer import decode as D
+from paddle_operator_tpu.infer import paged as PG
 from paddle_operator_tpu.models import afmoe as M
 from paddle_operator_tpu.models.afmoe import AfmoeConfig
 
@@ -137,62 +139,51 @@ def forward(cfg: AfmoeConfig, params: Dict[str, Any], tokens: jax.Array,
 
 def paged_ring_forward(cfg: AfmoeConfig, params, tok: jax.Array, cache,
                        table: jax.Array, active: jax.Array):
-    """``paged.paged_ring_forward`` for this architecture: ``tok [B]`` at
+    """The paged ring's decode step for this architecture: ``tok [B]`` at
     per-lane ``cache['pos']`` -> (logits ``[B, V]``, advanced cache, the
     step's load ``[E]`` over `active` lanes and its experts touched,
-    summed over the expert layers)."""
-    from paddle_operator_tpu.infer import paged as PG
-    from paddle_operator_tpu.ops.decode_attention import (
-        paged_decode_attention,
-    )
-
+    summed over the expert layers).  The pool is reached through its
+    view (``paged.PagedView``: the token write, the decode kernel with a
+    layer's window, the gathered lanes for :func:`models.afmoe.attend`);
+    the block is this architecture's own."""
     pos = cache["pos"]
-    b = tok.shape[0]
-    bs = cache["k"].shape[3]
     x = M.embed(cfg, params, tok[:, None])
     cos, sin = M.rope_tables(cfg)
-    attn_impl = cfg.resolved_decode_attn()
+    view = PG.PagedView(cfg, cache, table)
+    view.enter(1)
     nd = cfg.n_dense_layers
     windows, ropes = cfg.windows(), cfg.ropes()
     counted = active[:, None]
     scanned, experts = M.split_experts(params["moe_layers"])
 
-    def block(lp, li, x, kc, vc, window, use_rope, moe_layer=None):
+    def block(lp, li, x, bufs, window, use_rope, moe_layer=None):
         q, k, v, g = M.attn_inputs(cfg, lp, x, cos, sin, pos[:, None],
                                    use_rope)
-        kc = PG._write_token_paged(kc, k.transpose(0, 2, 1, 3), li, table,
-                                   pos, bs)
-        vc = PG._write_token_paged(vc, v.transpose(0, 2, 1, 3), li, table,
-                                   pos, bs)
-        if attn_impl != "xla":
-            att = paged_decode_attention(
-                q[:, 0], kc, vc, table, pos + 1, layer=li,
-                starts=jnp.maximum(pos + 1 - window, 0),
-                interpret=(attn_impl == "pallas-interpret"))
-            att = att.reshape(b, 1, -1).astype(cfg.dtype)
+        bufs = view.write(bufs, li, k, v)
+        if view.kernel:
+            att = view.kernel_attend(bufs, li, q, window=window)
         else:
-            att = M.attend(cfg, q, PG._gather_lane_view(kc, table, li),
-                           PG._gather_lane_view(vc, table, li),
-                           pos[:, None], window)
+            att = M.attend(cfg, q, *view.lanes(bufs, li), pos[:, None],
+                           window)
         a = M.attn_residual(cfg, lp, x, att, g)
         y, load = M.ffn_residual(
             cfg, lp, a, None if moe_layer is None else experts, moe_layer,
             counted)
-        return y, kc, vc, load
+        return y, bufs, load
 
-    kc, vc = cache["k"], cache["v"]
+    bufs = (cache["k"], cache["v"])
     for i in range(nd):
-        x, kc, vc, _ = block(M.layer_at(params["dense_layers"], i),
-                             jnp.int32(i), x, kc, vc, windows[i], ropes[i])
+        x, bufs, _ = block(M.layer_at(params["dense_layers"], i),
+                           jnp.int32(i), x, bufs, windows[i], ropes[i])
 
     def body(carry, layer_in):
-        x, kc, vc = carry
+        x, bufs = carry
         lp, li, window, use_rope = layer_in
-        y, kc, vc, load = block(lp, li, x, kc, vc, window, use_rope, li - nd)
-        return (y, kc, vc), load
+        y, bufs, load = block(lp, li, x, bufs, window, use_rope, li - nd)
+        return (y, bufs), load
 
-    (x, kc, vc), loads = jax.lax.scan(
-        body, (x, kc, vc),
+    (x, (kc, vc)), loads = jax.lax.scan(
+        body, (x, bufs),
         (scanned, jnp.arange(nd, cfg.n_layers),
          jnp.asarray(windows[nd:], jnp.int32),
          jnp.asarray(ropes[nd:], bool)))
@@ -213,15 +204,13 @@ def make_paged_chunk_step(cfg: AfmoeConfig, chunk_tokens: int,
 
     ``step(params, cache, table, tok, temp, keys, active)
     -> (cache', tok', toks [chunk, B][, ok [B]], moe)``"""
-    from paddle_operator_tpu.infer.executor import _sample_tokens
-
     def step(params, cache, table, tok, temp, keys, active):
         def tick(carry, _):
             cache, tok, ok, load, touched = carry
             logits, new_cache, l, t = paged_ring_forward(
                 cfg, params, tok, cache, table, active)
-            nxt = _sample_tokens(logits, temp, keys, cache["pos"],
-                                 top_k, top_p)
+            nxt = D._sample_tokens(logits, temp, keys, cache["pos"],
+                                   top_k, top_p)
             new_cache["pos"] = jnp.where(active, new_cache["pos"], 0)
             nxt = jnp.where(active, nxt, tok)
             if check_finite:
@@ -254,10 +243,6 @@ def make_paged_prefill_insert(cfg: AfmoeConfig, bucket: int, block_size: int,
     into the pool as whole blocks at the lane's table entries, the first
     token sampled).  The real prompt tokens' assignments by expert add
     to ``cache['moe_pf']``."""
-    from paddle_operator_tpu.infer import decode as D
-    from paddle_operator_tpu.infer.executor import _sample_tokens
-    from paddle_operator_tpu.infer.paged import _scatter_prompt_blocks
-
     if bucket % block_size:
         raise ValueError(f"prefill bucket {bucket} not a multiple of the "
                          f"block size {block_size}")
@@ -271,14 +256,14 @@ def make_paged_prefill_insert(cfg: AfmoeConfig, bucket: int, block_size: int,
                                      counted=counted)
         new_cache = dict(
             cache,
-            k=_scatter_prompt_blocks(cache["k"], lane["k"], table_row,
-                                     block_size),
-            v=_scatter_prompt_blocks(cache["v"], lane["v"], table_row,
-                                     block_size),
+            k=PG.scatter_prompt_blocks(cache["k"], lane["k"], table_row,
+                                       block_size),
+            v=PG.scatter_prompt_blocks(cache["v"], lane["v"], table_row,
+                                       block_size),
             pos=cache["pos"].at[slot].set(prompt_len),
             moe_pf=cache["moe_pf"] + load)
         key = jax.random.PRNGKey(seed)
-        first = _sample_tokens(
+        first = D._sample_tokens(
             logits[0], jnp.reshape(temp_val, (1,)).astype(jnp.float32),
             key[None], jnp.reshape(prompt_len - 1, (1,)), top_k, top_p)[0]
         return (new_cache, tok.at[slot].set(first),

@@ -66,7 +66,7 @@ def shard_params_for_serving(params: Dict[str, Any], cfg: LlamaConfig,
 
 def _use_sharded_kernel(cfg: LlamaConfig, mesh, attn_impl: str) -> bool:
     """THE kernel-eligibility rule for tp>1 meshes, shared by
-    decode._forward and batcher._ring_forward: the pallas kernel enters
+    :func:`_forward` and the cache views: the pallas kernel enters
     a sharded mesh only through shard_map (sharded_decode_attention)
     and only when whole GQA groups split; everything else serves
     through the GSPMD einsum path."""
@@ -85,6 +85,19 @@ def resolve_decode_attn(cfg: LlamaConfig, mesh) -> Tuple[str, bool]:
     if mesh_tp(mesh) > 1 and not use_sharded:
         attn_impl = "xla"
     return attn_impl, use_sharded
+
+
+def decode_kernel_mode(cfg: LlamaConfig, mesh, step: bool
+                       ) -> Tuple[bool, bool, bool]:
+    """``(kernel, projects, interpret)`` for a cache view's attention
+    (:class:`ContiguousView`, infer/paged.py): whether a forward that is
+    (``step``) the decode step — one token a lane — attends through the
+    decode kernel, whether that is the TP-sharded region which applies
+    ``wo`` itself, and whether the kernel runs interpreted.  Every other
+    forward attends through the einsum (:func:`_attend_cache`)."""
+    attn_impl, sharded = resolve_decode_attn(cfg, mesh)
+    kernel = step and attn_impl != "xla"
+    return kernel, kernel and sharded, attn_impl == "pallas-interpret"
 
 
 def alloc_kv_buffer(cfg: LlamaConfig, shape, mesh) -> jax.Array:
@@ -108,7 +121,7 @@ def alloc_kv_buffer(cfg: LlamaConfig, shape, mesh) -> jax.Array:
 
 # Named scopes put a layer's name into every device operation's
 # ``op_name``, one vocabulary wherever the block is written out (here,
-# infer/executor.py, infer/paged.py, infer/speculative.py,
+# the cache views' writes and kernel calls in infer/paged.py,
 # models/llama.py): embed, norm, attn.qkv, attn.rope, cache_write,
 # attn.kernel, attn.out, ffn, lm_head, sample — and loss, opt_update in
 # the train step.  They change no computation and no compile-cache key
@@ -167,6 +180,28 @@ def _rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
     return out.astype(x.dtype)
 
 
+@jax.named_scope("attn.rope")
+def _rope_lanes(q: jax.Array, k: jax.Array, cos: jax.Array, sin: jax.Array,
+                rows: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """:func:`_rope` of q and k ``[B, T, H, D]`` with every lane at its
+    own position: ``rows`` is ``[B]`` for one token a lane, else
+    ``[B, T]`` (the table slice is a plain gather ``cos[rows]``)."""
+    if rows.ndim == 1:
+        cos_b = cos[rows][:, None, None, :]          # [B, 1, 1, d/2]
+        sin_b = sin[rows][:, None, None, :]
+    else:
+        cos_b = cos[rows][:, :, None, :]             # [B, T, 1, d/2]
+        sin_b = sin[rows][:, :, None, :]
+
+    def rot(u):
+        u1, u2 = jnp.split(u.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate(
+            [u1 * cos_b - u2 * sin_b, u2 * cos_b + u1 * sin_b],
+            axis=-1).astype(u.dtype)
+
+    return rot(q), rot(k)
+
+
 def cache_alloc_len(max_len: int) -> int:
     """Allocation length for a KV cache of logical capacity ``max_len``:
     rounded up to a whole number of pallas key blocks
@@ -210,6 +245,171 @@ def init_cache(cfg: LlamaConfig, batch: int,
         "v": alloc_kv_buffer(cfg, shape, mesh),
         "pos": jnp.zeros((), jnp.int32),
     }
+
+
+# ---------------------------------------------------------------------------
+# The contiguous ring: one cache lane a request, a fill position a lane
+# ---------------------------------------------------------------------------
+
+
+def init_ring_cache(cfg: LlamaConfig, slots: int,
+                    max_len: int, mesh=None) -> Dict[str, jax.Array]:
+    """KV ring: like :func:`init_cache` (same head-major layout,
+    block-aligned allocation, same kv-head tp sharding under a serving
+    mesh) but with a per-lane fill position vector instead of one
+    scalar."""
+    if max_len > cfg.max_seq_len:
+        raise ValueError(f"max_len {max_len} exceeds the RoPE table "
+                         f"(cfg.max_seq_len={cfg.max_seq_len})")
+    alloc = cache_alloc_len(max_len)
+    shape = (cfg.n_layers, slots, cfg.n_kv_heads, alloc, cfg.head_dim)
+    return {
+        "k": alloc_kv_buffer(cfg, shape, mesh),
+        "v": alloc_kv_buffer(cfg, shape, mesh),
+        "pos": jnp.zeros((slots,), jnp.int32),
+    }
+
+
+@jax.named_scope("cache_write")
+def _write_lane(cache_l: jax.Array, kv: jax.Array,
+                pos: jax.Array) -> jax.Array:
+    """[B, H, S, D] cache layer <- [B, H, 1, D] new row at per-lane pos."""
+    return jax.vmap(
+        lambda c, x, p: jax.lax.dynamic_update_slice(c, x, (0, p, 0))
+    )(cache_l, kv, pos)
+
+
+@jax.named_scope("cache_write")
+def _write_lane_rows(cache_l: jax.Array, kv: jax.Array,
+                     pos: jax.Array) -> jax.Array:
+    """[B, H, S, D] cache layer <- [B, H, T, D] new rows at per-lane
+    start positions ``pos``.  Unrolled per lane (static slot count) for
+    the same reason as :func:`_write_lane_stacked`: a vmapped update
+    over ragged positions lowers to a scatter that copies the carry."""
+    for lane in range(kv.shape[0]):
+        cache_l = jax.lax.dynamic_update_slice(
+            cache_l, kv[lane][None], (lane, 0, pos[lane], 0))
+    return cache_l
+
+
+@jax.named_scope("cache_write")
+def _write_lane_stacked(stack: jax.Array, kv: jax.Array, li: jax.Array,
+                        pos: jax.Array) -> jax.Array:
+    """[L, B, H, S, D] stacked cache <- [B, H, 1, D] new rows at layer
+    ``li`` and per-lane positions ``pos``.
+
+    One dynamic_update_slice PER LANE (a static unroll over the slot
+    count), not a vmapped/batched update: vmapping over ragged lane
+    positions lowers to a scatter, and a scatter into the scan-carried
+    stack makes XLA materialize a copy of the whole ring cache per
+    layer per tick — measured 30x slower than raw decode.  Chained
+    single-row dus ops update the carry in place."""
+    b = kv.shape[0]
+    for lane in range(b):
+        stack = jax.lax.dynamic_update_slice(
+            stack, kv[lane][None, None], (li, lane, 0, pos[lane], 0))
+    return stack
+
+
+def _splice_lane(ring: Dict[str, jax.Array], lane: Dict[str, jax.Array],
+                 slot, prompt_len) -> Dict[str, jax.Array]:
+    """Zero ring lane ``slot`` and splice a freshly prefilled
+    batch-of-one lane cache into it, setting the lane's fill position
+    to ``prompt_len`` — the device half of admission, shared by the
+    plain, speculative and chunked-final inserts so their splice
+    semantics cannot drift.  A lane cache LONGER than the ring lane
+    (a chunk-width-padded staging cache) is truncated: rows past the
+    ring allocation are pads by construction."""
+    ring_alloc = ring["k"].shape[3]
+    lane_k, lane_v = lane["k"], lane["v"]
+    if lane_k.shape[3] > ring_alloc:
+        lane_k = lane_k[:, :, :, :ring_alloc]
+        lane_v = lane_v[:, :, :, :ring_alloc]
+    k = jnp.zeros_like(ring["k"][:, 0])
+    k = jax.lax.dynamic_update_slice(k, lane_k[:, 0], (0, 0, 0, 0))
+    v = jnp.zeros_like(ring["v"][:, 0])
+    v = jax.lax.dynamic_update_slice(v, lane_v[:, 0], (0, 0, 0, 0))
+    new_k = jax.lax.dynamic_update_slice(
+        ring["k"], k[:, None], (0, slot, 0, 0, 0))
+    new_v = jax.lax.dynamic_update_slice(
+        ring["v"], v[:, None], (0, slot, 0, 0, 0))
+    return {"k": new_k, "v": new_v,
+            "pos": ring["pos"].at[slot].set(prompt_len)}
+
+
+class ContiguousView:
+    """The contiguous ring as :func:`cached_forward` sees a cache — a
+    view owns the two decisions a cache format brings and nothing else:
+    how ``T`` new rows a lane are written, and what attends over them
+    (which also says how the buffers ride the layer scan).  The ring,
+    the speculative draft and a chunked prefill's staging lane all hold
+    one: ``k``/``v`` ``[L, B, H_kv, S, D]`` and ``pos [B]``.
+
+    One token a lane with the decode kernel on: the caches stay STACKED
+    and ride the scan as carry, the layer's index steering the kernel's
+    block index map (:func:`_forward` has the measured reason), and
+    under a serving mesh the kernel and the output projection run
+    TP-sharded in one manual region a layer (``projects``: the view's
+    ``attend`` then returns the projected residual).  Else the einsum
+    (:func:`_attend_cache`) over one layer's caches, scanned as xs — the
+    oracle's program.
+
+    The paged pool's views are beside the pool (infer/paged.py)."""
+
+    def __init__(self, cfg: LlamaConfig, cache: Dict[str, jax.Array],
+                 mesh=None) -> None:
+        self.cfg, self.mesh = cfg, mesh
+        self.k, self.v, self.pos = cache["k"], cache["v"], cache["pos"]
+
+    def begin(self, t: int):
+        """``(held, rode)`` for a forward of ``t`` tokens a lane: what
+        the layer scan carries whole and what it scans layer by layer
+        (``stacked``: the caches and the layer's index; else nothing
+        and the caches)."""
+        self.stacked, self.projects, self.interpret = decode_kernel_mode(
+            self.cfg, self.mesh, t == 1)
+        if self.stacked:
+            return (self.k, self.v), jnp.arange(self.cfg.n_layers)
+        return (), (self.k, self.v)
+
+    def write(self, bufs, li, k: jax.Array, v: jax.Array):
+        """New ``[B, T, H, D]`` rows at ``pos[b] + j`` into ``bufs``."""
+        k_c, v_c = bufs
+        if self.stacked:
+            k_c = _write_lane_stacked(k_c, k.transpose(0, 2, 1, 3), li,
+                                      self.pos)
+            v_c = _write_lane_stacked(v_c, v.transpose(0, 2, 1, 3), li,
+                                      self.pos)
+            return k_c, v_c
+        write = _write_lane if k.shape[1] == 1 else _write_lane_rows
+        k_c = write(k_c, k.transpose(0, 2, 1, 3), self.pos)
+        v_c = write(v_c, v.transpose(0, 2, 1, 3), self.pos)
+        return k_c, v_c
+
+    def attend(self, bufs, li, q: jax.Array, rows: jax.Array, wo):
+        """``q [B, T, Hq, D]`` at absolute positions ``rows`` over what
+        :meth:`write` left -> ``[B, T, Hq*D]``, or (``projects``) the
+        residual ``[B, dim]`` already through ``wo``."""
+        k_c, v_c = bufs
+        if not self.stacked:
+            return _attend_cache(self.cfg, q, k_c, v_c, rows)
+        from paddle_operator_tpu.ops.decode_attention import (
+            decode_attention,
+            sharded_decode_attention,
+        )
+
+        if self.projects:
+            return sharded_decode_attention(
+                self.mesh, q[:, 0], k_c, v_c, self.pos + 1, wo, layer=li,
+                interpret=self.interpret, compute_dtype=self.cfg.dtype)
+        out = decode_attention(q[:, 0], k_c, v_c, self.pos + 1, layer=li,
+                               interpret=self.interpret)
+        return out.reshape(q.shape[0], 1, -1).astype(self.cfg.dtype)
+
+    def end(self, bufs, t: int) -> Dict[str, jax.Array]:
+        """The cache dict back, ``t`` rows a lane further."""
+        k, v = bufs
+        return {"k": k, "v": v, "pos": self.pos + t}
 
 
 def _qkv(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
@@ -311,9 +511,16 @@ def _write_rows(k_cache: jax.Array, v_cache: jax.Array, k: jax.Array,
 
 @jax.named_scope("attn.kernel")
 def _attend_cache(cfg: LlamaConfig, q: jax.Array, k_cache: jax.Array,
-                  v_cache: jax.Array, pos: jax.Array) -> jax.Array:
-    """The XLA einsum attention of [B, T] new positions starting at
-    ``pos`` against head-major caches [B, H_kv, S, D] -> [B, T, Hq*D].
+                  v_cache: jax.Array, rows: jax.Array) -> jax.Array:
+    """THE XLA einsum attention of [B, T] new positions against
+    head-major caches [B, H_kv, S, D] (one layer of a contiguous cache,
+    or a paged pool's gathered lane view) -> [B, T, Hq*D].  ``rows``
+    says where the query rows sit, and so what each may attend — cache
+    columns up to its own absolute position, causal and fill mask in
+    one: a scalar (every lane's T rows start there: :func:`_forward`),
+    ``[B]`` (one row a lane, at the lane's position) or ``[B, T]``.
+    Masked columns contribute exact zeros, so a view's stale or unmapped
+    tail never shows.
     Quadratic in HBM for a multi-token block (float32 scores
     [B, T, Hkv, n_rep, S]): whole-prompt prefill leaves it for the flash
     kernel where that runs (:func:`_prefill_layer`)."""
@@ -328,15 +535,22 @@ def _attend_cache(cfg: LlamaConfig, q: jax.Array, k_cache: jax.Array,
     n_rep = hq // hkv
     max_len = k_cache.shape[2]
     qg = q.reshape(b, t, hkv, n_rep, d)
-    # scores [B, T, Hkv, n_rep, max_len]; rows may attend cache cols
-    # up to their own absolute position (causal + fill mask in one)
+    # scores [B, T, Hkv, n_rep, max_len]
     scores = jnp.einsum("bthrd,bhsd->bthrs", qg, k_cache,
                         preferred_element_type=jnp.float32) / jnp.sqrt(
         jnp.float32(d))
-    cols = jnp.arange(max_len)                           # [S]
-    rows = pos + jnp.arange(t)                           # [T]
-    mask = cols[None, :] <= rows[:, None]                # [T, S]
-    scores = jnp.where(mask[None, :, None, None, :], scores, -1e30)
+    if rows.ndim == 0:
+        cols = jnp.arange(max_len)                           # [S]
+        rows = rows + jnp.arange(t)                          # [T]
+        mask = (cols[None, :] <= rows[:, None])[None, :, None, None, :]
+    elif rows.ndim == 1:
+        mask = jnp.arange(max_len)[None, :] <= rows[:, None]     # [B, S]
+        mask = mask[:, None, None, None, :]
+    else:
+        mask = (jnp.arange(max_len)[None, None, :]
+                <= rows[:, :, None])                          # [B, T, S]
+        mask = mask[:, :, None, None, :]
+    scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bthrs,bhsd->bthrd", probs.astype(cfg.dtype),
                      v_cache, preferred_element_type=jnp.float32)
@@ -467,7 +681,7 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
     ``whole_prompt``: the caller made ``cache`` itself, in this same
     call, with :func:`init_cache` — it is empty and at position 0, and
     ``tokens`` is everything it will hold.  Set by :func:`prefill`,
-    :func:`paged_prefill` and the ring's whole-prompt inserts, never by
+    infer/paged.py ``paged_prefill`` and the ring's whole-prompt inserts, never by
     a forward that continues a cache.  Such a prefill has two attention
     paths, chosen per width before tracing (:func:`prefill_attn_impl`):
     the flash kernel over the prompt's own q, k, v
@@ -492,90 +706,55 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
                                 cfg.rope_theta)
 
     attn_impl, use_sharded = resolve_decode_attn(cfg, mesh)
-    if tokens.shape[1] == 1 and use_sharded:
-        # TP-sharded kernel: same stacked-cache scan as below, but the
-        # attention + output projection run inside one manual region per
-        # layer (ops/decode_attention.py sharded_decode_attention)
-        from paddle_operator_tpu.ops.decode_attention import (
-            sharded_decode_attention,
-        )
-
-        b = x.shape[0]
-
-        def body(carry, layer_in):
-            x, kc, vc = carry
-            if adp is not None:
-                lp, adp_l, li = layer_in
-                lo = (adp_l, aid)
-            else:
-                lp, li = layer_in
-                lo = None
-            q, k, v = _qkv(cfg, lp, x, cos, sin, pos, lora=lo)
-            kc = jax.lax.dynamic_update_slice(
-                kc, k.transpose(0, 2, 1, 3)[None], (li, 0, 0, pos, 0))
-            vc = jax.lax.dynamic_update_slice(
-                vc, v.transpose(0, 2, 1, 3)[None], (li, 0, 0, pos, 0))
-            proj = sharded_decode_attention(
-                mesh, q[:, 0], kc, vc, jnp.broadcast_to(pos + 1, (b,)),
-                lp["attn"]["wo"]["kernel"], layer=li,
-                interpret=(attn_impl == "pallas-interpret"),
-                compute_dtype=cfg.dtype)
-            x = x + proj[:, None].astype(cfg.dtype)
-            return (_ffn_residual(cfg, lp, x), kc, vc), ()
-
-        xs = ((params["layers"], adp, jnp.arange(cfg.n_layers))
-              if adp is not None
-              else (params["layers"], jnp.arange(cfg.n_layers)))
-        (x, k_new, v_new), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]), xs)
-    elif tokens.shape[1] == 1 and attn_impl != "xla":
+    if tokens.shape[1] == 1 and attn_impl != "xla":
         # pallas decode path: the caches stay STACKED [L, B, H, S, D]
         # and flow as scan CARRY, with the layer index steering the
         # kernel's block index map.  Scanning them as xs (the einsum
         # structure below) would slice each layer out first, and a
         # dynamic-slice that feeds a pallas custom-call must be
         # materialized by XLA — a per-layer copy of the layer's whole
-        # cache, measured +170us/layer at b8.
-        from paddle_operator_tpu.ops.decode_attention import decode_attention
+        # cache, measured +170us/layer at b8.  Under a tp mesh the
+        # attention + output projection run inside one manual region per
+        # layer (ops/decode_attention.py sharded_decode_attention).
+        from paddle_operator_tpu.ops.decode_attention import (
+            decode_attention,
+            sharded_decode_attention,
+        )
 
         b = x.shape[0]
         hq, d = cfg.n_heads, cfg.head_dim
+        interpret = attn_impl == "pallas-interpret"
 
         def body(carry, layer_in):
             x, kc, vc = carry
-            if adp is not None:
-                lp, adp_l, li = layer_in
-                lo = (adp_l, aid)
-            else:
-                lp, li = layer_in
-                lo = None
+            lp, li, lo = _layer_operands(layer_in, aid)
             q, k, v = _qkv(cfg, lp, x, cos, sin, pos, lora=lo)
             kc = jax.lax.dynamic_update_slice(
                 kc, k.transpose(0, 2, 1, 3)[None], (li, 0, 0, pos, 0))
             vc = jax.lax.dynamic_update_slice(
                 vc, v.transpose(0, 2, 1, 3)[None], (li, 0, 0, pos, 0))
+            if use_sharded:
+                proj = sharded_decode_attention(
+                    mesh, q[:, 0], kc, vc, jnp.broadcast_to(pos + 1, (b,)),
+                    lp["attn"]["wo"]["kernel"], layer=li,
+                    interpret=interpret, compute_dtype=cfg.dtype)
+                x = x + proj[:, None].astype(cfg.dtype)
+                return (_ffn_residual(cfg, lp, x), kc, vc), ()
             out = decode_attention(
                 q[:, 0], kc, vc, jnp.broadcast_to(pos + 1, (b,)),
-                layer=li, interpret=(attn_impl == "pallas-interpret"))
+                layer=li, interpret=interpret)
             out = out.reshape(b, 1, hq * d).astype(cfg.dtype)
             return (_finish_layer(cfg, lp, x, out), kc, vc), ()
 
-        xs = ((params["layers"], adp, jnp.arange(cfg.n_layers))
-              if adp is not None
-              else (params["layers"], jnp.arange(cfg.n_layers)))
         (x, k_new, v_new), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]), xs)
+            body, (x, cache["k"], cache["v"]),
+            (params["layers"], adp, jnp.arange(cfg.n_layers)))
     else:
         flash = whole_prompt and prefill_attn_impl(
             cfg, tokens.shape[1], mesh) == "flash"
 
         def body(x, layer_in):
-            if adp is not None:
-                lp, adp_l, k_c, v_c = layer_in
-                lo = (adp_l, aid)
-            else:
-                lp, k_c, v_c = layer_in
-                lo = None
+            lp, (k_c, v_c), lo = _layer_operands(layer_in, aid)
             if flash:
                 y, k_c, v_c = _prefill_layer(cfg, lp, x, cos, sin, k_c,
                                              v_c, pos, lora=lo, mesh=mesh)
@@ -584,16 +763,115 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
                                      lora=lo)
             return y, (k_c, v_c)
 
-        xs = ((params["layers"], adp, cache["k"], cache["v"])
-              if adp is not None
-              else (params["layers"], cache["k"], cache["v"]))
-        x, (k_new, v_new) = jax.lax.scan(body, x, xs)
+        x, (k_new, v_new) = jax.lax.scan(
+            body, x, (params["layers"], adp, (cache["k"], cache["v"])))
     if last_only:
         x = x[:, -1:]
     logits = _lm_head(cfg, params, x)
     new_cache = {"k": k_new, "v": v_new,
                  "pos": pos + tokens.shape[1]}
     return logits, new_cache
+
+
+def _layer_operands(layer_in, aid):
+    """What a layer scan hands its body, ``(lp, adp_l, rode)`` — one
+    layer's params, its slice of the stacked LoRA arrays (None on an
+    adapterless ring: an empty node of the scanned tree, so the traced
+    program is the adapterless one) and whatever else rides the scan —
+    as ``(lp, rode, lora)`` with ``lora`` the ``(adp_l, aid)`` the
+    projections take (:func:`_qkv_proj`)."""
+    lp, adp_l, rode = layer_in
+    return lp, rode, (None if adp_l is None else (adp_l, aid))
+
+
+# ---------------------------------------------------------------------------
+# The ONE cached forward at per-lane positions, over a cache view
+# ---------------------------------------------------------------------------
+
+
+def _cached_layer(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
+                  cos: jax.Array, sin: jax.Array, view, bufs, li, lora):
+    """One decoder layer over [B, T] new tokens, lane b's at positions
+    ``view.pos[b] + j``: projections, rotation at each row's own
+    position, the view's write, the view's attention, output projection
+    and feed-forward.  :func:`_layer`'s mathematics with the scalar
+    position a vector and the cache behind a view."""
+    t = x.shape[1]
+    h = _rms(x, lp["attn_norm"]["scale"], cfg.norm_eps, cfg.dtype)
+    q, k, v = _qkv_proj(cfg, lp, h, t, lora)
+    rows = (view.pos if t == 1
+            else view.pos[:, None] + jnp.arange(t)[None, :])    # [B, T]
+    q, k = _rope_lanes(q, k, cos, sin, rows)
+    bufs = view.write(bufs, li, k, v)
+    out = view.attend(bufs, li, q, rows, lp["attn"]["wo"]["kernel"])
+    if view.projects:
+        # the TP-sharded kernel applied wo inside its manual region
+        x = x + out[:, None].astype(cfg.dtype)
+        return _ffn_residual(cfg, lp, x), bufs
+    return _finish_layer(cfg, lp, x, out), bufs
+
+
+def _cached_layers(cfg: LlamaConfig, params: Dict[str, Any],
+                   toks: jax.Array, view, lora):
+    """Embed ``toks [B, T]`` and scan :func:`_cached_layer` over the
+    stacked layers -> (hidden states, the view's buffers after the
+    writes).  The view says how its buffers ride the scan
+    (``view.begin``): whole, as carry, beside the layer's index — the
+    decode kernels and every paged pool — or a layer at a time as xs."""
+    adp, aid = lora if lora is not None else (None, None)
+    x = _embed(cfg, params, toks)
+    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
+                                cfg.rope_theta)
+    held, rode = view.begin(toks.shape[1])
+
+    def body(carry, layer_in):
+        x, held = carry
+        lp, rode, lo = _layer_operands(layer_in, aid)
+        bufs, li = (held, rode) if view.stacked else (rode, None)
+        x, bufs = _cached_layer(cfg, lp, x, cos, sin, view, bufs, li, lo)
+        return ((x, bufs), ()) if view.stacked else ((x, ()), bufs)
+
+    (x, held), rode = jax.lax.scan(body, (x, held),
+                                   (params["layers"], adp, rode))
+    return x, (held if view.stacked else rode)
+
+
+def cached_forward(cfg: LlamaConfig, params: Dict[str, Any],
+                   toks: jax.Array, view, *, lora=None, head: bool = True
+                   ) -> Tuple[Optional[jax.Array], Dict[str, jax.Array]]:
+    """[B, T] new tokens, lane b's at ``view.pos[b] + j`` -> ([B, T,
+    vocab] logits, the cache ``T`` rows a lane further): the speculative
+    verify, the prefix cache's suffix insert, a chunked prefill's slice.
+    The cache comes as a view (:class:`ContiguousView`; infer/paged.py
+    ``paged_view``), chosen by whoever builds the program from the cache
+    it holds; under a serving mesh every einsum rides GSPMD off the
+    param and cache shardings.
+
+    ``lora``: ``(adp, aid)`` as in :func:`_forward`.  ``head=False``
+    skips the final norm + lm head and returns ``(None, cache)``: an
+    intermediate prefill slice only appends KV, and head logits over a
+    whole slice are the biggest tensor in the prefill path."""
+    x, bufs = _cached_layers(cfg, params, toks, view, lora)
+    new_cache = view.end(bufs, toks.shape[1])
+    if not head:
+        return None, new_cache
+    return _lm_head(cfg, params, x), new_cache
+
+
+def cached_step(cfg: LlamaConfig, params: Dict[str, Any], tok: jax.Array,
+                view, *, lora=None
+                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """The decode step, :func:`cached_forward` at one token a lane:
+    ``tok [B]`` at ``view.pos`` -> (logits [B, vocab], the cache one row
+    a lane further).  Where T is 1 a view may take the decode kernel
+    (ops/decode_attention.py) for its attention.  Written out rather
+    than ``cached_forward(...)[:, 0]``: the step takes its head before
+    the position's advance and the multi-token forward after, as the
+    programs pinned on ISSUE 30's parent have them
+    (tests/test_llama_ring_pinned.py)."""
+    x, bufs = _cached_layers(cfg, params, tok[:, None], view, lora)
+    logits = _lm_head(cfg, params, x)[:, 0]
+    return logits, view.end(bufs, 1)
 
 
 def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,
@@ -609,83 +887,6 @@ def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,
     logits, cache = _forward(cfg, params, tokens, cache, last_only=True,
                              mesh=mesh, whole_prompt=True)
     return logits[:, 0], cache
-
-
-def paged_prefill(params: Dict[str, Any], cfg: LlamaConfig,
-                  tokens: jax.Array, pool_cache: Dict[str, jax.Array],
-                  table_row: jax.Array, *, block_size: Optional[int] = None,
-                  mesh=None, quant: bool = False,
-                  prompt_len: Optional[jax.Array] = None, lora=None):
-    """Prefill a whole [1, bucket] prompt and write its KV into the
-    PAGED block pool (infer/paged.py) as block-aligned chunks at the
-    lane's ``table_row`` entries — the cold-admission half of paged
-    serving.  The forward itself is exactly :func:`prefill`'s (same
-    compiled ops — what keeps the paged ring's first token
-    bit-identical to the contiguous ring's — and the same choice of
-    attention by width, :func:`prefill_attn_impl`: the flash kernel
-    over the prompt's own q, k, v where it runs, else the einsum over
-    the lane cache); only the destination
-    changes: block ``j`` of the lane cache lands in pool block
-    ``table_row[j]``, pad blocks land wherever the table maps them
-    (the trash block when unmapped — exactness-with-padding,
-    block-granular).  Returns ([1, bucket, vocab] logits — the caller
-    samples at ``prompt_len - 1`` — and the pool cache with this
-    lane's position untouched (the caller's insert sets it).
-
-    ``quant=True`` (needs ``prompt_len``, traced): whole blocks
-    quantize ONCE on the way into the int8 pool
-    (ops/decode_attention.py scatter_prefill_blocks_quant), and the
-    prompt's partial last block is returned as exact bf16 tail tiles
-    ``(logits, cache', tail_k, tail_v)`` [L, 1, H, bs, D] for the
-    caller's insert to splice into the lane's staging tail — the one
-    block whose scale cannot be final yet."""
-    from paddle_operator_tpu.infer.paged import _scatter_prompt_blocks
-
-    bs = block_size or pool_cache["k"].shape[3]
-    lane = init_cache(cfg, 1, tokens.shape[1])
-    logits, lane = _forward(cfg, params, tokens, lane, mesh=mesh,
-                            lora=lora, whole_prompt=True)
-    if not quant:
-        k = _scatter_prompt_blocks(pool_cache["k"], lane["k"], table_row,
-                                   bs)
-        v = _scatter_prompt_blocks(pool_cache["v"], lane["v"], table_row,
-                                   bs)
-        return logits, {"k": k, "v": v, "pos": pool_cache["pos"]}
-    from paddle_operator_tpu.ops.decode_attention import (
-        scatter_prefill_blocks_quant,
-    )
-
-    if prompt_len is None:
-        raise ValueError("quant paged_prefill needs prompt_len for the "
-                         "staging-tail slice")
-    k, ks = scatter_prefill_blocks_quant(
-        pool_cache["k"], pool_cache["ks"], lane["k"], table_row, bs)
-    v, vs = scatter_prefill_blocks_quant(
-        pool_cache["v"], pool_cache["vs"], lane["v"], table_row, bs)
-    # the write-frontier block's exact rows: [start, start + bs) of the
-    # lane cache.  The lane alloc need not be a block multiple, and
-    # dynamic_slice CLAMPS an out-of-range start backwards — which
-    # would hand back rows of the PREVIOUS block at the wrong tail
-    # offsets (positions start+o would attend K/V of start-pad+o) —
-    # so pad the time axis up to a block multiple first.  The one
-    # remaining clamp (block-aligned prompt filling the whole padded
-    # alloc, start == padded len) is harmless: decode then begins a
-    # FRESH block and every stale tail row sits behind the fill mask.
-    L, _, h, t_alloc, dd = lane["k"].shape
-    pad = -t_alloc % bs
-    lane_k, lane_v = lane["k"], lane["v"]
-    if pad:
-        widths = ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0))
-        lane_k = jnp.pad(lane_k, widths)
-        lane_v = jnp.pad(lane_v, widths)
-    start = (prompt_len // bs) * bs
-    tail_k = jax.lax.dynamic_slice(lane_k, (0, 0, 0, start, 0),
-                                   (L, 1, h, bs, dd))
-    tail_v = jax.lax.dynamic_slice(lane_v, (0, 0, 0, start, 0),
-                                   (L, 1, h, bs, dd))
-    cache = {"k": k, "v": v, "ks": ks, "vs": vs, "kt": pool_cache["kt"],
-             "vt": pool_cache["vt"], "pos": pool_cache["pos"]}
-    return logits, cache, tail_k, tail_v
 
 
 def decode_step(params: Dict[str, Any], cfg: LlamaConfig,
@@ -736,6 +937,68 @@ def _filter_logits(logits: jax.Array, top_k: Optional[int],
     return logits
 
 
+@jax.named_scope("sample")
+def _sample_tokens(logits, temp, keys, pos, top_k, top_p):
+    """THE per-lane sampling rule — shared by the chunk step and EVERY
+    admission insert (inline, chunked final, suffix, disagg) so token 1
+    and tokens 2..N can never be drawn under different rules.  logits
+    [B, V], temp [B], keys [B, 2], pos [B] -> [B] int32: greedy at temp
+    0, else per-lane fold_in(position) (deterministic given (seed,
+    pos), independent across lanes and steps) feeding temperature +
+    top-k/top-p filtered categorical sampling."""
+    greedy = logits.argmax(-1).astype(jnp.int32)
+    filt = _filter_logits(
+        logits / jnp.maximum(temp, 1e-6)[:, None], top_k, top_p)
+    sub = jax.vmap(jax.random.fold_in)(keys, pos)
+    drawn = jax.vmap(
+        lambda k, l: jax.random.categorical(k, l))(sub, filt)
+    return jnp.where(temp > 0, drawn.astype(jnp.int32), greedy)
+
+
+def _mega_advance(toks, raw, live, left, eos):
+    """On-device continuation bookkeeping at one fused-iteration
+    boundary of a megastep (ISSUE 11) — the EXACT decision the host
+    makes between two 1-step dispatches, in compiled form so N ring
+    iterations can run without a host round-trip.
+
+    ``toks`` [T, B] is the boundary's emitted tokens (a chunk's ticks,
+    or a spec round's committed block), ``raw`` [B] the device-valid
+    row count per lane (``chunk`` for plain chunks, ``n_commit`` for
+    spec rounds, 0 for lanes that sat the iteration out), ``live`` [B]
+    the continuation mask at the iteration's START, ``left`` [B] the
+    per-lane remaining token budget and ``eos`` [B] the per-lane eos id
+    (-1: none).  Returns ``(count, live', left')``: the tokens the host
+    will actually consume for this boundary (up to and INCLUDING an
+    eos, capped by the budget — the same walk scheduler._consume runs),
+    and the advanced continuation state.  A lane that saw eos or
+    exhausted its budget goes dead and free-runs masked until the
+    megastep ends."""
+    t = toks.shape[0]
+    idx = jnp.arange(t)[:, None]
+    hitv = (eos[None, :] >= 0) & (toks == eos[None, :])
+    hit = hitv.astype(jnp.int32)
+    eos_before = (jnp.cumsum(hit, axis=0) - hit) > 0
+    valid = ((idx < raw[None, :]) & ~eos_before
+             & (idx < left[None, :]) & live[None, :])
+    count = valid.sum(axis=0).astype(jnp.int32)
+    saw_eos = (hitv & valid).any(axis=0)
+    left2 = left - count
+    live2 = live & ~saw_eos & (left2 > 0)
+    return count, live2, left2
+
+
+def _mega_continue(toks, raw, live, left, steps, eos):
+    """The WHOLE per-boundary continuation update, shared by every
+    megastep builder (contiguous, paged, spec) so the token-budget walk
+    and the step-budget decrement can never drift between them:
+    :func:`_mega_advance` plus the deadline-tick step accounting.
+    Returns ``(count, live', left', steps')``."""
+    count, live2, left2 = _mega_advance(toks, raw, live, left, eos)
+    steps2 = steps - live.astype(jnp.int32)
+    live2 = live2 & (steps2 > 0)
+    return count, live2, left2, steps2
+
+
 def generate(params: Dict[str, Any], cfg: LlamaConfig, prompt: jax.Array,
              *, max_new_tokens: int, temperature: float = 0.0,
              top_k: Optional[int] = None, top_p: Optional[float] = None,
@@ -784,19 +1047,3 @@ def generate(params: Dict[str, Any], cfg: LlamaConfig, prompt: jax.Array,
             else jnp.zeros((max_new_tokens, 2), jnp.uint32))
     (_, _, _), toks = jax.lax.scan(step, (logits, cache, done0), keys)
     return jnp.concatenate([prompt, toks.T], axis=1)
-
-
-def speculative_generate(params, draft_params, cfg: LlamaConfig,
-                         draft_cfg: LlamaConfig, prompt: jax.Array, **kw):
-    """Draft-propose + chunked-verify counterpart of :func:`generate`:
-    a small draft model (``LlamaConfig.draft()``) proposes ``spec_k``
-    tokens per round and the target verifies all of them in one
-    multi-token forward — token-identical to :func:`generate` at
-    temperature 0, distribution-preserving (rejection sampling) above.
-    Implementation and the full contract live in infer/speculative.py;
-    this re-export keeps the serving entrypoints in one module."""
-    from paddle_operator_tpu.infer.speculative import (
-        speculative_generate as _impl,
-    )
-
-    return _impl(params, draft_params, cfg, draft_cfg, prompt, **kw)

@@ -1,6 +1,6 @@
 """Device half of the serving ring: compiled programs + ring state.
 
-ISSUE 6 split the ~1.6k-line ``infer/batcher.py`` into a **scheduler**
+ISSUE 6 split the ~1.6k-line one-file ring into a **scheduler**
 (infer/scheduler.py — admission, queues, deadlines, request lifecycle,
 resilience hooks; pure host code) and this **executor** (compiled
 dispatch, ring/paged caches, prefill and decode step functions; every
@@ -32,8 +32,8 @@ serve.py env ``SERVE_PREFILL``):
 
 All three are greedy-bit-identical to the inline ring: every prefill
 path runs the same compiled op sequences (``decode._forward`` /
-``speculative._multi_forward(_paged)``) and samples the first token
-through the shared ``_sample_tokens`` rule — pinned by
+``decode.cached_forward`` over the lane's cache view) and samples the
+first token through the shared ``_sample_tokens`` rule — pinned by
 tests/test_prefill_modes.py and the dryrun ``serve-disagg`` line.
 """
 
@@ -50,7 +50,14 @@ import jax
 import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
-from paddle_operator_tpu.models.llama import LlamaConfig, rope_frequencies
+from paddle_operator_tpu.infer import paged as PG
+from paddle_operator_tpu.infer.decode import (
+    _mega_continue,
+    _sample_tokens,
+    _splice_lane,
+    init_ring_cache,
+)
+from paddle_operator_tpu.models.llama import LlamaConfig
 
 
 class ExecPlan:
@@ -117,221 +124,9 @@ class DispatchResult:
 
 
 # ---------------------------------------------------------------------------
-# Per-lane-position forward step (moved verbatim from infer/batcher.py)
+# The contiguous ring's programs (its cache, the one cached forward and
+# the sampling rule live below, in infer/decode.py)
 # ---------------------------------------------------------------------------
-
-
-def init_ring_cache(cfg: LlamaConfig, slots: int,
-                    max_len: int, mesh=None) -> Dict[str, jax.Array]:
-    """KV ring: like decode.init_cache (same head-major layout,
-    block-aligned allocation, same kv-head tp sharding under a serving
-    mesh) but with a per-lane fill position vector instead of one
-    scalar."""
-    if max_len > cfg.max_seq_len:
-        raise ValueError(f"max_len {max_len} exceeds the RoPE table "
-                         f"(cfg.max_seq_len={cfg.max_seq_len})")
-    alloc = D.cache_alloc_len(max_len)
-    shape = (cfg.n_layers, slots, cfg.n_kv_heads, alloc, cfg.head_dim)
-    return {
-        "k": D.alloc_kv_buffer(cfg, shape, mesh),
-        "v": D.alloc_kv_buffer(cfg, shape, mesh),
-        "pos": jnp.zeros((slots,), jnp.int32),
-    }
-
-
-@jax.named_scope("cache_write")
-def _write_lane(cache_l: jax.Array, kv: jax.Array,
-                pos: jax.Array) -> jax.Array:
-    """[B, H, S, D] cache layer <- [B, H, 1, D] new row at per-lane pos."""
-    return jax.vmap(
-        lambda c, x, p: jax.lax.dynamic_update_slice(c, x, (0, p, 0))
-    )(cache_l, kv, pos)
-
-
-def _qkv_ring(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
-              cos: jax.Array, sin: jax.Array, pos: jax.Array,
-              lora=None):
-    """Pre-attention half for ONE new token per lane at per-lane
-    positions ``pos`` [B]: RMSNorm -> projections -> RoPE at each
-    lane's own position (the table slice is a plain gather cos[pos]).
-
-    ``lora`` (ISSUE 10): ``(adp_l, aid)`` — one layer's stacked LoRA
-    arrays + the per-LANE adapter id vector; the batched gather +
-    delta matmul (qos.lora_qkv) runs inside the same compiled step, so
-    a mixed-adapter batch is still ONE dispatch."""
-    h = D._rms(x, lp["attn_norm"]["scale"], cfg.norm_eps, cfg.dtype)
-    q, k, v = D._qkv_proj(cfg, lp, h, 1, lora)
-    with jax.named_scope("attn.rope"):
-        cos_b = cos[pos][:, None, None, :]          # [B, 1, 1, d/2]
-        sin_b = sin[pos][:, None, None, :]
-
-        def rot(t):
-            t1, t2 = jnp.split(t.astype(jnp.float32), 2, axis=-1)
-            return jnp.concatenate(
-                [t1 * cos_b - t2 * sin_b, t2 * cos_b + t1 * sin_b],
-                axis=-1).astype(t.dtype)
-
-        return rot(q), rot(k), v
-
-
-def _layer_step(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
-                cos: jax.Array, sin: jax.Array, k_cache: jax.Array,
-                v_cache: jax.Array, pos: jax.Array, lora=None
-                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One decoder layer for ONE new token per lane ([B, 1, D] at lane
-    positions ``pos`` [B]) with the XLA einsum attention.  Same math as
-    decode._layer (which this is pinned against) with the scalar
-    position generalized to a vector.  The pallas path keeps the caches
-    stacked and does not go through here (see _ring_forward)."""
-    b = x.shape[0]
-    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q, k, v = _qkv_ring(cfg, lp, x, cos, sin, pos, lora=lora)
-    k_cache = _write_lane(k_cache, k.transpose(0, 2, 1, 3), pos)
-    v_cache = _write_lane(v_cache, v.transpose(0, 2, 1, 3), pos)
-
-    with jax.named_scope("attn.kernel"):
-        n_rep = hq // hkv
-        max_len = k_cache.shape[2]
-        qg = q.reshape(b, 1, hkv, n_rep, d)
-        scores = jnp.einsum("bthrd,bhsd->bthrs", qg, k_cache,
-                            preferred_element_type=jnp.float32) / jnp.sqrt(
-            jnp.float32(d))
-        # lane b may attend cache cols [0, pos_b] (its own new row incl.)
-        mask = jnp.arange(max_len)[None, :] <= pos[:, None]      # [B, S]
-        scores = jnp.where(mask[:, None, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bthrs,bhsd->bthrd", probs.astype(cfg.dtype),
-                         v_cache, preferred_element_type=jnp.float32)
-        out = out.reshape(b, 1, hq * d).astype(cfg.dtype)
-    return D._finish_layer(cfg, lp, x, out), k_cache, v_cache
-
-
-@jax.named_scope("cache_write")
-def _write_lane_stacked(stack: jax.Array, kv: jax.Array, li: jax.Array,
-                        pos: jax.Array) -> jax.Array:
-    """[L, B, H, S, D] stacked cache <- [B, H, 1, D] new rows at layer
-    ``li`` and per-lane positions ``pos``.
-
-    One dynamic_update_slice PER LANE (a static unroll over the slot
-    count), not a vmapped/batched update: vmapping over ragged lane
-    positions lowers to a scatter, and a scatter into the scan-carried
-    stack makes XLA materialize a copy of the whole ring cache per
-    layer per tick — measured 30x slower than raw decode.  Chained
-    single-row dus ops update the carry in place."""
-    b = kv.shape[0]
-    for lane in range(b):
-        stack = jax.lax.dynamic_update_slice(
-            stack, kv[lane][None, None], (li, lane, 0, pos[lane], 0))
-    return stack
-
-
-def _ring_forward(cfg: LlamaConfig, params: Dict[str, Any],
-                  tok: jax.Array, cache: Dict[str, jax.Array],
-                  mesh=None, lora=None
-                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """tok [B] at per-lane cache['pos'] -> (logits [B, V], advanced
-    cache).  Counterpart of decode._forward for vector positions; like
-    it, the pallas path carries the caches STACKED through the layer
-    scan so the kernel reads them copy-free (decode.py _forward has the
-    why), and under a serving mesh the kernel + output projection run
-    TP-sharded in one manual region per layer (the ragged per-lane
-    ``pos`` vector is exactly the ``lengths`` operand the kernel's
-    index map already takes — replicated across shards)."""
-    pos = cache["pos"]
-    adp, aid = lora if lora is not None else (None, None)
-    x = D._embed(cfg, params, tok[:, None])
-    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
-                                cfg.rope_theta)
-
-    attn_impl, use_sharded = D.resolve_decode_attn(cfg, mesh)
-    stacked_xs = ((params["layers"], adp, jnp.arange(cfg.n_layers))
-                  if adp is not None
-                  else (params["layers"], jnp.arange(cfg.n_layers)))
-
-    def _unpack(layer_in):
-        if adp is not None:
-            lp, adp_l, li = layer_in
-            return lp, li, (adp_l, aid)
-        lp, li = layer_in
-        return lp, li, None
-
-    if use_sharded:
-        from paddle_operator_tpu.ops.decode_attention import (
-            sharded_decode_attention,
-        )
-
-        def body(carry, layer_in):
-            x, kc, vc = carry
-            lp, li, lo = _unpack(layer_in)
-            q, k, v = _qkv_ring(cfg, lp, x, cos, sin, pos, lora=lo)
-            kc = _write_lane_stacked(kc, k.transpose(0, 2, 1, 3), li, pos)
-            vc = _write_lane_stacked(vc, v.transpose(0, 2, 1, 3), li, pos)
-            proj = sharded_decode_attention(
-                mesh, q[:, 0], kc, vc, pos + 1,
-                lp["attn"]["wo"]["kernel"], layer=li,
-                interpret=(attn_impl == "pallas-interpret"),
-                compute_dtype=cfg.dtype)
-            x = x + proj[:, None].astype(cfg.dtype)
-            return (D._ffn_residual(cfg, lp, x), kc, vc), ()
-
-        (x, k_new, v_new), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]), stacked_xs)
-    elif attn_impl != "xla":
-        from paddle_operator_tpu.ops.decode_attention import decode_attention
-
-        b = x.shape[0]
-        hq, d = cfg.n_heads, cfg.head_dim
-
-        def body(carry, layer_in):
-            x, kc, vc = carry
-            lp, li, lo = _unpack(layer_in)
-            q, k, v = _qkv_ring(cfg, lp, x, cos, sin, pos, lora=lo)
-            kc = _write_lane_stacked(kc, k.transpose(0, 2, 1, 3), li, pos)
-            vc = _write_lane_stacked(vc, v.transpose(0, 2, 1, 3), li, pos)
-            out = decode_attention(
-                q[:, 0], kc, vc, pos + 1, layer=li,
-                interpret=(attn_impl == "pallas-interpret"))
-            out = out.reshape(b, 1, hq * d).astype(cfg.dtype)
-            return (D._finish_layer(cfg, lp, x, out), kc, vc), ()
-
-        (x, k_new, v_new), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]), stacked_xs)
-    else:
-        def body(x, layer_in):
-            if adp is not None:
-                lp, adp_l, k_c, v_c = layer_in
-                lo = (adp_l, aid)
-            else:
-                lp, k_c, v_c = layer_in
-                lo = None
-            y, k_c, v_c = _layer_step(cfg, lp, x, cos, sin, k_c, v_c,
-                                      pos, lora=lo)
-            return y, (k_c, v_c)
-
-        xs = ((params["layers"], adp, cache["k"], cache["v"])
-              if adp is not None
-              else (params["layers"], cache["k"], cache["v"]))
-        x, (k_new, v_new) = jax.lax.scan(body, x, xs)
-    return (D._lm_head(cfg, params, x)[:, 0],
-            {"k": k_new, "v": v_new, "pos": pos + 1})
-
-
-@jax.named_scope("sample")
-def _sample_tokens(logits, temp, keys, pos, top_k, top_p):
-    """THE per-lane sampling rule — shared by the chunk step and EVERY
-    admission insert (inline, chunked final, suffix, disagg) so token 1
-    and tokens 2..N can never be drawn under different rules.  logits
-    [B, V], temp [B], keys [B, 2], pos [B] -> [B] int32: greedy at temp
-    0, else per-lane fold_in(position) (deterministic given (seed,
-    pos), independent across lanes and steps) feeding temperature +
-    top-k/top-p filtered categorical sampling."""
-    greedy = logits.argmax(-1).astype(jnp.int32)
-    filt = D._filter_logits(
-        logits / jnp.maximum(temp, 1e-6)[:, None], top_k, top_p)
-    sub = jax.vmap(jax.random.fold_in)(keys, pos)
-    drawn = jax.vmap(
-        lambda k, l: jax.random.categorical(k, l))(sub, filt)
-    return jnp.where(temp > 0, drawn.astype(jnp.int32), greedy)
 
 
 def make_chunk_step(cfg: LlamaConfig, chunk_tokens: int,
@@ -372,8 +167,9 @@ def make_chunk_step(cfg: LlamaConfig, chunk_tokens: int,
                 cache, tok, ok = carry
             else:
                 cache, tok = carry
-            logits, new_cache = _ring_forward(cfg, params, tok, cache,
-                                              mesh=mesh, lora=lora)
+            logits, new_cache = D.cached_step(
+                cfg, params, tok, D.ContiguousView(cfg, cache, mesh),
+                lora=lora)
             nxt = _sample_tokens(logits, temp, keys, cache["pos"],
                                  top_k, top_p)
             # retired/free lanes: position ZEROED (a stale fill
@@ -398,50 +194,6 @@ def make_chunk_step(cfg: LlamaConfig, chunk_tokens: int,
         return cache, tok, toks
 
     return jax.jit(step, donate_argnums=(1,))
-
-
-def _mega_advance(toks, raw, live, left, eos):
-    """On-device continuation bookkeeping at one fused-iteration
-    boundary of a megastep (ISSUE 11) — the EXACT decision the host
-    makes between two 1-step dispatches, in compiled form so N ring
-    iterations can run without a host round-trip.
-
-    ``toks`` [T, B] is the boundary's emitted tokens (a chunk's ticks,
-    or a spec round's committed block), ``raw`` [B] the device-valid
-    row count per lane (``chunk`` for plain chunks, ``n_commit`` for
-    spec rounds, 0 for lanes that sat the iteration out), ``live`` [B]
-    the continuation mask at the iteration's START, ``left`` [B] the
-    per-lane remaining token budget and ``eos`` [B] the per-lane eos id
-    (-1: none).  Returns ``(count, live', left')``: the tokens the host
-    will actually consume for this boundary (up to and INCLUDING an
-    eos, capped by the budget — the same walk scheduler._consume runs),
-    and the advanced continuation state.  A lane that saw eos or
-    exhausted its budget goes dead and free-runs masked until the
-    megastep ends."""
-    t = toks.shape[0]
-    idx = jnp.arange(t)[:, None]
-    hitv = (eos[None, :] >= 0) & (toks == eos[None, :])
-    hit = hitv.astype(jnp.int32)
-    eos_before = (jnp.cumsum(hit, axis=0) - hit) > 0
-    valid = ((idx < raw[None, :]) & ~eos_before
-             & (idx < left[None, :]) & live[None, :])
-    count = valid.sum(axis=0).astype(jnp.int32)
-    saw_eos = (hitv & valid).any(axis=0)
-    left2 = left - count
-    live2 = live & ~saw_eos & (left2 > 0)
-    return count, live2, left2
-
-
-def _mega_continue(toks, raw, live, left, steps, eos):
-    """The WHOLE per-boundary continuation update, shared by every
-    megastep builder (contiguous, paged, spec) so the token-budget walk
-    and the step-budget decrement can never drift between them:
-    :func:`_mega_advance` plus the deadline-tick step accounting.
-    Returns ``(count, live', left', steps')``."""
-    count, live2, left2 = _mega_advance(toks, raw, live, left, eos)
-    steps2 = steps - live.astype(jnp.int32)
-    live2 = live2 & (steps2 > 0)
-    return count, live2, left2, steps2
 
 
 def make_megastep(cfg: LlamaConfig, chunk_tokens: int, n_steps: int,
@@ -487,9 +239,9 @@ def make_megastep(cfg: LlamaConfig, chunk_tokens: int, n_steps: int,
                     cache, tok, ok = c
                 else:
                     cache, tok = c
-                logits, new_cache = _ring_forward(cfg, params, tok,
-                                                  cache, mesh=mesh,
-                                                  lora=lora)
+                logits, new_cache = D.cached_step(
+                    cfg, params, tok, D.ContiguousView(cfg, cache, mesh),
+                    lora=lora)
                 nxt = _sample_tokens(logits, temp, keys, cache["pos"],
                                      top_k, top_p)
                 new_cache["pos"] = jnp.where(live, new_cache["pos"], 0)
@@ -529,32 +281,6 @@ def make_megastep(cfg: LlamaConfig, chunk_tokens: int, n_steps: int,
         return cache, tok, toks, counts
 
     return jax.jit(mega, donate_argnums=(1,))
-
-
-def _splice_lane(ring: Dict[str, jax.Array], lane: Dict[str, jax.Array],
-                 slot, prompt_len) -> Dict[str, jax.Array]:
-    """Zero ring lane ``slot`` and splice a freshly prefilled
-    batch-of-one lane cache into it, setting the lane's fill position
-    to ``prompt_len`` — the device half of admission, shared by the
-    plain, speculative and chunked-final inserts so their splice
-    semantics cannot drift.  A lane cache LONGER than the ring lane
-    (a chunk-width-padded staging cache) is truncated: rows past the
-    ring allocation are pads by construction."""
-    ring_alloc = ring["k"].shape[3]
-    lane_k, lane_v = lane["k"], lane["v"]
-    if lane_k.shape[3] > ring_alloc:
-        lane_k = lane_k[:, :, :, :ring_alloc]
-        lane_v = lane_v[:, :, :, :ring_alloc]
-    k = jnp.zeros_like(ring["k"][:, 0])
-    k = jax.lax.dynamic_update_slice(k, lane_k[:, 0], (0, 0, 0, 0))
-    v = jnp.zeros_like(ring["v"][:, 0])
-    v = jax.lax.dynamic_update_slice(v, lane_v[:, 0], (0, 0, 0, 0))
-    new_k = jax.lax.dynamic_update_slice(
-        ring["k"], k[:, None], (0, slot, 0, 0, 0))
-    new_v = jax.lax.dynamic_update_slice(
-        ring["v"], v[:, None], (0, slot, 0, 0, 0))
-    return {"k": new_k, "v": new_v,
-            "pos": ring["pos"].at[slot].set(prompt_len)}
 
 
 def make_prefill_insert(cfg: LlamaConfig, bucket: int,
@@ -666,14 +392,12 @@ def make_prefill_chunk(cfg: LlamaConfig, slice_bucket: int,
     ``chunk(params, lane_k, lane_v, toks [1, slice_bucket], start)
     -> (lane_k', lane_v')``
     """
-    from paddle_operator_tpu.infer.speculative import _multi_forward
-
     def chunk(params, lane_k, lane_v, toks, start, *lora_args):
         cache = {"k": lane_k, "v": lane_v,
                  "pos": jnp.reshape(start, (1,)).astype(jnp.int32)}
-        _, new = _multi_forward(
-            cfg, params, toks, cache, mesh=mesh, head=False,
-            lora=tuple(lora_args) if lora_args else None)
+        _, new = D.cached_forward(
+            cfg, params, toks, D.ContiguousView(cfg, cache, mesh),
+            head=False, lora=tuple(lora_args) if lora_args else None)
         return new["k"], new["v"]
 
     return jax.jit(chunk, donate_argnums=(1, 2))
@@ -693,15 +417,13 @@ def make_chunked_final_insert(cfg: LlamaConfig, slice_bucket: int,
     toks [1, slice_bucket], n_rows, start, prompt_len, slot, temp_val,
     seed) -> (cache', tok', temp', keys', first_token)``
     """
-    from paddle_operator_tpu.infer.speculative import _multi_forward
-
     def insert(params, cache, lane_k, lane_v, tok, temp, keys, toks,
                n_rows, start, prompt_len, slot, temp_val, seed,
                *lora_args):
         stage = {"k": lane_k, "v": lane_v,
                  "pos": jnp.reshape(start, (1,)).astype(jnp.int32)}
-        logits, new_lane = _multi_forward(
-            cfg, params, toks, stage, mesh=mesh,
+        logits, new_lane = D.cached_forward(
+            cfg, params, toks, D.ContiguousView(cfg, stage, mesh),
             lora=tuple(lora_args) if lora_args else None)
         logits = logits[0, n_rows - 1]
         new_cache = _splice_lane(cache, new_lane, slot, prompt_len)
@@ -738,15 +460,13 @@ def make_spec_chunked_final_insert(cfg: LlamaConfig, dcfg: LlamaConfig,
     keys, toks, n_rows, start, prompt [1, bucket], prompt_len, slot,
     temp_val, seed) -> (cache', dcache', tok', temp', keys', first)``
     """
-    from paddle_operator_tpu.infer.speculative import _multi_forward
-
     def insert(params, dparams, cache, dcache, lane_k, lane_v, tok, temp,
                keys, toks, n_rows, start, prompt, prompt_len, slot,
                temp_val, seed):
         stage = {"k": lane_k, "v": lane_v,
                  "pos": jnp.reshape(start, (1,)).astype(jnp.int32)}
-        logits, new_lane = _multi_forward(cfg, params, toks, stage,
-                                          mesh=mesh)
+        logits, new_lane = D.cached_forward(
+            cfg, params, toks, D.ContiguousView(cfg, stage, mesh))
         logits = logits[0, n_rows - 1]
         new_cache = _splice_lane(cache, new_lane, slot, prompt_len)
         dlane = D.init_cache(dcfg, 1, bucket)
@@ -847,7 +567,7 @@ def make_disagg_prefill(cfg: LlamaConfig, bucket: int, block_size: int,
                 seed, *lora_args):
         lora = tuple(lora_args) if lora_args else None
         if quant:
-            logits, new_cache, tail_k, tail_v = D.paged_prefill(
+            logits, new_cache, tail_k, tail_v = PG.paged_prefill(
                 params, cfg, prompt, cache, table_row,
                 block_size=block_size, mesh=mesh, quant=True,
                 prompt_len=prompt_len, lora=lora)
@@ -856,10 +576,10 @@ def make_disagg_prefill(cfg: LlamaConfig, bucket: int, block_size: int,
             new_cache["vt"] = jax.lax.dynamic_update_slice(
                 new_cache["vt"], tail_v, (0, 0, 0, 0, 0))
         else:
-            logits, new_cache = D.paged_prefill(params, cfg, prompt,
-                                                cache, table_row,
-                                                block_size=block_size,
-                                                mesh=mesh, lora=lora)
+            logits, new_cache = PG.paged_prefill(params, cfg, prompt,
+                                                 cache, table_row,
+                                                 block_size=block_size,
+                                                 mesh=mesh, lora=lora)
         logits = logits[0, prompt_len - 1]
         key = jax.random.PRNGKey(seed)
         first = _sample_tokens(
@@ -901,20 +621,17 @@ def make_pool_prefill_slice(cfg: LlamaConfig, mesh=None,
     O(lanes x blocks), not O(lanes x rows): at production slice widths
     the per-row unroll is pathological to COMPILE.  The quant tail
     protocol is inherently per-row and keeps the row path."""
-    from paddle_operator_tpu.infer.speculative import _multi_forward_paged
-
     def slice_(params, cache, tables, toks, starts, limits, mask,
                *lora_args):
         lane_cache = {"k": cache["k"], "v": cache["v"], "pos": starts}
         if quant:
             lane_cache["ks"], lane_cache["vs"] = cache["ks"], cache["vs"]
             lane_cache["kt"], lane_cache["vt"] = cache["kt"], cache["vt"]
-        _, new = _multi_forward_paged(
-            cfg, params, toks, lane_cache, tables, limit=limits,
-            mesh=mesh, head=False, quant=quant,
-            lane_mask=(mask if quant else None),
-            lora=tuple(lora_args) if lora_args else None,
-            aligned=not quant)
+        _, new = D.cached_forward(
+            cfg, params, toks,
+            PG.paged_view(cfg, lane_cache, tables, limit=limits,
+                          lane_mask=mask, aligned=True, mesh=mesh),
+            head=False, lora=tuple(lora_args) if lora_args else None)
         out = {"k": new["k"], "v": new["v"], "pos": cache["pos"]}
         if quant:
             out["ks"], out["vs"] = new["ks"], new["vs"]
@@ -948,20 +665,17 @@ def make_pool_prefill_final(cfg: LlamaConfig,
     exactness-with-padding contract: masked in-slice, overwritten by
     decode before any read, and the prefix cache stores only full
     blocks strictly inside the prompt)."""
-    from paddle_operator_tpu.infer.speculative import _multi_forward_paged
-
     def final(params, cache, tables, toks, n_rows, starts, temps,
               seeds, limits, mask, *lora_args):
         lane_cache = {"k": cache["k"], "v": cache["v"], "pos": starts}
         if quant:
             lane_cache["ks"], lane_cache["vs"] = cache["ks"], cache["vs"]
             lane_cache["kt"], lane_cache["vt"] = cache["kt"], cache["vt"]
-        logits, new = _multi_forward_paged(
-            cfg, params, toks, lane_cache, tables, limit=limits,
-            mesh=mesh, quant=quant,
-            lane_mask=(mask if quant else None),
-            lora=tuple(lora_args) if lora_args else None,
-            aligned=not quant)
+        logits, new = D.cached_forward(
+            cfg, params, toks,
+            PG.paged_view(cfg, lane_cache, tables, limit=limits,
+                          lane_mask=mask, aligned=True, mesh=mesh),
+            lora=tuple(lora_args) if lora_args else None)
         out = {"k": new["k"], "v": new["v"], "pos": cache["pos"]}
         if quant:
             out["ks"], out["vs"] = new["ks"], new["vs"]
@@ -1122,8 +836,6 @@ class PrefillExecutor:
                  lanes: int = 1, prefill_chunk: int = 64,
                  stream: bool = False,
                  prefix_blocks: int = 0) -> None:
-        from paddle_operator_tpu.infer import paged as PG
-
         # adapter registry shared with the decode ring (ISSUE 10): a
         # cold adapter prompt must prefill WITH its delta — the KV the
         # handoff copies is the adapter's, not the base model's
@@ -1339,8 +1051,6 @@ class PrefillExecutor:
         lcount, _, h, _, d = p0["k"].shape
         slab_k = np.zeros((lcount, 1, h, pad * bs, d), p0["k"].dtype)
         slab_v = np.zeros_like(slab_k)
-        from paddle_operator_tpu.infer import paged as PG
-
         ids = np.full((pad,), PG.TRASH_BLOCK, np.int32)
         for j, payload in enumerate(payloads):
             ids[j] = self.tables[lane][j]
@@ -1447,8 +1157,6 @@ class PrefillExecutor:
                if j.n - j.start <= sb]
         self._note_occ(len(active))
         tail = self._lora_tail(active)
-        from paddle_operator_tpu.infer import paged as PG
-
         if inter:
             mw = self._width(max(active[ln].start + sb
                                  for ln in inter))
@@ -1694,11 +1402,9 @@ class RingExecutor:
         # pool, dequant fused into the kernels — ~2x resident lanes per
         # HBM byte; "none" (default) keeps the bf16 pool bit-identical
         # to pre-quantization behavior (infer/paged.py module note)
-        from paddle_operator_tpu.infer import paged as _PGQ
-
-        if kv_quant not in _PGQ.KV_QUANT_MODES:
+        if kv_quant not in PG.KV_QUANT_MODES:
             raise ValueError(f"kv_quant {kv_quant!r} not in "
-                             f"{_PGQ.KV_QUANT_MODES}")
+                             f"{PG.KV_QUANT_MODES}")
         self.kv_quant = kv_quant
         self.quant = kv_quant == "int8"
         # an architecture other than LLaMA's serves on the paged ring's
@@ -1719,8 +1425,6 @@ class RingExecutor:
                              "(the pool block is the quantization "
                              "unit); set paged=True / SERVE_PAGED=1")
         if self.paged:
-            from paddle_operator_tpu.infer import paged as PG
-
             self._pg = PG
             self.block_size = int(block_size)
             self._num_blocks = num_blocks

@@ -1,7 +1,7 @@
 """Paged KV cache + radix prefix reuse for the serving ring.
 
-The continuous-batching ring (infer/batcher.py) allocates one
-contiguous ``[L, slots, H_kv, max_len, D]`` KV region per lane and
+The contiguous ring (infer/decode.py ``init_ring_cache``) allocates
+one contiguous ``[L, slots, H_kv, max_len, D]`` KV region per lane and
 re-prefills every prompt from scratch: every resident lane pays
 worst-case ``max_len`` HBM whether it holds 40 tokens or 2000, and a
 fleet of requests sharing a 2k system prompt pays the same prefill over
@@ -76,7 +76,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
-from paddle_operator_tpu.models.llama import LlamaConfig, rope_frequencies
+from paddle_operator_tpu.models.llama import LlamaConfig
 from paddle_operator_tpu.utils.radixkey import chain_key as _radix_chain_key
 
 TRASH_BLOCK = 0
@@ -1122,7 +1122,7 @@ def _write_token_paged(pool: jax.Array, kv: jax.Array, li: jax.Array,
                        block_size: int) -> jax.Array:
     """[L, N, H, bs, D] pool <- [B, H, 1, D] new rows, lane b's row at
     pool block ``table[b, pos_b // bs]`` offset ``pos_b % bs``.  Static
-    unroll over lanes for the same reason as batcher._write_lane_stacked
+    unroll over lanes for the same reason as decode._write_lane_stacked
     (a vmapped ragged update lowers to a carry-copying scatter)."""
     for lane in range(kv.shape[0]):
         blk = table[lane, pos[lane] // block_size]
@@ -1273,238 +1273,265 @@ def _gather_lane_view(pool: jax.Array, table: jax.Array,
     return v.reshape(b, h, m * bs, d)
 
 
-@jax.named_scope("attn.kernel")
-def _attend_einsum(cfg: LlamaConfig, q: jax.Array, k_view: jax.Array,
-                   v_view: jax.Array, pos: jax.Array) -> jax.Array:
-    """batcher._layer_step's attention block, lifted so the paged
-    forward runs the IDENTICAL einsum/mask/softmax op sequence over the
-    gathered view — columns [0, pos_b] hold the same values as the
-    contiguous ring, masked tail columns contribute exact zeros, so
-    greedy streams stay bit-identical to the oracle."""
-    b = q.shape[0]
-    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    n_rep = hq // hkv
-    s = k_view.shape[2]
-    qg = q.reshape(b, 1, hkv, n_rep, d)
-    scores = jnp.einsum("bthrd,bhsd->bthrs", qg, k_view,
-                        preferred_element_type=jnp.float32) / jnp.sqrt(
-        jnp.float32(d))
-    mask = jnp.arange(s)[None, :] <= pos[:, None]        # [B, S]
-    scores = jnp.where(mask[:, None, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bthrs,bhsd->bthrd", probs.astype(cfg.dtype),
-                     v_view, preferred_element_type=jnp.float32)
-    return out.reshape(b, 1, hq * d).astype(cfg.dtype)
+@jax.named_scope("cache_write")
+def _write_rows_quant(kc: jax.Array, vc: jax.Array, ks: jax.Array,
+                      vs: jax.Array, kt: jax.Array, vt: jax.Array,
+                      kh: jax.Array, vh: jax.Array, li: jax.Array,
+                      table: jax.Array, pos: jax.Array,
+                      limit: Optional[jax.Array],
+                      lane_mask: Optional[jax.Array]):
+    """Quantized-pool write of [B, H, T, D] new rows (``kh``/``vh``) at
+    per-lane start positions ``pos``: each row accumulates EXACT in the
+    lane's bf16 staging tail; a row completing its block quantizes the
+    whole tail block into the int8 pool — codes + one scale, computed
+    once from the full block (the reason the tail exists: per-token
+    requantization would re-derive the scale T times and perturb
+    already-written rows every step).  Rows that are pads (``p >=
+    limit``) or belong to masked lanes (``lane_mask``) redirect to the
+    TRASH tail row (index B) — a pad row writing the lane's real tail
+    would clobber live rows when the pad span wraps the block."""
+    b, hkv, t, d = kh.shape
+    bs = kc.shape[3]
+    trash_row = kt.shape[1] - 1
+    for lane in range(b):
+        for j in range(t):
+            p = pos[lane] + j
+            real = None
+            if limit is not None:
+                real = p < limit[lane]
+            if lane_mask is not None:
+                real = (lane_mask[lane] if real is None
+                        else real & lane_mask[lane])
+            row = (lane if real is None
+                   else jnp.where(real, lane, trash_row))
+            kt = jax.lax.dynamic_update_slice(
+                kt, kh[lane, :, j][None, None, :, None, :],
+                (li, row, 0, p % bs, 0))
+            vt = jax.lax.dynamic_update_slice(
+                vt, vh[lane, :, j][None, None, :, None, :],
+                (li, row, 0, p % bs, 0))
+            complete = (p + 1) % bs == 0
+            if real is not None:
+                complete = complete & real
+            dst = table[lane, p // bs]
+
+            # block-completion commit behind a cond: only the
+            # 1-in-bs completing row pays the two tile quantizes +
+            # pool writes (same rationale as _write_token_quant)
+            def _commit(st, row=row, dst=dst, kt=kt, vt=vt):
+                kc, vc, ks, vs = st
+                ktile = jax.lax.dynamic_slice(
+                    kt, (li, row, 0, 0, 0), (1, 1, hkv, bs, d))
+                kcodes, kscale = quantize_kv(ktile)
+                kc = jax.lax.dynamic_update_slice(kc, kcodes,
+                                                  (li, dst, 0, 0, 0))
+                ks = jax.lax.dynamic_update_slice(ks, kscale,
+                                                  (li, dst, 0))
+                vtile = jax.lax.dynamic_slice(
+                    vt, (li, row, 0, 0, 0), (1, 1, hkv, bs, d))
+                vcodes, vscale = quantize_kv(vtile)
+                vc = jax.lax.dynamic_update_slice(vc, vcodes,
+                                                  (li, dst, 0, 0, 0))
+                vs = jax.lax.dynamic_update_slice(vs, vscale,
+                                                  (li, dst, 0))
+                return kc, vc, ks, vs
+
+            kc, vc, ks, vs = jax.lax.cond(complete, _commit,
+                                          lambda st: st,
+                                          (kc, vc, ks, vs))
+    return kc, vc, ks, vs, kt, vt
 
 
-def paged_ring_forward(cfg: LlamaConfig, params: Dict[str, Any],
-                       tok: jax.Array, cache: Dict[str, jax.Array],
-                       table: jax.Array, mesh=None, quant: bool = False,
-                       active: Optional[jax.Array] = None, lora=None
-                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """batcher._ring_forward over the paged pool: tok [B] at per-lane
-    cache['pos'] -> (logits [B, V], advanced cache).  The pools ride
-    the layer scan as CARRY (block ids are dynamic; slicing a layer out
-    per step would materialize it anyway), the kernel path hands the
-    stacked pools + table to paged_decode_attention, the einsum path
-    gathers the lane view per layer.
+# ---------------------------------------------------------------------------
+# The pool as decode.cached_forward sees a cache: the paged views
+# ---------------------------------------------------------------------------
 
-    ``quant=True`` (SERVE_KV_QUANT=int8): the cache is the codes+scales
-    +staging-tails dict (init_paged_cache quant) — new rows accumulate
-    exact in the lane's bf16 tail and quantize into the pool on block
-    completion (:func:`_write_token_quant`); attention reads codes with
-    the dequant fused in-kernel (or the dequantizing gather view on the
-    einsum path).  ``active`` [B] redirects inactive lanes' tail writes
-    to the trash tail — a mid-prefill lane's tail is live state the
-    resident chunk step must not touch (the tail analogue of masking
-    prefill-pending table rows to the trash block)."""
-    from paddle_operator_tpu.infer.executor import _qkv_ring
 
-    pos = cache["pos"]
-    adp, aid = lora if lora is not None else (None, None)
-    block_size = cache["k"].shape[3]
-    x = D._embed(cfg, params, tok[:, None])
-    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
-                                cfg.rope_theta)
+class PagedView:
+    """The bf16 pool + block table behind :func:`decode.cached_forward`
+    (decode.ContiguousView has the contract): ``cache`` the pool dict
+    with per-lane ``pos``, ``table`` [B, M] int32.  The pools ride the
+    layer scan as CARRY beside the layer's index (block ids are dynamic;
+    slicing a layer out per step would materialize it anyway).
 
-    attn_impl, use_sharded = D.resolve_decode_attn(cfg, mesh)
-    if quant:
-        return _paged_ring_forward_quant(
-            cfg, params, x, cache, table, pos, block_size, cos, sin,
-            attn_impl, use_sharded, active, mesh, lora=lora)
-    xs = ((params["layers"], adp, jnp.arange(cfg.n_layers))
-          if adp is not None
-          else (params["layers"], jnp.arange(cfg.n_layers)))
+    - One token a lane and no ``limit`` is the DECODE STEP: the row
+      lands through ``_write_token_paged`` and, where the kernel is on,
+      ``paged_decode_attention`` streams the table-mapped blocks (under
+      a serving mesh sharded, the output projection inside its manual
+      region).  An inactive lane needs no mask here: its zeroed table
+      row already sends its row to the trash block.
+    - Otherwise (speculative verify, suffix insert, prefill slices) rows
+      land wherever the table maps their absolute position — those at
+      or past ``limit`` [B] (pads) in the trash block; whole blocks at
+      once when ``aligned`` (the caller guarantees block-aligned ``pos``
+      and a block-multiple row count: the N-lane prefill engine, where
+      the per-row unroll is pathological to COMPILE) — and the einsum
+      attends over the gathered lane view (:meth:`lanes`).
 
-    def _unpack(layer_in):
-        if adp is not None:
-            lp, adp_l, li = layer_in
-            return lp, li, (adp_l, aid)
-        lp, li = layer_in
-        return lp, li, None
+    ``lane_mask`` is the int8 pool's (:class:`PagedQuantView`); this
+    view takes and ignores it so callers need not know the format
+    (:func:`paged_view`)."""
 
-    if use_sharded:
+    stacked = True
+
+    def __init__(self, cfg, cache: Dict[str, jax.Array], table: jax.Array,
+                 *, limit: Optional[jax.Array] = None,
+                 lane_mask: Optional[jax.Array] = None,
+                 aligned: bool = False, mesh=None) -> None:
+        self.cfg, self.mesh = cfg, mesh
+        self.cache, self.table, self.pos = cache, table, cache["pos"]
+        self.limit, self.lane_mask, self.aligned = limit, lane_mask, aligned
+        self.block_size = cache["k"].shape[3]
+
+    def enter(self, t: int) -> None:
+        """Fix the forward's kind from its static row count: the decode
+        step (``step``), and whether its attention is the kernel."""
+        self.t = t
+        self.step = t == 1 and self.limit is None
+        self.kernel, self.projects, self.interpret = D.decode_kernel_mode(
+            self.cfg, self.mesh, self.step)
+
+    def begin(self, t: int):
+        self.enter(t)
+        return ((self.cache["k"], self.cache["v"]),
+                jnp.arange(self.cfg.n_layers))
+
+    def write(self, bufs, li, k: jax.Array, v: jax.Array):
+        kc, vc = bufs
+        bs = self.block_size
+        if self.step:
+            kc = _write_token_paged(kc, k.transpose(0, 2, 1, 3), li,
+                                    self.table, self.pos, bs)
+            vc = _write_token_paged(vc, v.transpose(0, 2, 1, 3), li,
+                                    self.table, self.pos, bs)
+            return kc, vc
+        write = _write_blocks_paged if self.aligned else _write_rows_paged
+        kc = write(kc, k.transpose(0, 2, 1, 3), li, self.table, self.pos,
+                   bs, self.limit)
+        vc = write(vc, v.transpose(0, 2, 1, 3), li, self.table, self.pos,
+                   bs, self.limit)
+        return kc, vc
+
+    def lanes(self, bufs, li) -> Tuple[jax.Array, jax.Array]:
+        """Layer ``li`` of the pool as contiguous lanes, ``(k, v)``
+        [B, H, M*bs, D] — what an einsum attention reads (this block's
+        :func:`decode._attend_cache`, or another architecture's own)."""
+        kc, vc = bufs
+        return (_gather_lane_view(kc, self.table, li),
+                _gather_lane_view(vc, self.table, li))
+
+    def _kernel_operands(self, bufs) -> Dict[str, jax.Array]:
+        return {}
+
+    def kernel_attend(self, bufs, li, q: jax.Array, wo=None, window=None):
+        """The decode kernel over the table-mapped blocks: ``q``
+        [B, 1, Hq, D] -> [B, 1, Hq*D], or (``projects``) the residual
+        [B, dim] already through ``wo``.  ``window`` (a layer's sliding
+        window, tp 1): the lane attends its last ``window`` positions
+        and the kernel skips the blocks wholly before them."""
         from paddle_operator_tpu.ops.decode_attention import (
+            paged_decode_attention,
             sharded_paged_decode_attention,
         )
 
-        def body(carry, layer_in):
-            x, kc, vc = carry
-            lp, li, lo = _unpack(layer_in)
-            q, k, v = _qkv_ring(cfg, lp, x, cos, sin, pos, lora=lo)
-            kc = _write_token_paged(kc, k.transpose(0, 2, 1, 3), li,
-                                    table, pos, block_size)
-            vc = _write_token_paged(vc, v.transpose(0, 2, 1, 3), li,
-                                    table, pos, block_size)
-            proj = sharded_paged_decode_attention(
-                mesh, q[:, 0], kc, vc, table, pos + 1,
-                lp["attn"]["wo"]["kernel"], layer=li,
-                interpret=(attn_impl == "pallas-interpret"),
-                compute_dtype=cfg.dtype)
-            x = x + proj[:, None].astype(cfg.dtype)
-            return (D._ffn_residual(cfg, lp, x), kc, vc), ()
-    elif attn_impl != "xla":
-        from paddle_operator_tpu.ops.decode_attention import (
-            paged_decode_attention,
-        )
+        kc, vc = bufs[:2]
+        if self.projects:
+            return sharded_paged_decode_attention(
+                self.mesh, q[:, 0], kc, vc, self.table, self.pos + 1, wo,
+                layer=li, interpret=self.interpret,
+                compute_dtype=self.cfg.dtype, **self._kernel_operands(bufs))
+        out = paged_decode_attention(
+            q[:, 0], kc, vc, self.table, self.pos + 1, layer=li,
+            starts=(None if window is None
+                    else jnp.maximum(self.pos + 1 - window, 0)),
+            interpret=self.interpret, **self._kernel_operands(bufs))
+        return out.reshape(q.shape[0], 1, -1).astype(self.cfg.dtype)
 
-        b = x.shape[0]
-        hq, d = cfg.n_heads, cfg.head_dim
+    def attend(self, bufs, li, q: jax.Array, rows: jax.Array, wo):
+        if self.kernel:
+            return self.kernel_attend(bufs, li, q, wo)
+        return D._attend_cache(self.cfg, q, *self.lanes(bufs, li), rows)
 
-        def body(carry, layer_in):
-            x, kc, vc = carry
-            lp, li, lo = _unpack(layer_in)
-            q, k, v = _qkv_ring(cfg, lp, x, cos, sin, pos, lora=lo)
-            kc = _write_token_paged(kc, k.transpose(0, 2, 1, 3), li,
-                                    table, pos, block_size)
-            vc = _write_token_paged(vc, v.transpose(0, 2, 1, 3), li,
-                                    table, pos, block_size)
-            out = paged_decode_attention(
-                q[:, 0], kc, vc, table, pos + 1, layer=li,
-                interpret=(attn_impl == "pallas-interpret"))
-            out = out.reshape(b, 1, hq * d).astype(cfg.dtype)
-            return (D._finish_layer(cfg, lp, x, out), kc, vc), ()
-    else:
-        def body(carry, layer_in):
-            x, kc, vc = carry
-            lp, li, lo = _unpack(layer_in)
-            q, k, v = _qkv_ring(cfg, lp, x, cos, sin, pos, lora=lo)
-            kc = _write_token_paged(kc, k.transpose(0, 2, 1, 3), li,
-                                    table, pos, block_size)
-            vc = _write_token_paged(vc, v.transpose(0, 2, 1, 3), li,
-                                    table, pos, block_size)
-            out = _attend_einsum(cfg, q,
-                                 _gather_lane_view(kc, table, li),
-                                 _gather_lane_view(vc, table, li), pos)
-            return (D._finish_layer(cfg, lp, x, out), kc, vc), ()
-
-    (x, k_new, v_new), _ = jax.lax.scan(
-        body, (x, cache["k"], cache["v"]), xs)
-    logits = D._lm_head(cfg, params, x)
-    return logits[:, 0], {"k": k_new, "v": v_new, "pos": pos + 1}
+    def end(self, bufs, t: int) -> Dict[str, jax.Array]:
+        kc, vc = bufs
+        return {"k": kc, "v": vc, "pos": self.pos + t}
 
 
-def _paged_ring_forward_quant(cfg, params, x, cache, table, pos,
-                              block_size, cos, sin, attn_impl,
-                              use_sharded, active, mesh, lora=None):
-    """The quantized-pool decode forward (split out of
-    :func:`paged_ring_forward` so the bf16 path stays byte-identical):
-    same layer math, with the token write going through the staging
-    tail (:func:`_write_token_quant`) and the attention reading int8
-    codes — fused-dequant kernel where eligible, dequantizing gather
-    view on the einsum path."""
-    from paddle_operator_tpu.infer.executor import _qkv_ring
+class PagedQuantView(PagedView):
+    """:class:`PagedView` over the INT8 pool (``init_paged_cache``
+    ``quant="int8"``): codes, scales and the bf16 staging tails all ride
+    the scan.  New rows accumulate exact in the lane's tail and quantize
+    into the pool on block completion (``_write_token_quant`` for the
+    decode step, ``_write_rows_quant`` else); attention reads codes with
+    the dequant fused in-kernel, or the dequantizing gather view with
+    the lane's tail in place of its write-frontier block.
 
-    b = x.shape[0]
-    hq, d = cfg.n_heads, cfg.head_dim
-    adp, aid = lora if lora is not None else (None, None)
-    trash_row = cache["kt"].shape[1] - 1
-    lanes = jnp.arange(b)
-    rows_idx = (jnp.where(active, lanes, trash_row)
-                if active is not None else lanes)
-    xs = ((params["layers"], adp, jnp.arange(cfg.n_layers))
-          if adp is not None
-          else (params["layers"], jnp.arange(cfg.n_layers)))
+    ``lane_mask`` [B] (the step's ``active``, a round's, an engine's
+    participating lanes) sends masked lanes' rows to the TRASH tail: a
+    lane mid-prefill keeps live state in its tail that a resident
+    dispatch must not touch — the tail's analogue of masking a
+    prefill-pending table row to the trash block.  ``aligned`` does not
+    apply: the tail protocol is per row by nature."""
 
-    def _unpack(layer_in):
-        if adp is not None:
-            lp, adp_l, li = layer_in
-            return lp, li, (adp_l, aid)
-        lp, li = layer_in
-        return lp, li, None
+    def begin(self, t: int):
+        self.enter(t)
+        c = self.cache
+        if self.step:
+            trash_row = c["kt"].shape[1] - 1
+            lanes = jnp.arange(self.pos.shape[0])
+            self.rows_idx = (jnp.where(self.lane_mask, lanes, trash_row)
+                             if self.lane_mask is not None else lanes)
+        li = jnp.arange(self.cfg.n_layers)
+        if self.step and not self.kernel:
+            self.wb = self.pos // self.block_size
+        return (c["k"], c["v"], c["ks"], c["vs"], c["kt"], c["vt"]), li
 
-    if use_sharded:
-        from paddle_operator_tpu.ops.decode_attention import (
-            sharded_paged_decode_attention,
-        )
-
-        def body(carry, layer_in):
-            x, kc, vc, ks, vs, kt, vt = carry
-            lp, li, lo = _unpack(layer_in)
-            q, k, v = _qkv_ring(cfg, lp, x, cos, sin, pos, lora=lo)
+    def write(self, bufs, li, k: jax.Array, v: jax.Array):
+        kc, vc, ks, vs, kt, vt = bufs
+        if self.step:
             kc, ks, kt = _write_token_quant(
-                kc, ks, kt, k.transpose(0, 2, 1, 3), li, table, pos,
-                rows_idx, block_size)
+                kc, ks, kt, k.transpose(0, 2, 1, 3), li, self.table,
+                self.pos, self.rows_idx, self.block_size)
             vc, vs, vt = _write_token_quant(
-                vc, vs, vt, v.transpose(0, 2, 1, 3), li, table, pos,
-                rows_idx, block_size)
-            proj = sharded_paged_decode_attention(
-                mesh, q[:, 0], kc, vc, table, pos + 1,
-                lp["attn"]["wo"]["kernel"], layer=li,
-                interpret=(attn_impl == "pallas-interpret"),
-                compute_dtype=cfg.dtype,
-                k_scale=ks, v_scale=vs, k_tail=kt, v_tail=vt)
-            x = x + proj[:, None].astype(cfg.dtype)
-            return (D._ffn_residual(cfg, lp, x), kc, vc, ks, vs,
-                    kt, vt), ()
-    elif attn_impl != "xla":
-        from paddle_operator_tpu.ops.decode_attention import (
-            paged_decode_attention,
-        )
+                vc, vs, vt, v.transpose(0, 2, 1, 3), li, self.table,
+                self.pos, self.rows_idx, self.block_size)
+            return kc, vc, ks, vs, kt, vt
+        return _write_rows_quant(
+            kc, vc, ks, vs, kt, vt, k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), li, self.table, self.pos, self.limit,
+            self.lane_mask)
 
-        def body(carry, layer_in):
-            x, kc, vc, ks, vs, kt, vt = carry
-            lp, li, lo = _unpack(layer_in)
-            q, k, v = _qkv_ring(cfg, lp, x, cos, sin, pos, lora=lo)
-            kc, ks, kt = _write_token_quant(
-                kc, ks, kt, k.transpose(0, 2, 1, 3), li, table, pos,
-                rows_idx, block_size)
-            vc, vs, vt = _write_token_quant(
-                vc, vs, vt, v.transpose(0, 2, 1, 3), li, table, pos,
-                rows_idx, block_size)
-            out = paged_decode_attention(
-                q[:, 0], kc, vc, table, pos + 1, layer=li,
-                interpret=(attn_impl == "pallas-interpret"),
-                k_scale=ks, v_scale=vs, k_tail=kt, v_tail=vt)
-            out = out.reshape(b, 1, hq * d).astype(cfg.dtype)
-            return (D._finish_layer(cfg, lp, x, out), kc, vc, ks, vs,
-                    kt, vt), ()
-    else:
-        wb = pos // block_size
+    def lanes(self, bufs, li) -> Tuple[jax.Array, jax.Array]:
+        kc, vc, ks, vs, kt, vt = bufs
+        if self.step:
+            wb = self.wb
+        else:
+            # per-lane write-frontier block: the last REAL row written
+            # (pads never advance the tail), floor 0 for fully-masked
+            # lanes
+            lim_eff = (self.limit if self.limit is not None
+                       else self.pos + self.t)
+            wb = (jnp.maximum(jnp.minimum(self.pos + self.t, lim_eff) - 1,
+                              0) // self.block_size)
+        return (_gather_lane_view_quant(kc, ks, kt, self.table, li, wb),
+                _gather_lane_view_quant(vc, vs, vt, self.table, li, wb))
 
-        def body(carry, layer_in):
-            x, kc, vc, ks, vs, kt, vt = carry
-            lp, li, lo = _unpack(layer_in)
-            q, k, v = _qkv_ring(cfg, lp, x, cos, sin, pos, lora=lo)
-            kc, ks, kt = _write_token_quant(
-                kc, ks, kt, k.transpose(0, 2, 1, 3), li, table, pos,
-                rows_idx, block_size)
-            vc, vs, vt = _write_token_quant(
-                vc, vs, vt, v.transpose(0, 2, 1, 3), li, table, pos,
-                rows_idx, block_size)
-            out = _attend_einsum(
-                cfg, q, _gather_lane_view_quant(kc, ks, kt, table, li, wb),
-                _gather_lane_view_quant(vc, vs, vt, table, li, wb), pos)
-            return (D._finish_layer(cfg, lp, x, out), kc, vc, ks, vs,
-                    kt, vt), ()
+    def _kernel_operands(self, bufs) -> Dict[str, jax.Array]:
+        _, _, ks, vs, kt, vt = bufs
+        return dict(k_scale=ks, v_scale=vs, k_tail=kt, v_tail=vt)
 
-    (x, k_new, v_new, ks_new, vs_new, kt_new, vt_new), _ = jax.lax.scan(
-        body, (x, cache["k"], cache["v"], cache["ks"], cache["vs"],
-               cache["kt"], cache["vt"]), xs)
-    logits = D._lm_head(cfg, params, x)
-    return logits[:, 0], {"k": k_new, "v": v_new, "ks": ks_new,
-                          "vs": vs_new, "kt": kt_new, "vt": vt_new,
-                          "pos": pos + 1}
+    def end(self, bufs, t: int) -> Dict[str, jax.Array]:
+        kc, vc, ks, vs, kt, vt = bufs
+        return {"k": kc, "v": vc, "ks": ks, "vs": vs, "kt": kt, "vt": vt,
+                "pos": self.pos + t}
+
+
+def paged_view(cfg, cache: Dict[str, jax.Array], table: jax.Array,
+               **kw) -> PagedView:
+    """The view of a paged cache, chosen from the cache itself: the int8
+    pool carries its scales (``ks``)."""
+    return (PagedQuantView if "ks" in cache else PagedView)(
+        cfg, cache, table, **kw)
 
 
 def make_paged_chunk_step(cfg: LlamaConfig, chunk_tokens: int,
@@ -1513,7 +1540,7 @@ def make_paged_chunk_step(cfg: LlamaConfig, chunk_tokens: int,
                           check_finite: bool = False,
                           quant: bool = False):
     """The resident compiled decode program of the PAGED ring — the
-    exact contract of batcher.make_chunk_step plus the block table:
+    exact contract of executor.make_chunk_step plus the block table:
 
     ``step(params, cache, table, tok, temp, keys, active)
     -> (cache', tok', toks [chunk, B])``
@@ -1524,14 +1551,12 @@ def make_paged_chunk_step(cfg: LlamaConfig, chunk_tokens: int,
     re-allocated block.
 
     ``check_finite=True``: a fourth ``ok [B]`` output — the per-lane
-    isfinite fold of every tick's logits (batcher NaN-lane quarantine;
+    isfinite fold of every tick's logits (the ring's NaN-lane quarantine;
     see make_chunk_step).
 
     ``quant=True``: the cache is the int8 codes+scales+tails dict;
     ``active`` additionally steers inactive lanes' tail writes to the
-    trash tail (see paged_ring_forward)."""
-    from paddle_operator_tpu.infer.executor import _sample_tokens
-
+    trash tail (see :class:`PagedQuantView`)."""
     def step(params, cache, table, tok, temp, keys, active, *lora_args):
         lora = tuple(lora_args) if lora_args else None
 
@@ -1540,11 +1565,12 @@ def make_paged_chunk_step(cfg: LlamaConfig, chunk_tokens: int,
                 cache, tok, ok = carry
             else:
                 cache, tok = carry
-            logits, new_cache = paged_ring_forward(
-                cfg, params, tok, cache, table, mesh=mesh, quant=quant,
-                active=active if quant else None, lora=lora)
-            nxt = _sample_tokens(logits, temp, keys, cache["pos"],
-                                 top_k, top_p)
+            logits, new_cache = D.cached_step(
+                cfg, params, tok,
+                paged_view(cfg, cache, table, lane_mask=active, mesh=mesh),
+                lora=lora)
+            nxt = D._sample_tokens(logits, temp, keys, cache["pos"],
+                                   top_k, top_p)
             new_cache["pos"] = jnp.where(active, new_cache["pos"], 0)
             nxt = jnp.where(active, nxt, tok)
             if check_finite:
@@ -1572,7 +1598,7 @@ def make_paged_megastep(cfg: LlamaConfig, chunk_tokens: int,
     """N fused PAGED ring iterations in one compiled dispatch
     (ISSUE 11): ``make_paged_chunk_step``'s tick scanned ``n_steps``
     chunks with the host's boundary decisions — eos, token budget,
-    step budget — carried on device (executor._mega_advance).  The
+    step budget — carried on device (decode._mega_advance).  The
     paged pool is what makes a mid-megastep finish SAFE without host
     help: each fused chunk runs against an EFFECTIVE table whose dead
     lanes' rows are replaced wholesale by the trash block (the same
@@ -1589,11 +1615,6 @@ def make_paged_megastep(cfg: LlamaConfig, chunk_tokens: int,
     steps, *lora) -> (cache', tok', toks [n, chunk, B], counts [n, B]
     [, oks [n, B]])`` — the same output contract as
     executor.make_megastep, table operand added."""
-    from paddle_operator_tpu.infer.executor import (
-        _mega_continue,
-        _sample_tokens,
-    )
-
     def mega(params, cache, table, tok, temp, keys, active, eos, left,
              steps, *lora_args):
         lora = tuple(lora_args) if lora_args else None
@@ -1608,12 +1629,13 @@ def make_paged_megastep(cfg: LlamaConfig, chunk_tokens: int,
                     cache, tok, ok = c
                 else:
                     cache, tok = c
-                logits, new_cache = paged_ring_forward(
-                    cfg, params, tok, cache, tbl_eff, mesh=mesh,
-                    quant=quant, active=live if quant else None,
+                logits, new_cache = D.cached_step(
+                    cfg, params, tok,
+                    paged_view(cfg, cache, tbl_eff, lane_mask=live,
+                               mesh=mesh),
                     lora=lora)
-                nxt = _sample_tokens(logits, temp, keys, cache["pos"],
-                                     top_k, top_p)
+                nxt = D._sample_tokens(logits, temp, keys, cache["pos"],
+                                       top_k, top_p)
                 new_cache["pos"] = jnp.where(live, new_cache["pos"], 0)
                 nxt = jnp.where(live, nxt, tok)
                 if check_finite:
@@ -1630,7 +1652,7 @@ def make_paged_megastep(cfg: LlamaConfig, chunk_tokens: int,
                 (cache, tok), toks = jax.lax.scan(
                     tick, (cache, tok), None, length=chunk_tokens)
             raw = jnp.where(live, chunk_tokens, 0).astype(jnp.int32)
-            count, live2, left2, lsteps2 = _mega_continue(
+            count, live2, left2, lsteps2 = D._mega_continue(
                 toks, raw, live, lleft, lsteps, eos)
             cache["pos"] = jnp.where(live, cache["pos"], p0)
             out = (toks, count, ok) if check_finite else (toks, count)
@@ -1651,7 +1673,7 @@ def make_paged_megastep(cfg: LlamaConfig, chunk_tokens: int,
 
 
 @jax.named_scope("cache_write")
-def _scatter_prompt_blocks(pool: jax.Array, lane: jax.Array,
+def scatter_prompt_blocks(pool: jax.Array, lane: jax.Array,
                            table_row: jax.Array,
                            block_size: int) -> jax.Array:
     """Write a contiguous [L, 1, H, bucket, D] prefilled lane cache
@@ -1664,6 +1686,81 @@ def _scatter_prompt_blocks(pool: jax.Array, lane: jax.Array,
     )
 
     return scatter_prefill_blocks(pool, lane, table_row, block_size)
+
+
+def paged_prefill(params: Dict[str, Any], cfg: LlamaConfig,
+                  tokens: jax.Array, pool_cache: Dict[str, jax.Array],
+                  table_row: jax.Array, *, block_size: Optional[int] = None,
+                  mesh=None, quant: bool = False,
+                  prompt_len: Optional[jax.Array] = None, lora=None):
+    """Prefill a whole [1, bucket] prompt and write its KV into the
+    PAGED block pool as block-aligned chunks at the
+    lane's ``table_row`` entries — the cold-admission half of paged
+    serving.  The forward itself is exactly ``decode.prefill``'s (same
+    compiled ops — what keeps the paged ring's first token
+    bit-identical to the contiguous ring's — and the same choice of
+    attention by width, ``decode.prefill_attn_impl``: the flash kernel
+    over the prompt's own q, k, v where it runs, else the einsum over
+    the lane cache); only the destination
+    changes: block ``j`` of the lane cache lands in pool block
+    ``table_row[j]``, pad blocks land wherever the table maps them
+    (the trash block when unmapped — exactness-with-padding,
+    block-granular).  Returns ([1, bucket, vocab] logits — the caller
+    samples at ``prompt_len - 1`` — and the pool cache with this
+    lane's position untouched (the caller's insert sets it).
+
+    ``quant=True`` (needs ``prompt_len``, traced): whole blocks
+    quantize ONCE on the way into the int8 pool
+    (ops/decode_attention.py scatter_prefill_blocks_quant), and the
+    prompt's partial last block is returned as exact bf16 tail tiles
+    ``(logits, cache', tail_k, tail_v)`` [L, 1, H, bs, D] for the
+    caller's insert to splice into the lane's staging tail — the one
+    block whose scale cannot be final yet."""
+    bs = block_size or pool_cache["k"].shape[3]
+    lane = D.init_cache(cfg, 1, tokens.shape[1])
+    logits, lane = D._forward(cfg, params, tokens, lane, mesh=mesh,
+                              lora=lora, whole_prompt=True)
+    if not quant:
+        k = scatter_prompt_blocks(pool_cache["k"], lane["k"], table_row,
+                                   bs)
+        v = scatter_prompt_blocks(pool_cache["v"], lane["v"], table_row,
+                                   bs)
+        return logits, {"k": k, "v": v, "pos": pool_cache["pos"]}
+    from paddle_operator_tpu.ops.decode_attention import (
+        scatter_prefill_blocks_quant,
+    )
+
+    if prompt_len is None:
+        raise ValueError("quant paged_prefill needs prompt_len for the "
+                         "staging-tail slice")
+    k, ks = scatter_prefill_blocks_quant(
+        pool_cache["k"], pool_cache["ks"], lane["k"], table_row, bs)
+    v, vs = scatter_prefill_blocks_quant(
+        pool_cache["v"], pool_cache["vs"], lane["v"], table_row, bs)
+    # the write-frontier block's exact rows: [start, start + bs) of the
+    # lane cache.  The lane alloc need not be a block multiple, and
+    # dynamic_slice CLAMPS an out-of-range start backwards — which
+    # would hand back rows of the PREVIOUS block at the wrong tail
+    # offsets (positions start+o would attend K/V of start-pad+o) —
+    # so pad the time axis up to a block multiple first.  The one
+    # remaining clamp (block-aligned prompt filling the whole padded
+    # alloc, start == padded len) is harmless: decode then begins a
+    # FRESH block and every stale tail row sits behind the fill mask.
+    L, _, h, t_alloc, dd = lane["k"].shape
+    pad = -t_alloc % bs
+    lane_k, lane_v = lane["k"], lane["v"]
+    if pad:
+        widths = ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0))
+        lane_k = jnp.pad(lane_k, widths)
+        lane_v = jnp.pad(lane_v, widths)
+    start = (prompt_len // bs) * bs
+    tail_k = jax.lax.dynamic_slice(lane_k, (0, 0, 0, start, 0),
+                                   (L, 1, h, bs, dd))
+    tail_v = jax.lax.dynamic_slice(lane_v, (0, 0, 0, start, 0),
+                                   (L, 1, h, bs, dd))
+    cache = {"k": k, "v": v, "ks": ks, "vs": vs, "kt": pool_cache["kt"],
+             "vt": pool_cache["vt"], "pos": pool_cache["pos"]}
+    return logits, cache, tail_k, tail_v
 
 
 def make_paged_prefill_insert(cfg: LlamaConfig, bucket: int,
@@ -1685,8 +1782,6 @@ def make_paged_prefill_insert(cfg: LlamaConfig, bucket: int,
     prompt [1,bucket], prompt_len, slot, temp_val, seed)
     -> (cache', tok', temp', keys', first_token)``
     """
-    from paddle_operator_tpu.infer.executor import _sample_tokens
-
     if bucket % block_size:
         raise ValueError(f"prefill bucket {bucket} not a multiple of the "
                          f"block size {block_size}")
@@ -1695,7 +1790,7 @@ def make_paged_prefill_insert(cfg: LlamaConfig, bucket: int,
                prompt_len, slot, temp_val, seed, *lora_args):
         lora = tuple(lora_args) if lora_args else None
         if quant:
-            logits, new_cache, tail_k, tail_v = D.paged_prefill(
+            logits, new_cache, tail_k, tail_v = paged_prefill(
                 params, cfg, prompt, cache, table_row,
                 block_size=block_size, mesh=mesh, quant=True,
                 prompt_len=prompt_len, lora=lora)
@@ -1704,14 +1799,14 @@ def make_paged_prefill_insert(cfg: LlamaConfig, bucket: int,
             new_cache["vt"] = jax.lax.dynamic_update_slice(
                 new_cache["vt"], tail_v, (0, slot, 0, 0, 0))
         else:
-            logits, new_cache = D.paged_prefill(params, cfg, prompt,
-                                                cache, table_row,
-                                                block_size=block_size,
-                                                mesh=mesh, lora=lora)
+            logits, new_cache = paged_prefill(params, cfg, prompt,
+                                              cache, table_row,
+                                              block_size=block_size,
+                                              mesh=mesh, lora=lora)
         logits = logits[0, prompt_len - 1]
         new_cache["pos"] = new_cache["pos"].at[slot].set(prompt_len)
         key = jax.random.PRNGKey(seed)
-        first = _sample_tokens(
+        first = D._sample_tokens(
             logits[None], jnp.reshape(temp_val, (1,)).astype(jnp.float32),
             key[None], jnp.reshape(prompt_len - 1, (1,)),
             top_k, top_p)[0]
@@ -1727,7 +1822,7 @@ def make_paged_prefill_insert(cfg: LlamaConfig, bucket: int,
 def _slice_lane_tails(cache: Dict[str, jax.Array], slot):
     """One lane's staging tails as 2-row mini-arrays (row 0 = the lane,
     row 1 = a zeroed trash row) for a batch-of-one quant forward —
-    _multi_forward_paged addresses tails by lane index with the LAST
+    :class:`PagedQuantView` addresses tails by lane index with the LAST
     row as trash, so a B=1 call needs exactly this shape."""
     lcount, _, h, bs, d = cache["kt"].shape
     mk = jax.lax.dynamic_slice(cache["kt"], (0, slot, 0, 0, 0),
@@ -1757,8 +1852,8 @@ def make_paged_suffix_insert(cfg: LlamaConfig, suffix_bucket: int,
     """Prefix-HIT paged admission: the lane's table already maps the
     cached prefix blocks (read-only; CoW'd where the suffix will
     write), so the forward runs over the SUFFIX ONLY — a multi-token
-    per-lane-offset forward (speculative._multi_forward_paged) whose
-    attention walks the block table.  A shared 2048-token system prompt
+    per-lane-offset forward (``decode.cached_forward`` over the pool's
+    view) whose attention walks the block table.  A shared 2048-token system prompt
     costs its followers exactly the suffix; the prefill-call counter
     the tests assert on never ticks for the cached prefix.
 
@@ -1772,9 +1867,6 @@ def make_paged_suffix_insert(cfg: LlamaConfig, suffix_bucket: int,
     suffix [1, suffix_bucket], suffix_len, hit_len, slot, temp_val,
     seed) -> (cache', tok', temp', keys', first_token)``
     """
-    from paddle_operator_tpu.infer.executor import _sample_tokens
-    from paddle_operator_tpu.infer.speculative import _multi_forward_paged
-
     def insert(params, cache, table_row, tok, temp, keys, suffix,
                suffix_len, hit_len, slot, temp_val, seed, *lora_args):
         prompt_len = hit_len + suffix_len
@@ -1784,9 +1876,10 @@ def make_paged_suffix_insert(cfg: LlamaConfig, suffix_bucket: int,
             lane_cache["ks"], lane_cache["vs"] = cache["ks"], cache["vs"]
             lane_cache["kt"], lane_cache["vt"] = _slice_lane_tails(
                 cache, slot)
-        logits, new_lane = _multi_forward_paged(
-            cfg, params, suffix, lane_cache, table_row[None, :],
-            limit=jnp.reshape(prompt_len, (1,)), mesh=mesh, quant=quant,
+        logits, new_lane = D.cached_forward(
+            cfg, params, suffix,
+            paged_view(cfg, lane_cache, table_row[None, :],
+                       limit=jnp.reshape(prompt_len, (1,)), mesh=mesh),
             lora=tuple(lora_args) if lora_args else None)
         logits = logits[0, suffix_len - 1]
         new_cache = {"k": new_lane["k"], "v": new_lane["v"],
@@ -1797,7 +1890,7 @@ def make_paged_suffix_insert(cfg: LlamaConfig, suffix_bucket: int,
             new_cache["kt"], new_cache["vt"] = _restore_lane_tails(
                 cache, new_lane, slot)
         key = jax.random.PRNGKey(seed)
-        first = _sample_tokens(
+        first = D._sample_tokens(
             logits[None], jnp.reshape(temp_val, (1,)).astype(jnp.float32),
             key[None], jnp.reshape(prompt_len - 1, (1,)),
             top_k, top_p)[0]
@@ -1826,11 +1919,6 @@ def make_paged_spec_prefill_insert(cfg: LlamaConfig, dcfg: LlamaConfig,
     keys, prompt, prompt_len, slot, temp_val, seed)
     -> (cache', dcache', tok', temp', keys', first_token)``
     """
-    from paddle_operator_tpu.infer.executor import (
-        _sample_tokens,
-        _splice_lane,
-    )
-
     if bucket % block_size:
         raise ValueError(f"prefill bucket {bucket} not a multiple of the "
                          f"block size {block_size}")
@@ -1838,7 +1926,7 @@ def make_paged_spec_prefill_insert(cfg: LlamaConfig, dcfg: LlamaConfig,
     def insert(params, dparams, cache, dcache, table_row, tok, temp, keys,
                prompt, prompt_len, slot, temp_val, seed):
         if quant:
-            logits, new_cache, tail_k, tail_v = D.paged_prefill(
+            logits, new_cache, tail_k, tail_v = paged_prefill(
                 params, cfg, prompt, cache, table_row,
                 block_size=block_size, mesh=mesh, quant=True,
                 prompt_len=prompt_len)
@@ -1847,19 +1935,19 @@ def make_paged_spec_prefill_insert(cfg: LlamaConfig, dcfg: LlamaConfig,
             new_cache["vt"] = jax.lax.dynamic_update_slice(
                 new_cache["vt"], tail_v, (0, slot, 0, 0, 0))
         else:
-            logits, new_cache = D.paged_prefill(params, cfg, prompt,
-                                                cache, table_row,
-                                                block_size=block_size,
-                                                mesh=mesh)
+            logits, new_cache = paged_prefill(params, cfg, prompt,
+                                              cache, table_row,
+                                              block_size=block_size,
+                                              mesh=mesh)
         logits = logits[0, prompt_len - 1]
         new_cache["pos"] = new_cache["pos"].at[slot].set(prompt_len)
         dlane = D.init_cache(dcfg, 1, bucket)
         _, dlane = D._forward(dcfg, dparams, prompt, dlane,
                               last_only=True, mesh=mesh,
                               whole_prompt=True)
-        new_dcache = _splice_lane(dcache, dlane, slot, prompt_len)
+        new_dcache = D._splice_lane(dcache, dlane, slot, prompt_len)
         key = jax.random.PRNGKey(seed)
-        first = _sample_tokens(
+        first = D._sample_tokens(
             logits[None], jnp.reshape(temp_val, (1,)).astype(jnp.float32),
             key[None], jnp.reshape(prompt_len - 1, (1,)),
             top_k, top_p)[0]
@@ -1892,15 +1980,14 @@ def make_paged_prefill_chunk(cfg: LlamaConfig, slice_bucket: int,
     quantize whole blocks as they complete, so the tail state carried
     between slices IS the cache dict's — no extra bookkeeping.
     """
-    from paddle_operator_tpu.infer.speculative import _multi_forward_paged
-
     def chunk(params, cache, table_row, toks, start, limit, *lora_args):
         lane_cache = {"k": cache["k"], "v": cache["v"],
                       "pos": jnp.reshape(start, (1,)).astype(jnp.int32)}
-        _, new = _multi_forward_paged(
-            cfg, params, toks, lane_cache, table_row[None, :],
-            limit=jnp.reshape(limit, (1,)), mesh=mesh, head=False,
-            lora=tuple(lora_args) if lora_args else None)
+        _, new = D.cached_forward(
+            cfg, params, toks,
+            paged_view(cfg, lane_cache, table_row[None, :],
+                       limit=jnp.reshape(limit, (1,)), mesh=mesh),
+            head=False, lora=tuple(lora_args) if lora_args else None)
         return {"k": new["k"], "v": new["v"], "pos": cache["pos"]}
 
     def chunk_quant(params, cache, table_row, toks, start, limit, slot,
@@ -1910,11 +1997,11 @@ def make_paged_prefill_chunk(cfg: LlamaConfig, slice_bucket: int,
                       "ks": cache["ks"], "vs": cache["vs"],
                       "kt": mk, "vt": mv,
                       "pos": jnp.reshape(start, (1,)).astype(jnp.int32)}
-        _, new = _multi_forward_paged(
-            cfg, params, toks, lane_cache, table_row[None, :],
-            limit=jnp.reshape(limit, (1,)), mesh=mesh, head=False,
-            quant=True,
-            lora=tuple(lora_args) if lora_args else None)
+        _, new = D.cached_forward(
+            cfg, params, toks,
+            paged_view(cfg, lane_cache, table_row[None, :],
+                       limit=jnp.reshape(limit, (1,)), mesh=mesh),
+            head=False, lora=tuple(lora_args) if lora_args else None)
         kt, vt = _restore_lane_tails(cache, new, slot)
         return {"k": new["k"], "v": new["v"], "ks": new["ks"],
                 "vs": new["vs"], "kt": kt, "vt": vt,
@@ -1940,12 +2027,6 @@ def make_paged_spec_suffix_insert(cfg: LlamaConfig, dcfg: LlamaConfig,
     prompt [1, bucket], prompt_len, temp_val, seed)
     -> (cache', dcache', tok', temp', keys', first_token)``
     """
-    from paddle_operator_tpu.infer.executor import (
-        _sample_tokens,
-        _splice_lane,
-    )
-    from paddle_operator_tpu.infer.speculative import _multi_forward_paged
-
     def insert(params, dparams, cache, dcache, table_row, tok, temp,
                keys, suffix, suffix_len, hit_len, slot, prompt,
                prompt_len, temp_val, seed):
@@ -1955,9 +2036,10 @@ def make_paged_spec_suffix_insert(cfg: LlamaConfig, dcfg: LlamaConfig,
             lane_cache["ks"], lane_cache["vs"] = cache["ks"], cache["vs"]
             lane_cache["kt"], lane_cache["vt"] = _slice_lane_tails(
                 cache, slot)
-        logits, new_lane = _multi_forward_paged(
-            cfg, params, suffix, lane_cache, table_row[None, :],
-            limit=jnp.reshape(prompt_len, (1,)), mesh=mesh, quant=quant)
+        logits, new_lane = D.cached_forward(
+            cfg, params, suffix,
+            paged_view(cfg, lane_cache, table_row[None, :],
+                       limit=jnp.reshape(prompt_len, (1,)), mesh=mesh))
         logits = logits[0, suffix_len - 1]
         new_cache = {"k": new_lane["k"], "v": new_lane["v"],
                      "pos": cache["pos"].at[slot].set(prompt_len)}
@@ -1970,9 +2052,9 @@ def make_paged_spec_suffix_insert(cfg: LlamaConfig, dcfg: LlamaConfig,
         _, dlane = D._forward(dcfg, dparams, prompt, dlane,
                               last_only=True, mesh=mesh,
                               whole_prompt=True)
-        new_dcache = _splice_lane(dcache, dlane, slot, prompt_len)
+        new_dcache = D._splice_lane(dcache, dlane, slot, prompt_len)
         key = jax.random.PRNGKey(seed)
-        first = _sample_tokens(
+        first = D._sample_tokens(
             logits[None], jnp.reshape(temp_val, (1,)).astype(jnp.float32),
             key[None], jnp.reshape(prompt_len - 1, (1,)),
             top_k, top_p)[0]

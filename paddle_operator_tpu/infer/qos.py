@@ -570,7 +570,7 @@ class AdapterRegistry:
 
 def lora_qkv(h, adp_l, aid, q, k, v, dtype):
     """THE shared adapter-delta rule, applied at every q/k/v projection
-    site (decode._qkv, executor._qkv_ring, speculative._layer_multi*,
+    site (decode._qkv and decode._cached_layer, through _qkv_proj,
     and through them every admission insert and the resident step), so
     prefill KV and decode KV can never be computed under different
     adapter math.

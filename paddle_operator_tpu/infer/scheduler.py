@@ -1,12 +1,11 @@
 """Host half of the serving ring: the continuous-batching scheduler.
 
-ISSUE 6 split ``infer/batcher.py`` into this scheduler (admission,
+ISSUE 6 split the one-file ring into this scheduler (admission,
 queues, deadlines, request lifecycle, resilience hooks — pure host
 code; the only jax it touches is sequencing dispatches on its
 executor) and ``infer/executor.py`` (compiled programs + device state).
-:class:`ContinuousBatcher` keeps its name, constructor surface and
-behavior — ``infer/batcher.py`` re-exports it — and gains the prefill
-modes the split exists for:
+:class:`ContinuousBatcher` kept its name, constructor surface and
+behavior, and gained the prefill modes the split exists for:
 
 - ``prefill_mode="inline"``: admission prefills the whole prompt in one
   compiled dispatch on the ring thread (the original behavior — one

@@ -18,7 +18,7 @@ Two modes:
   serialize behind each other.
 - **continuous mode** (``make_server(..., continuous=True)``): requests
   are admitted into a fixed ring of decode lanes sharing ONE resident
-  compiled step (infer/batcher.py) — staggered concurrent requests
+  compiled step (infer/scheduler.py) — staggered concurrent requests
   decode side by side, lanes recycle on eos/budget, and the compile set
   is fixed regardless of arrival pattern.  Per-request knobs:
   max_new_tokens, temperature, seed, eos_token; top-k/top-p are
@@ -105,7 +105,7 @@ class ContinuousGenerator:
     the point."""
 
     def __init__(self, params: Any, cfg: LlamaConfig, **ring_kw) -> None:
-        from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+        from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
         self.batcher = ContinuousBatcher(params, cfg, **ring_kw)
         self.cfg = cfg
@@ -850,7 +850,7 @@ def make_server(host: str, port: int, params: Any, cfg: LlamaConfig,
                 job: str = "local", replica: str = "",
                 **ring_kw) -> ThreadingHTTPServer:
     """``continuous=True`` serves through the decode ring
-    (infer/batcher.py; ``ring_kw``: slots, max_len, chunk_tokens,
+    (infer/scheduler.py; ``ring_kw``: slots, max_len, chunk_tokens,
     prefill_buckets, top_k, top_p).  ``mesh`` (make_serving_mesh)
     makes either mode tensor-parallel — the ring's resident programs
     and the batch generator's jits compile sharded, token streams

@@ -15,15 +15,15 @@ accepted token the target streams its weights 1/(a+1) times.
 
 Design, in this codebase's terms:
 
-- **Draft propose** rides the existing single-token ring step
-  (infer/batcher.py ``_ring_forward`` — per-lane positions, pallas
-  kernel on TPU) for K+1 ticks: the last tick's logits are discarded
+- **Draft propose** rides the ring's single-token step
+  (infer/decode.py ``cached_step`` over the draft's contiguous view —
+  per-lane positions, pallas kernel on TPU) for K+1 ticks: the last tick's logits are discarded
   but its cache write appends d_K's KV, so ANY accept length can rewind
   without a gap (the standard "feed the last draft too" trick).
 - **Chunked verify** is one multi-token forward at per-lane offsets
-  (:func:`_multi_forward`) — the prefill math of infer/decode.py
-  ``_layer`` generalized to a per-lane position vector, reusing the
-  cache-append layout the ring path established.  XLA einsum attention:
+  (infer/decode.py ``cached_forward``, the same forward over the target
+  cache's view, contiguous or paged) — the prefill math of ``_layer``
+  generalized to a per-lane position vector.  XLA einsum attention:
   T = K+1 is a handful of rows, the weight stream dominates.
 - **Acceptance**: exact greedy equality at temperature 0 (output is
   BIT-IDENTICAL to autoregressive ``decode.generate`` — pinned by
@@ -57,7 +57,7 @@ rejects the combination up front rather than silently not checking.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import numpy as np
 
@@ -65,7 +65,8 @@ import jax
 import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
-from paddle_operator_tpu.models.llama import LlamaConfig, rope_frequencies
+from paddle_operator_tpu.infer import paged as PG
+from paddle_operator_tpu.models.llama import LlamaConfig
 
 
 def check_draft_compat(cfg: LlamaConfig, draft_cfg: LlamaConfig) -> None:
@@ -80,380 +81,6 @@ def check_draft_compat(cfg: LlamaConfig, draft_cfg: LlamaConfig) -> None:
             f"{draft_cfg.vocab_size} vs target {cfg.vocab_size} — "
             "speculative decoding exchanges token ids between the two "
             "models, so they must share one tokenizer")
-
-
-# ---------------------------------------------------------------------------
-# Device side: multi-token verify forward at per-lane positions
-# ---------------------------------------------------------------------------
-
-
-@jax.named_scope("cache_write")
-def _write_rows(cache_l: jax.Array, kv: jax.Array,
-                pos: jax.Array) -> jax.Array:
-    """[B, H, S, D] cache layer <- [B, H, T, D] new rows at per-lane
-    start positions ``pos``.  Unrolled per lane (static slot count) for
-    the same reason as batcher._write_lane_stacked: a vmapped update
-    over ragged positions lowers to a scatter that copies the carry."""
-    for lane in range(kv.shape[0]):
-        cache_l = jax.lax.dynamic_update_slice(
-            cache_l, kv[lane][None], (lane, 0, pos[lane], 0))
-    return cache_l
-
-
-def _proj_qkv(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
-              lora=None) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Shared multi-token projection block: norm -> q/k/v (+ the
-    per-row LoRA delta when ``lora=(adp_l, aid)`` — qos.lora_qkv, the
-    same rule every other projection site applies), reshaped to
-    [B, T, H, D] pre-RoPE."""
-    b, t, _ = x.shape
-    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    h = D._rms(x, lp["attn_norm"]["scale"], cfg.norm_eps, cfg.dtype)
-    q = D._mm(h, lp["attn"]["wq"]["kernel"], cfg.dtype)
-    k = D._mm(h, lp["attn"]["wk"]["kernel"], cfg.dtype)
-    v = D._mm(h, lp["attn"]["wv"]["kernel"], cfg.dtype)
-    if lora is not None:
-        from paddle_operator_tpu.infer.qos import lora_qkv
-
-        q, k, v = lora_qkv(h, lora[0], lora[1], q, k, v, cfg.dtype)
-    return (q.reshape(b, t, hq, d), k.reshape(b, t, hkv, d),
-            v.reshape(b, t, hkv, d))
-
-
-def _layer_multi(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
-                 cos: jax.Array, sin: jax.Array, k_cache: jax.Array,
-                 v_cache: jax.Array, pos: jax.Array, lora=None
-                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One decoder layer over [B, T] new tokens starting at PER-LANE
-    offsets ``pos`` [B] — decode._layer's math with the scalar position
-    generalized to a vector (and batcher._layer_step's with one token
-    generalized to T).  Row (b, j) sits at absolute position pos[b]+j
-    and attends cache cols [0, pos[b]+j]."""
-    b, t, _ = x.shape
-    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q, k, v = _proj_qkv(cfg, lp, x, lora)
-    abs_pos = pos[:, None] + jnp.arange(t)[None, :]          # [B, T]
-    cos_b = cos[abs_pos][:, :, None, :]                      # [B, T, 1, d/2]
-    sin_b = sin[abs_pos][:, :, None, :]
-
-    def rot(u):
-        u1, u2 = jnp.split(u.astype(jnp.float32), 2, axis=-1)
-        return jnp.concatenate(
-            [u1 * cos_b - u2 * sin_b, u2 * cos_b + u1 * sin_b],
-            axis=-1).astype(u.dtype)
-
-    q, k = rot(q), rot(k)
-    k_cache = _write_rows(k_cache, k.transpose(0, 2, 1, 3), pos)
-    v_cache = _write_rows(v_cache, v.transpose(0, 2, 1, 3), pos)
-
-    n_rep = hq // hkv
-    s = k_cache.shape[2]
-    qg = q.reshape(b, t, hkv, n_rep, d)
-    scores = jnp.einsum("bthrd,bhsd->bthrs", qg, k_cache,
-                        preferred_element_type=jnp.float32) / jnp.sqrt(
-        jnp.float32(d))
-    mask = jnp.arange(s)[None, None, :] <= abs_pos[:, :, None]  # [B, T, S]
-    scores = jnp.where(mask[:, :, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bthrs,bhsd->bthrd", probs.astype(cfg.dtype),
-                     v_cache, preferred_element_type=jnp.float32)
-    out = out.reshape(b, t, hq * d).astype(cfg.dtype)
-    return D._finish_layer(cfg, lp, x, out), k_cache, v_cache
-
-
-def _multi_forward(cfg: LlamaConfig, params: Dict[str, Any],
-                   toks: jax.Array, cache: Dict[str, jax.Array],
-                   mesh=None, head: bool = True, lora=None
-                   ) -> Tuple[Optional[jax.Array], Dict[str, jax.Array]]:
-    """[B, T] new tokens at per-lane cache['pos'] -> ([B, T, vocab]
-    logits, advanced cache).  The chunked-verify forward: every einsum
-    is the ring path's, so under a serving mesh the whole thing rides
-    GSPMD off the param/cache shardings (T is a handful of rows — the
-    pallas single-query kernel has nothing to win here).
-
-    ``head=False`` skips the final norm + lm head and returns
-    ``(None, cache)`` — an INTERMEDIATE chunked-prefill slice
-    (executor.make_prefill_chunk) only appends KV, and head logits
-    over a whole slice are the biggest tensor in the prefill path."""
-    pos = cache["pos"]
-    adp, aid = lora if lora is not None else (None, None)
-    x = D._embed(cfg, params, toks)
-    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
-                                cfg.rope_theta)
-
-    def body(x, layer_in):
-        if adp is not None:
-            lp, adp_l, k_c, v_c = layer_in
-            lo = (adp_l, aid)
-        else:
-            lp, k_c, v_c = layer_in
-            lo = None
-        y, k_c, v_c = _layer_multi(cfg, lp, x, cos, sin, k_c, v_c, pos,
-                                   lora=lo)
-        return y, (k_c, v_c)
-
-    xs = ((params["layers"], adp, cache["k"], cache["v"])
-          if adp is not None
-          else (params["layers"], cache["k"], cache["v"]))
-    x, (k_new, v_new) = jax.lax.scan(body, x, xs)
-    new_cache = {"k": k_new, "v": v_new, "pos": pos + toks.shape[1]}
-    if not head:
-        return None, new_cache
-    logits = D._lm_head(cfg, params, x)
-    return logits, new_cache
-
-
-def _layer_multi_paged(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
-                       cos: jax.Array, sin: jax.Array, k_pool: jax.Array,
-                       v_pool: jax.Array, li: jax.Array, table: jax.Array,
-                       pos: jax.Array, limit: Optional[jax.Array],
-                       lora=None, aligned: bool = False
-                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """:func:`_layer_multi` over the PAGED pool (infer/paged.py): new
-    rows land in whatever pool block the lane's table maps for their
-    absolute position (rows past ``limit`` route to the trash block —
-    suffix-prefill pads), and the attention walks the table through the
-    gathered lane view.  Same einsum/mask sequence as the contiguous
-    verify, so greedy paged-vs-contiguous streams stay bit-identical.
-
-    ``aligned=True`` (callers that guarantee block-aligned ``pos`` and
-    a block-multiple row count — the N-lane prefill engine's slice
-    programs): writes go whole-block (``_write_blocks_paged``) instead
-    of per-row, collapsing the traced write-op count by
-    ``block_size``x — at production slice widths the per-row unroll is
-    pathological to compile, not just to run."""
-    from paddle_operator_tpu.infer.paged import (
-        _gather_lane_view,
-        _write_blocks_paged,
-        _write_rows_paged,
-    )
-
-    b, t, _ = x.shape
-    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q, k, v = _proj_qkv(cfg, lp, x, lora)
-    abs_pos = pos[:, None] + jnp.arange(t)[None, :]          # [B, T]
-    cos_b = cos[abs_pos][:, :, None, :]
-    sin_b = sin[abs_pos][:, :, None, :]
-
-    def rot(u):
-        u1, u2 = jnp.split(u.astype(jnp.float32), 2, axis=-1)
-        return jnp.concatenate(
-            [u1 * cos_b - u2 * sin_b, u2 * cos_b + u1 * sin_b],
-            axis=-1).astype(u.dtype)
-
-    q, k = rot(q), rot(k)
-    block_size = k_pool.shape[3]
-    write = _write_blocks_paged if aligned else _write_rows_paged
-    k_pool = write(k_pool, k.transpose(0, 2, 1, 3), li, table, pos,
-                   block_size, limit)
-    v_pool = write(v_pool, v.transpose(0, 2, 1, 3), li, table, pos,
-                   block_size, limit)
-    k_view = _gather_lane_view(k_pool, table, li)
-    v_view = _gather_lane_view(v_pool, table, li)
-
-    n_rep = hq // hkv
-    s = k_view.shape[2]
-    qg = q.reshape(b, t, hkv, n_rep, d)
-    scores = jnp.einsum("bthrd,bhsd->bthrs", qg, k_view,
-                        preferred_element_type=jnp.float32) / jnp.sqrt(
-        jnp.float32(d))
-    mask = jnp.arange(s)[None, None, :] <= abs_pos[:, :, None]  # [B, T, S]
-    scores = jnp.where(mask[:, :, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bthrs,bhsd->bthrd", probs.astype(cfg.dtype),
-                     v_view, preferred_element_type=jnp.float32)
-    out = out.reshape(b, t, hq * d).astype(cfg.dtype)
-    return D._finish_layer(cfg, lp, x, out), k_pool, v_pool
-
-
-def _layer_multi_paged_quant(cfg: LlamaConfig, lp: Dict[str, Any],
-                             x: jax.Array, cos: jax.Array, sin: jax.Array,
-                             kc: jax.Array, vc: jax.Array, ks: jax.Array,
-                             vs: jax.Array, kt: jax.Array, vt: jax.Array,
-                             li: jax.Array, table: jax.Array,
-                             pos: jax.Array, limit: Optional[jax.Array],
-                             lane_mask: Optional[jax.Array], lora=None):
-    """:func:`_layer_multi_paged` over the QUANTIZED pool
-    (SERVE_KV_QUANT=int8): each new row accumulates EXACT in the lane's
-    bf16 staging tail; a row completing its block quantizes the whole
-    tail block into the int8 pool — codes + one scale, computed once
-    from the full block (the reason the tail exists: per-token
-    requantization would re-derive the scale T times and perturb
-    already-written rows every step).  Rows that are pads (``p >=
-    limit``) or belong to masked lanes (``lane_mask``) redirect to the
-    TRASH tail row (index B) — a pad row writing the lane's real tail
-    would clobber live rows when the pad span wraps the block.  The
-    attention reads the dequantizing gather view: full blocks from the
-    pool, the write-frontier block from the tail."""
-    from paddle_operator_tpu.infer.paged import (
-        _gather_lane_view_quant,
-        quantize_kv,
-    )
-
-    b, t, _ = x.shape
-    hq, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q, k, v = _proj_qkv(cfg, lp, x, lora)
-    abs_pos = pos[:, None] + jnp.arange(t)[None, :]          # [B, T]
-    cos_b = cos[abs_pos][:, :, None, :]
-    sin_b = sin[abs_pos][:, :, None, :]
-
-    def rot(u):
-        u1, u2 = jnp.split(u.astype(jnp.float32), 2, axis=-1)
-        return jnp.concatenate(
-            [u1 * cos_b - u2 * sin_b, u2 * cos_b + u1 * sin_b],
-            axis=-1).astype(u.dtype)
-
-    q, k = rot(q), rot(k)
-    bs = kc.shape[3]
-    kh = k.transpose(0, 2, 1, 3)                             # [B, H, T, D]
-    vh = v.transpose(0, 2, 1, 3)
-    trash_row = kt.shape[1] - 1
-    for lane in range(b):
-        for j in range(t):
-            p = pos[lane] + j
-            real = None
-            if limit is not None:
-                real = p < limit[lane]
-            if lane_mask is not None:
-                real = (lane_mask[lane] if real is None
-                        else real & lane_mask[lane])
-            row = (lane if real is None
-                   else jnp.where(real, lane, trash_row))
-            kt = jax.lax.dynamic_update_slice(
-                kt, kh[lane, :, j][None, None, :, None, :],
-                (li, row, 0, p % bs, 0))
-            vt = jax.lax.dynamic_update_slice(
-                vt, vh[lane, :, j][None, None, :, None, :],
-                (li, row, 0, p % bs, 0))
-            complete = (p + 1) % bs == 0
-            if real is not None:
-                complete = complete & real
-            dst = table[lane, p // bs]
-
-            # block-completion commit behind a cond: only the
-            # 1-in-bs completing row pays the two tile quantizes +
-            # pool writes (same rationale as paged._write_token_quant)
-            def _commit(st, row=row, dst=dst, kt=kt, vt=vt):
-                kc, vc, ks, vs = st
-                ktile = jax.lax.dynamic_slice(
-                    kt, (li, row, 0, 0, 0), (1, 1, hkv, bs, d))
-                kcodes, kscale = quantize_kv(ktile)
-                kc = jax.lax.dynamic_update_slice(kc, kcodes,
-                                                  (li, dst, 0, 0, 0))
-                ks = jax.lax.dynamic_update_slice(ks, kscale,
-                                                  (li, dst, 0))
-                vtile = jax.lax.dynamic_slice(
-                    vt, (li, row, 0, 0, 0), (1, 1, hkv, bs, d))
-                vcodes, vscale = quantize_kv(vtile)
-                vc = jax.lax.dynamic_update_slice(vc, vcodes,
-                                                  (li, dst, 0, 0, 0))
-                vs = jax.lax.dynamic_update_slice(vs, vscale,
-                                                  (li, dst, 0))
-                return kc, vc, ks, vs
-
-            kc, vc, ks, vs = jax.lax.cond(complete, _commit,
-                                          lambda st: st,
-                                          (kc, vc, ks, vs))
-
-    # per-lane write-frontier block: the last REAL row written (pads
-    # never advance the tail), floor 0 for fully-masked lanes
-    lim_eff = limit if limit is not None else pos + t
-    wb = jnp.maximum(jnp.minimum(pos + t, lim_eff) - 1, 0) // bs
-    k_view = _gather_lane_view_quant(kc, ks, kt, table, li, wb)
-    v_view = _gather_lane_view_quant(vc, vs, vt, table, li, wb)
-
-    n_rep = hq // hkv
-    s = k_view.shape[2]
-    qg = q.reshape(b, t, hkv, n_rep, d)
-    scores = jnp.einsum("bthrd,bhsd->bthrs", qg, k_view,
-                        preferred_element_type=jnp.float32) / jnp.sqrt(
-        jnp.float32(d))
-    mask = jnp.arange(s)[None, None, :] <= abs_pos[:, :, None]  # [B, T, S]
-    scores = jnp.where(mask[:, :, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bthrs,bhsd->bthrd", probs.astype(cfg.dtype),
-                     v_view, preferred_element_type=jnp.float32)
-    out = out.reshape(b, t, hq * d).astype(cfg.dtype)
-    return D._finish_layer(cfg, lp, x, out), kc, vc, ks, vs, kt, vt
-
-
-def _multi_forward_paged(cfg: LlamaConfig, params: Dict[str, Any],
-                         toks: jax.Array, cache: Dict[str, jax.Array],
-                         table: jax.Array,
-                         limit: Optional[jax.Array] = None,
-                         mesh=None, head: bool = True,
-                         quant: bool = False,
-                         lane_mask: Optional[jax.Array] = None,
-                         lora=None, aligned: bool = False
-                         ) -> Tuple[Optional[jax.Array],
-                                    Dict[str, jax.Array]]:
-    """:func:`_multi_forward` with the target cache PAGED: the
-    chunked-verify (and paged suffix-prefill) forward whose writes and
-    attention walk the block table.  ``table`` [B, M] int32;
-    ``limit`` [B] (optional) bounds real rows per lane — pads beyond it
-    write to the trash block.  The pools ride the layer scan as carry
-    (block ids are dynamic).  ``head=False``: KV append only, logits
-    None (intermediate chunked-prefill slices,
-    paged.make_paged_prefill_chunk).
-
-    ``quant=True``: the cache is the int8 codes+scales+tails dict and
-    the per-lane staging tails ride the carry too; ``lane_mask`` [B]
-    (the spec round's ``active``) additionally redirects masked lanes'
-    writes to the trash tail — their tail rows may be live prefill
-    state (see :func:`_layer_multi_paged_quant`).
-
-    ``aligned=True`` (bf16 only — the quant tail protocol is
-    inherently per-row): block-aligned whole-block writes, see
-    :func:`_layer_multi_paged`."""
-    pos = cache["pos"]
-    adp, aid = lora if lora is not None else (None, None)
-    x = D._embed(cfg, params, toks)
-    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
-                                cfg.rope_theta)
-    xs = ((params["layers"], adp, jnp.arange(cfg.n_layers))
-          if adp is not None
-          else (params["layers"], jnp.arange(cfg.n_layers)))
-
-    def _unpack(layer_in):
-        if adp is not None:
-            lp, adp_l, li = layer_in
-            return lp, li, (adp_l, aid)
-        lp, li = layer_in
-        return lp, li, None
-
-    if quant:
-        def body_q(carry, layer_in):
-            x, kc, vc, ks, vs, kt, vt = carry
-            lp, li, lo = _unpack(layer_in)
-            y, kc, vc, ks, vs, kt, vt = _layer_multi_paged_quant(
-                cfg, lp, x, cos, sin, kc, vc, ks, vs, kt, vt, li,
-                table, pos, limit, lane_mask, lora=lo)
-            return (y, kc, vc, ks, vs, kt, vt), ()
-
-        (x, k_new, v_new, ks_new, vs_new, kt_new, vt_new), _ = \
-            jax.lax.scan(
-                body_q,
-                (x, cache["k"], cache["v"], cache["ks"], cache["vs"],
-                 cache["kt"], cache["vt"]), xs)
-        new_cache = {"k": k_new, "v": v_new, "ks": ks_new, "vs": vs_new,
-                     "kt": kt_new, "vt": vt_new,
-                     "pos": pos + toks.shape[1]}
-    else:
-        def body(carry, layer_in):
-            x, kc, vc = carry
-            lp, li, lo = _unpack(layer_in)
-            y, kc, vc = _layer_multi_paged(cfg, lp, x, cos, sin, kc, vc,
-                                           li, table, pos, limit,
-                                           lora=lo, aligned=aligned)
-            return (y, kc, vc), ()
-
-        (x, k_new, v_new), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]), xs)
-        new_cache = {"k": k_new, "v": v_new, "pos": pos + toks.shape[1]}
-    if not head:
-        return None, new_cache
-    logits = D._lm_head(cfg, params, x)
-    return logits, new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -483,7 +110,7 @@ def make_spec_round_fn(cfg: LlamaConfig, dcfg: LlamaConfig, spec_k: int,
     ``paged=True``: the TARGET cache is the paged block pool
     (infer/paged.py) — the round signature gains the block table after
     the caches (``round(params, dparams, tcache, dcache, table, ...)``)
-    and the verify forward walks it (:func:`_multi_forward_paged`).
+    and the verify forward walks it (``paged.paged_view``).
     The DRAFT cache stays a contiguous ring either way: its propose
     loop keeps the fast contiguous write path and pays no paging.
 
@@ -523,8 +150,6 @@ def _build_spec_round(cfg, dcfg, spec_k, top_k, top_p, mesh, paged,
     compiled program.  The op sequence is exactly what the jitted
     1-round program traced before the extraction; nothing about the
     round changed."""
-    from paddle_operator_tpu.infer.executor import _ring_forward
-
     kk = spec_k
 
     def _round(params, dparams, tcache, dcache, tok, temp, keys, active,
@@ -540,7 +165,8 @@ def _build_spec_round(cfg, dcfg, spec_k, top_k, top_p, mesh, paged,
         def draft_tick(carry, _):
             dc, tk = carry
             p0 = dc["pos"]
-            logits, dc = _ring_forward(dcfg, dparams, tk, dc, mesh=mesh)
+            logits, dc = D.cached_step(
+                dcfg, dparams, tk, D.ContiguousView(dcfg, dc, mesh))
             greedy = logits.argmax(-1).astype(jnp.int32)
             filt = D._filter_logits(
                 logits / jnp.maximum(temp, 1e-6)[:, None], top_k, top_p)
@@ -560,23 +186,15 @@ def _build_spec_round(cfg, dcfg, spec_k, top_k, top_p, mesh, paged,
         q = jnp.transpose(qdists[:kk], (1, 0, 2))            # [B, K, V]
 
         seq = jnp.concatenate([tok[:, None], drafts], axis=1)  # [B, K+1]
-        if paged and quant:
-            # quantized target pool: masked lanes' verify rows redirect
-            # to the trash tail (their tail rows may be live prefill
-            # state a resident dispatch must not clobber)
-            tlogits, tcache2 = _multi_forward_paged(
-                cfg, params, seq, tcache, table, mesh=mesh, quant=True,
-                lane_mask=active)
-        elif paged:
-            # paged target: the verify forward walks the block table —
-            # writes land in pool blocks, attention gathers the lane
-            # view (or streams table-mapped blocks on the kernel path)
-            tlogits, tcache2 = _multi_forward_paged(cfg, params, seq,
-                                                    tcache, table,
-                                                    mesh=mesh)
-        else:
-            tlogits, tcache2 = _multi_forward(cfg, params, seq, tcache,
-                                              mesh=mesh)
+        # the verify forward, over the target cache's view: paged, its
+        # writes land in pool blocks and the attention gathers the lane
+        # view; int8, masked lanes' rows go to the trash tail (their
+        # tail rows may be live prefill state a resident dispatch must
+        # not clobber)
+        view = (PG.paged_view(cfg, tcache, table, lane_mask=active,
+                              mesh=mesh)
+                if paged else D.ContiguousView(cfg, tcache, mesh))
+        tlogits, tcache2 = D.cached_forward(cfg, params, seq, view)
         tgt = tlogits.argmax(-1).astype(jnp.int32)           # [B, K+1]
 
         # greedy rule: accept while the draft equals the target argmax
@@ -635,8 +253,6 @@ def _build_spec_round(cfg, dcfg, spec_k, top_k, top_p, mesh, paged,
             # whose rewound write block was completed+quantized by the
             # verify; inactive lanes keep their (possibly live-prefill)
             # tails untouched
-            from paddle_operator_tpu.infer.paged import dequantize_kv
-
             bs_q = tcache2["k"].shape[3]
             wb_after = (tpos0 + kk) // bs_q
             wb_new = tcache2["pos"] // bs_q
@@ -650,11 +266,11 @@ def _build_spec_round(cfg, dcfg, spec_k, top_k, top_p, mesh, paged,
                 kt, vt = tails
                 blks = jnp.take_along_axis(table, wb_new[:, None],
                                            axis=1)[:, 0]       # [B]
-                deqk = dequantize_kv(
+                deqk = PG.dequantize_kv(
                     jnp.take(tcache2["k"], blks, axis=1),
                     jnp.take(tcache2["ks"], blks, axis=1),
                     kt.dtype)                           # [L, B, H, bs, D]
-                deqv = dequantize_kv(
+                deqv = PG.dequantize_kv(
                     jnp.take(tcache2["v"], blks, axis=1),
                     jnp.take(tcache2["vs"], blks, axis=1),
                     vt.dtype)
@@ -679,7 +295,7 @@ def make_spec_megastep(cfg: LlamaConfig, dcfg: LlamaConfig, spec_k: int,
     the raw round body (:func:`_build_spec_round`) scanned ``n_steps``
     times with the host's between-round decisions — eos inside a
     committed block, token budget, step budget — carried on device
-    (executor._mega_advance over each round's committed tokens).  A
+    (decode._mega_advance over each round's committed tokens).  A
     lane that finishes mid-megastep free-runs masked: under paging its
     verify writes go through an effective table whose row is replaced
     by the trash block, its draft writes land past its frozen draft
@@ -695,9 +311,6 @@ def make_spec_megastep(cfg: LlamaConfig, dcfg: LlamaConfig, spec_k: int,
     acceptance-telemetry number; 0 for dead rounds), ``counts[r, b]``
     the rows of ``committed[r, :, b]`` the host consumes (eos/budget
     truncated — scheduler._consume's walk, precomputed)."""
-    from paddle_operator_tpu.infer.executor import _mega_continue
-    from paddle_operator_tpu.infer.paged import TRASH_BLOCK
-
     _round = _build_spec_round(cfg, dcfg, spec_k, top_k, top_p, mesh,
                                paged, quant)
 
@@ -707,12 +320,12 @@ def make_spec_megastep(cfg: LlamaConfig, dcfg: LlamaConfig, spec_k: int,
         def outer(carry, _):
             tcache, dcache, tok, live, lleft, lsteps = carry
             tp0, dp0 = tcache["pos"], dcache["pos"]
-            tbl_eff = (jnp.where(live[:, None], table, TRASH_BLOCK)
+            tbl_eff = (jnp.where(live[:, None], table, PG.TRASH_BLOCK)
                        if paged else None)
             tcache, dcache, tok, committed, n_commit = _round(
                 params, dparams, tcache, dcache, tok, temp, keys, live,
                 tbl_eff)
-            count, live2, left2, lsteps2 = _mega_continue(
+            count, live2, left2, lsteps2 = D._mega_continue(
                 committed, n_commit, live, lleft, lsteps, eos)
             # frozen/dead lanes keep the positions their last consumed
             # token earned (the round zeroed them via the active mask)

@@ -64,7 +64,7 @@ class LlamaConfig:
     moe_top_k: int = 1
     moe_aux_weight: float = 0.01
     # Single-query attention implementation for the DECODE path
-    # (infer/decode.py, infer/batcher.py; training is untouched):
+    # (infer/decode.py, infer/paged.py; training is untouched):
     # "auto" (pallas on TPU, einsum elsewhere — the default), "xla"
     # (dense einsum over the full allocated cache), "pallas"
     # (ops/decode_attention.py — reads only the FILLED prefix; measured

@@ -106,7 +106,7 @@ class StepTimer:
 def serving_gauges(status_serving: dict, job: str,
                    replica: str = None) -> dict:
     """Prometheus gauge lines for one job's workload-published
-    ``status.serving`` block (infer/batcher.py
+    ``status.serving`` block (infer/scheduler.py
     ContinuousBatcher.serving_status) — shared by the manager's
     /metrics export (controller/manager.py) so names cannot drift from
     docs/serving.md.  ``job`` is ``namespace/name``.  Lives here (not

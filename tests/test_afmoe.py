@@ -219,7 +219,7 @@ def test_ring_serves_what_generate_answers_and_counts_it(model):
     """The scheduler's path end to end: requests through the continuous
     batcher answer generate's greedy tokens, and the routing counters
     arrive on serving_status with the dispatch's tokens."""
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
     _, cfg, _, params = model
     ring = ContinuousBatcher(params, cfg, slots=2, max_len=MAX_LEN,
@@ -356,8 +356,9 @@ def test_windowed_paged_kernel_against_the_einsum(window):
     got = paged_decode_attention(
         q, pool_k, pool_v, table, lengths, layer=li,
         starts=jnp.maximum(lengths - window, 0), interpret=True)
-    want = M.attend(cfg, q[:, None], PG._gather_lane_view(pool_k, table, li),
-                    PG._gather_lane_view(pool_v, table, li),
+    view = PG.PagedView(cfg, {"k": pool_k, "v": pool_v, "pos": lengths - 1},
+                        table)
+    want = M.attend(cfg, q[:, None], *view.lanes((pool_k, pool_v), li),
                     (lengths - 1)[:, None], window)
     assert rel(got.reshape(b, -1), want[:, 0]) < RTOL
     if window >= 384:       # no cut: the windowless kernel's answer

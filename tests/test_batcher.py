@@ -1,4 +1,5 @@
-"""Continuous-batching decode ring (infer/batcher.py) pinned against
+"""Continuous-batching decode ring (infer/scheduler.py over
+infer/executor.py; the cached forward in infer/decode.py) pinned against
 decode.generate: the ring generalizes the scalar cache position to
 per-lane vectors, so these equivalence tests are what keeps the two
 attention paths from diverging.  The scheduler tests then prove the
@@ -16,12 +17,12 @@ import jax
 import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
-from paddle_operator_tpu.infer.batcher import (
-    ContinuousBatcher,
-    init_ring_cache,
+from paddle_operator_tpu.infer.decode import init_ring_cache
+from paddle_operator_tpu.infer.executor import (
     make_chunk_step,
     make_prefill_insert,
 )
+from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 from paddle_operator_tpu.models.llama import make_model
 
 MAX_LEN = 64
@@ -80,8 +81,8 @@ class TestRingEquivalence:
             first.append(int(ftok))
         assert first == [r[0] for r in refs]     # prefill logits agree
 
-        from paddle_operator_tpu.infer.batcher import _ring_forward
-        ring_logits, _ = _ring_forward(cfg, params, tok, cache)
+        ring_logits, _ = D.cached_step(cfg, params, tok,
+                                       D.ContiguousView(cfg, cache))
         for i in range(3):
             np.testing.assert_allclose(np.asarray(ring_logits[i]),
                                        refs[i][1], rtol=1e-4, atol=1e-4,
@@ -196,7 +197,7 @@ class TestSeedFolding:
         """Seeds >= 2**31 hash-fold (batcher._fold_seed): same wide seed
         -> same stream; distinct wide seeds that a mask would collide
         (s and s + 2**31) -> distinct streams."""
-        from paddle_operator_tpu.infer.batcher import _fold_seed
+        from paddle_operator_tpu.infer.scheduler import _fold_seed
 
         s = 7
         assert _fold_seed(s + 2 ** 31) != _fold_seed(s + 2 ** 32)

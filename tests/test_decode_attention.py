@@ -195,11 +195,8 @@ class TestGenerateWithKernel:
     def test_ring_step_matches_xla_path(self):
         """The continuous-batching ring with the kernel: ragged lane
         positions through the pallas path."""
-        from paddle_operator_tpu.infer.batcher import (
-            _ring_forward,
-            init_ring_cache,
-            make_prefill_insert,
-        )
+        from paddle_operator_tpu.infer.decode import init_ring_cache
+        from paddle_operator_tpu.infer.executor import make_prefill_insert
 
         model, cfg_x = make_model("tiny", dtype=jnp.float32)
         params = model.init(jax.random.PRNGKey(0),
@@ -219,7 +216,8 @@ class TestGenerateWithKernel:
                 cache, tok, temp, keys, _f = insert(
                     params, cache, tok, temp, keys, p, n, slot, 0.0, 0)
             tok = jnp.asarray([3, 7], jnp.int32)
-            out, _ = _ring_forward(cfg, params, tok, cache)
+            out, _ = D.cached_step(cfg, params, tok,
+                                   D.ContiguousView(cfg, cache))
             return np.asarray(out)
 
         np.testing.assert_allclose(run(cfg_p), run(cfg_x),

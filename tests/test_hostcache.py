@@ -17,9 +17,9 @@ import jax
 import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
-from paddle_operator_tpu.infer.batcher import ContinuousBatcher
 from paddle_operator_tpu.infer.executor import RingExecutor
 from paddle_operator_tpu.infer.paged import HostCacheTier
+from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 from paddle_operator_tpu.models.llama import make_model
 
 MAX_LEN = 64

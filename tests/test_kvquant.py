@@ -15,13 +15,14 @@ import jax
 import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
-from paddle_operator_tpu.infer.batcher import ContinuousBatcher
 from paddle_operator_tpu.infer.paged import (
     dequantize_kv,
     init_paged_cache,
-    paged_ring_forward,
+    paged_prefill,
+    paged_view,
     quantize_kv,
 )
+from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 from paddle_operator_tpu.models.llama import Llama, make_model
 
 MAX_LEN = 64
@@ -211,10 +212,10 @@ class TestLogitBound:
         for quant in ("none", "int8"):
             cache = init_paged_cache(cfg, 1, n_blocks, BS,
                                      quant=quant)
-            out = D.paged_prefill(params, cfg, prompt, cache, table[0],
-                                  block_size=BS,
-                                  **({"quant": True, "prompt_len": 19}
-                                     if quant == "int8" else {}))
+            out = paged_prefill(params, cfg, prompt, cache, table[0],
+                                block_size=BS,
+                                **({"quant": True, "prompt_len": 19}
+                                   if quant == "int8" else {}))
             if quant == "int8":
                 logits, cache, tail_k, tail_v = out
                 cache["kt"] = cache["kt"].at[:, :1].set(tail_k)
@@ -229,11 +230,9 @@ class TestLogitBound:
         assert d0 <= self.TOL, f"prefill logit delta {d0}"
         tok = {q: jnp.asarray([int(logits0[q].argmax())]) for q in caches}
         steps = {
-            q: jax.jit(lambda pr, t, c, _q=(q == "int8"):
-                       paged_ring_forward(
-                           cfg, pr, t, c, table, quant=_q,
-                           active=(jnp.ones((1,), bool) if _q
-                                   else None)))
+            q: jax.jit(lambda pr, t, c: D.cached_step(
+                cfg, pr, t, paged_view(cfg, c, table,
+                                       lane_mask=jnp.ones((1,), bool))))
             for q in caches}
         worst = d0
         for _ in range(24):                  # crosses 3 block bounds

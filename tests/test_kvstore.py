@@ -551,7 +551,7 @@ def setup():
 
 
 def _ring(cfg, params, **kw):
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
     kw.setdefault("slots", 1)
     kw.setdefault("max_len", MAX_LEN)

@@ -32,9 +32,9 @@ import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
 from paddle_operator_tpu.infer import qos as QOS
-from paddle_operator_tpu.infer.batcher import ContinuousBatcher
 from paddle_operator_tpu.infer.chaos import ChaosInjector
 from paddle_operator_tpu.infer.resilience import RingResilience
+from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 from paddle_operator_tpu.models.llama import Llama, make_model
 
 MAX_LEN = 64

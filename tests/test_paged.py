@@ -15,12 +15,12 @@ import jax
 import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
-from paddle_operator_tpu.infer.batcher import ContinuousBatcher
 from paddle_operator_tpu.infer.paged import (
     NoFreeBlocks,
     PagedCacheManager,
     TRASH_BLOCK,
 )
+from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 from paddle_operator_tpu.models.llama import Llama, make_model
 
 MAX_LEN = 64

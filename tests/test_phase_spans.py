@@ -182,7 +182,7 @@ def tiny():
 
 
 def _ring(tiny, **kw):
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
     params, cfg = tiny
     return ContinuousBatcher(params, cfg, slots=2, max_len=64,

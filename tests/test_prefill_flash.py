@@ -21,11 +21,11 @@ import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
 from paddle_operator_tpu.infer import paged as PG
-from paddle_operator_tpu.infer.batcher import ContinuousBatcher
 from paddle_operator_tpu.infer.executor import (
     init_ring_cache,
     make_prefill_insert,
 )
+from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 from paddle_operator_tpu.models.llama import make_model
 from paddle_operator_tpu.ops import attention as A
 from paddle_operator_tpu.ops import pallas_attention as PA
@@ -160,7 +160,7 @@ def test_logits_of_every_real_row(request, n_rep, width):
 
     def both():
         cache = PG.init_paged_cache(cfg, SLOTS, pool.total, BS)
-        paged, _ = jax.jit(lambda t: D.paged_prefill(
+        paged, _ = jax.jit(lambda t: PG.paged_prefill(
             params, cfg, t, cache, row, block_size=BS))(prompt)
         last, lane = jax.jit(lambda t: D.prefill(params, cfg, t, width))(
             prompt)

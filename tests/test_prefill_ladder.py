@@ -12,15 +12,18 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_operator_tpu.infer import decode as D
 from paddle_operator_tpu.infer import serve
-from paddle_operator_tpu.infer.batcher import (
-    ContinuousBatcher,
+from paddle_operator_tpu.infer.executor import (
     PrefillExecutor,
     RingExecutor,
     _default_buckets,
 )
-from paddle_operator_tpu.infer.paged import TRASH_BLOCK, init_paged_cache
+from paddle_operator_tpu.infer.paged import (
+    TRASH_BLOCK,
+    init_paged_cache,
+    paged_prefill,
+)
+from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 from paddle_operator_tpu.models.llama import make_model
 
 MAX_LEN = 512
@@ -121,8 +124,8 @@ class TestEveryRungSameArithmetic:
         @jax.jit
         def prefill(tokens):
             cache = init_paged_cache(cfg, 2, ring.pool.total, BS)
-            return D.paged_prefill(params, cfg, tokens, cache,
-                                   jnp.asarray(row), block_size=BS)
+            return paged_prefill(params, cfg, tokens, cache,
+                                 jnp.asarray(row), block_size=BS)
 
         def through(width):
             padded = np.zeros((1, width), np.int32)

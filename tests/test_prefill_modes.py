@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
-from paddle_operator_tpu.infer.batcher import ContinuousBatcher
 from paddle_operator_tpu.infer.chaos import ChaosEvent, ChaosInjector
 from paddle_operator_tpu.infer.resilience import (
     LaneQuarantined,
@@ -37,6 +36,7 @@ from paddle_operator_tpu.infer.resilience import (
     RingResilience,
     ShuttingDown,
 )
+from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 from paddle_operator_tpu.models.llama import make_model
 
 MAX_LEN = 64

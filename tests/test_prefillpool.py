@@ -471,7 +471,7 @@ class TestEnginePearity:
         import jax.numpy as jnp
 
         from paddle_operator_tpu.infer import decode as ID
-        from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+        from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
         params, cfg = _tiny()
         new = 6
@@ -512,7 +512,7 @@ class TestPrefillPoolMatrix:
         import jax
         import jax.numpy as jnp
 
-        from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+        from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
         from paddle_operator_tpu.infer.prefill_serve import (
             RemotePrefillClient,
             make_prefill_server,
@@ -568,7 +568,7 @@ class TestPrefillPoolMatrix:
         import jax.numpy as jnp
 
         from paddle_operator_tpu.infer import decode as ID
-        from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+        from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
         params, cfg = _tiny()
         new = 6

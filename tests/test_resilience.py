@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
-from paddle_operator_tpu.infer.batcher import ContinuousBatcher
 from paddle_operator_tpu.infer.chaos import (
     ChaosEvent,
     ChaosInjector,
@@ -36,6 +35,7 @@ from paddle_operator_tpu.infer.resilience import (
     ServingDrain,
     ShuttingDown,
 )
+from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 from paddle_operator_tpu.models.llama import make_model
 
 MAX_LEN = 64

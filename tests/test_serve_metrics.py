@@ -1,5 +1,5 @@
 """Serving telemetry: the ``status.serving`` block
-(infer/batcher.py ContinuousBatcher.serving_status) plumbed through the
+(infer/scheduler.py ContinuousBatcher.serving_status) plumbed through the
 CRD status, preserved by the reconciler's status sync, and exported by
 the manager as ``tpujob_serve_*`` gauges on /metrics — the speculative
 acceptance rate, served-token throughput, and queue depth next to the
@@ -382,7 +382,7 @@ class TestBatcherServingStatus:
         import jax
         import jax.numpy as jnp
 
-        from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+        from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
         from paddle_operator_tpu.models.llama import make_model
 
         model, cfg = make_model("tiny", dtype=jnp.float32)
@@ -505,7 +505,7 @@ class TestBatcherServingStatus:
         import jax
         import jax.numpy as jnp
 
-        from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+        from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
         from paddle_operator_tpu.models.llama import make_model
 
         model, cfg = make_model("tiny", dtype=jnp.float32)
@@ -529,7 +529,7 @@ class TestBatcherServingStatus:
         import jax
         import jax.numpy as jnp
 
-        from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+        from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
         from paddle_operator_tpu.models.llama import make_model
 
         model, cfg = make_model("tiny", dtype=jnp.float32)

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
-from paddle_operator_tpu.infer.batcher import ContinuousBatcher, QueueFull
+from paddle_operator_tpu.infer.scheduler import ContinuousBatcher, QueueFull
 from paddle_operator_tpu.infer.speculative import (
     check_draft_compat,
     speculative_generate,

@@ -674,7 +674,7 @@ def tiny():
 
 
 def _ring(cfg, params, **kw):
-    from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+    from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 
     kw.setdefault("slots", 2)
     kw.setdefault("max_len", 64)

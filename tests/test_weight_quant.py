@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
 from paddle_operator_tpu.infer import quant as Q
-from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
 from paddle_operator_tpu.models.llama import Llama, make_model
 
 MAX_LEN = 64

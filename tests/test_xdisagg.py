@@ -423,7 +423,7 @@ class TestRemoteParity:
     def test_remote_equals_in_process(self, kv_quant):
         import jax
 
-        from paddle_operator_tpu.infer.batcher import ContinuousBatcher
+        from paddle_operator_tpu.infer.scheduler import ContinuousBatcher
         from paddle_operator_tpu.infer.prefill_serve import (
             RemotePrefillClient,
             make_prefill_server,
