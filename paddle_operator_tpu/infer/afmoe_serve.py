@@ -149,19 +149,23 @@ def paged_ring_forward(cfg: AfmoeConfig, params, tok: jax.Array, cache,
     pos = cache["pos"]
     x = M.embed(cfg, params, tok[:, None])
     cos, sin = M.rope_tables(cfg)
-    view = PG.PagedView(cfg, cache, table)
+    view = PG.PagedView(cfg, cache, table, lane_mask=active)
     view.enter(1)
     nd = cfg.n_dense_layers
     windows, ropes = cfg.windows(), cfg.ropes()
     counted = active[:, None]
     scanned, experts = M.split_experts(params["moe_layers"])
+    lists = None
+    if view.kernel:   # the tick's work lists: one a kind of layer
+        kinds = {w: view.cells(w) for w in set(windows)}
+        lists = [kinds[w] for w in windows]
 
-    def block(lp, li, x, bufs, window, use_rope, moe_layer=None):
+    def block(lp, li, x, bufs, window, use_rope, cells, moe_layer=None):
         q, k, v, g = M.attn_inputs(cfg, lp, x, cos, sin, pos[:, None],
                                    use_rope)
         bufs = view.write(bufs, li, k, v)
         if view.kernel:
-            att = view.kernel_attend(bufs, li, q, window=window)
+            att = view.kernel_attend(bufs, li, q, cells=cells)
         else:
             att = M.attend(cfg, q, *view.lanes(bufs, li), pos[:, None],
                            window)
@@ -174,19 +178,22 @@ def paged_ring_forward(cfg: AfmoeConfig, params, tok: jax.Array, cache,
     bufs = (cache["k"], cache["v"])
     for i in range(nd):
         x, bufs, _ = block(M.layer_at(params["dense_layers"], i),
-                           jnp.int32(i), x, bufs, windows[i], ropes[i])
+                           jnp.int32(i), x, bufs, windows[i], ropes[i],
+                           lists and lists[i])
 
     def body(carry, layer_in):
         x, bufs = carry
-        lp, li, window, use_rope = layer_in
-        y, bufs, load = block(lp, li, x, bufs, window, use_rope, li - nd)
+        lp, li, window, use_rope, cells = layer_in
+        y, bufs, load = block(lp, li, x, bufs, window, use_rope, cells,
+                              li - nd)
         return (y, bufs), load
 
     (x, (kc, vc)), loads = jax.lax.scan(
         body, (x, bufs),
         (scanned, jnp.arange(nd, cfg.n_layers),
          jnp.asarray(windows[nd:], jnp.int32),
-         jnp.asarray(ropes[nd:], bool)))
+         jnp.asarray(ropes[nd:], bool),
+         lists and jax.tree.map(lambda *c: jnp.stack(c), *lists[nd:])))
     new_cache = dict(cache, k=kc, v=vc, pos=pos + 1)
     return (M.lm_head(cfg, params, x)[:, 0], new_cache, loads.sum(0),
             jnp.sum(loads > 0))

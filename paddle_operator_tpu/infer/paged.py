@@ -25,10 +25,10 @@ Zheng et al. 2024) in this codebase's TPU-native terms:
   prefix of a cached block maps that block too (zero prefill beyond the
   mandatory last-token forward) and is **copied-on-write** before the
   lane's first write lands in it.
-- **Kernel/fallback split**: on TPU the pallas decode kernel walks the
-  block table through its *index map*
-  (ops/decode_attention.py ``paged_decode_attention`` — blocks stream
-  straight from their pool rows, dead tails skipped); the XLA einsum
+- **Kernel/fallback split**: on TPU the pallas decode kernel steps over
+  a list of the lanes' live blocks, read from the block table once a
+  tick (ops/decode_attention.py ``paged_decode_attention`` — blocks
+  stream straight from their pool rows); the XLA einsum
   path gathers the lane view with one ``take`` per layer
   (:func:`_gather_lane_view`) — the copy the kernel exists to avoid,
   kept as the CPU/odd-shape fallback.
@@ -85,7 +85,7 @@ TRASH_BLOCK = 0
 # parity oracle — byte-identical to pre-quantization behavior); "int8"
 # stores pool blocks as int8 codes + one f32 scale per (layer, block,
 # kv-head), with dequant fused into the paged kernels
-# (ops/decode_attention.py _paged_kernel_quant) / the gather view.
+# (ops/decode_attention.py _cells_kernel) / the gather view.
 # The win is CAPACITY, not kernel latency: ~2x resident lanes per HBM
 # byte, with a bounded per-step regression (the decode_attention.py
 # header has the v5e physics; bench.py measure_quantized_pool the
@@ -358,6 +358,17 @@ class PagedCacheManager:
 
     def blocks_free(self) -> int:
         return len(self.free)
+
+    def decode_cell_counts(self, lane_pos, active) -> Tuple[int, int]:
+        """``(live, grid)`` of one decode step's kernel call a layer:
+        the (lane, block) cells its work list holds
+        (ops/decode_attention.py ``decode_cells``: an active lane at
+        position p attends p + 1 rows, ``ceil((p + 1) / block)`` cells;
+        every other lane keeps one) against the ``lanes x max_blocks``
+        rectangle the grid used to step through."""
+        live = sum(-(-(int(p) + 1) // self.bs) if i in active else 1
+                   for i, p in enumerate(lane_pos))
+        return live, len(lane_pos) * self.max_blocks
 
     def blocks_cached(self) -> int:
         """DEVICE-resident cached blocks currently reclaimable
@@ -1358,8 +1369,10 @@ class PagedView:
       lands through ``_write_token_paged`` and, where the kernel is on,
       ``paged_decode_attention`` streams the table-mapped blocks (under
       a serving mesh sharded, the output projection inside its manual
-      region).  An inactive lane needs no mask here: its zeroed table
-      row already sends its row to the trash block.
+      region) over the tick's work list (:meth:`cells`, built once
+      outside the layer scan).  An inactive lane's write needs no mask
+      here: its zeroed table row already sends its row to the trash
+      block.
     - Otherwise (speculative verify, suffix insert, prefill slices) rows
       land wherever the table maps their absolute position — those at
       or past ``limit`` [B] (pads) in the trash block; whole blocks at
@@ -1368,9 +1381,11 @@ class PagedView:
       the per-row unroll is pathological to COMPILE) — and the einsum
       attends over the gathered lane view (:meth:`lanes`).
 
-    ``lane_mask`` is the int8 pool's (:class:`PagedQuantView`); this
-    view takes and ignores it so callers need not know the format
-    (:func:`paged_view`)."""
+    ``lane_mask`` [B] (the step's ``active``): a masked lane attends
+    nothing through the kernel — one cell of the list that fetches no
+    block and writes zeros (its token is discarded by the step) — and
+    under the int8 pool its rows go to the trash tail
+    (:class:`PagedQuantView`)."""
 
     stacked = True
 
@@ -1393,8 +1408,25 @@ class PagedView:
 
     def begin(self, t: int):
         self.enter(t)
+        self.step_cells = self.cells() if self.kernel else None
         return ((self.cache["k"], self.cache["v"]),
                 jnp.arange(self.cfg.n_layers))
+
+    def cells(self, window=None):
+        """The decode kernel's work list for this tick (ops/
+        decode_attention.py ``decode_cells``): positions and table are
+        the same for every layer, so it is built once, outside the layer
+        loop — one list a ``window`` (a sliding layer attends its last
+        ``window`` positions and the list leaves out the blocks wholly
+        before them)."""
+        from paddle_operator_tpu.ops.decode_attention import decode_cells
+
+        lengths = self.pos + 1
+        starts = (None if window is None
+                  else jnp.maximum(lengths - window, 0))
+        if self.lane_mask is not None:
+            lengths = jnp.where(self.lane_mask, lengths, 0)
+        return decode_cells(self.table, lengths, self.block_size, starts)
 
     def write(self, bufs, li, k: jax.Array, v: jax.Array):
         kc, vc = bufs
@@ -1423,27 +1455,26 @@ class PagedView:
     def _kernel_operands(self, bufs) -> Dict[str, jax.Array]:
         return {}
 
-    def kernel_attend(self, bufs, li, q: jax.Array, wo=None, window=None):
+    def kernel_attend(self, bufs, li, q: jax.Array, wo=None, cells=None):
         """The decode kernel over the table-mapped blocks: ``q``
         [B, 1, Hq, D] -> [B, 1, Hq*D], or (``projects``) the residual
-        [B, dim] already through ``wo``.  ``window`` (a layer's sliding
-        window, tp 1): the lane attends its last ``window`` positions
-        and the kernel skips the blocks wholly before them."""
+        [B, dim] already through ``wo``.  ``cells``: the layer's work
+        list (:meth:`cells`; a stack with window layers holds one a
+        window, tp 1); absent, the one :meth:`begin` built."""
         from paddle_operator_tpu.ops.decode_attention import (
             paged_decode_attention,
             sharded_paged_decode_attention,
         )
 
         kc, vc = bufs[:2]
+        cells = self.step_cells if cells is None else cells
         if self.projects:
             return sharded_paged_decode_attention(
-                self.mesh, q[:, 0], kc, vc, self.table, self.pos + 1, wo,
-                layer=li, interpret=self.interpret,
+                self.mesh, q[:, 0], kc, vc, self.table, None, wo,
+                layer=li, interpret=self.interpret, cells=cells,
                 compute_dtype=self.cfg.dtype, **self._kernel_operands(bufs))
         out = paged_decode_attention(
-            q[:, 0], kc, vc, self.table, self.pos + 1, layer=li,
-            starts=(None if window is None
-                    else jnp.maximum(self.pos + 1 - window, 0)),
+            q[:, 0], kc, vc, self.table, layer=li, cells=cells,
             interpret=self.interpret, **self._kernel_operands(bufs))
         return out.reshape(q.shape[0], 1, -1).astype(self.cfg.dtype)
 
@@ -1475,6 +1506,7 @@ class PagedQuantView(PagedView):
 
     def begin(self, t: int):
         self.enter(t)
+        self.step_cells = self.cells() if self.kernel else None
         c = self.cache
         if self.step:
             trash_row = c["kt"].shape[1] - 1

@@ -551,6 +551,11 @@ class ContinuousBatcher:
                       "prefill_bucket_tokens": 0,
                       "prefill_calls_by_bucket": {},
                       "decode_steps": 0, "decode_lane_steps": 0,
+                      # the paged decode kernel's work list against the
+                      # lanes x blocks rectangle, a layer's call of each
+                      # decode iteration (positions as the host holds
+                      # them at the dispatch)
+                      "decode_cells_live": 0, "decode_cells_grid": 0,
                       # routing counters of an expert architecture
                       # (infer/afmoe_serve.py): computed on the device,
                       # added up as each dispatch's results are consumed
@@ -1081,13 +1086,16 @@ class ContinuousBatcher:
             # dispatched and never a ratio (a reader takes the
             # difference between two scrapes): decode dispatches, the
             # device decode iterations they ran, those times the lanes
-            # live in each plan; insert programs dispatched, the real
-            # tokens they prefilled and the positions they computed
+            # live in each plan, and the paged decode kernel's cells
+            # (live, and the rectangle's); insert programs dispatched,
+            # the real tokens they prefilled and the positions they computed
             # (the program's width), by width; and the loop thread's
             # self seconds and counts by phase
             "dispatchesTotal": self.stats["chunks"],
             "decodeStepsTotal": self.stats["decode_steps"],
             "decodeLaneStepsTotal": self.stats["decode_lane_steps"],
+            "decodeCellsLive": self.stats["decode_cells_live"],
+            "decodeCellsGrid": self.stats["decode_cells_grid"],
             "prefillCallsTotal": self.stats["prefill_calls"],
             "prefillTokensTotal": pf_tok,
             "prefillBucketTokensTotal":
@@ -3264,8 +3272,13 @@ class ContinuousBatcher:
             # a synchronous-dispatch backend) wedges HERE — and any
             # raise becomes a ring fault handled at the loop top (fail
             # resident requests retriably, rebuild, back off).
+            cells_live = cells_grid = 0
+            if self.paged and not self.spec_k:
+                cells_live, cells_grid = self.pool.decode_cell_counts(
+                    self._lane_pos, set(active_idx))
             ph = tile.to("exec.dispatch", n_steps=n_mega,
-                         lanes_live=len(active_idx))
+                         lanes_live=len(active_idx), cells_live=cells_live,
+                         cells_grid=cells_grid)
             wd = self._watchdog
             if wd is not None:
                 wd.begin(scale=n_mega)
@@ -3284,6 +3297,8 @@ class ContinuousBatcher:
             self.stats["decode_steps"] += n_mega * advance
             self.stats["decode_lane_steps"] += (
                 n_mega * advance * len(active_idx))
+            self.stats["decode_cells_live"] += n_mega * advance * cells_live
+            self.stats["decode_cells_grid"] += n_mega * advance * cells_grid
             # kick the device->host copy NOW, before the consume wait:
             # by consume time the tokens are already on the wire and
             # np.asarray is a cheap completion wait instead of a full

@@ -1,47 +1,52 @@
 """Single-query (decode) attention over the KV cache — pallas TPU kernel.
 
-VERDICT r3 item 3 / r4 item 1: training attention is a tuned flash
-kernel (ops/pallas_attention.py) but decode ran XLA einsums over the
-FULL cache.  At serving-realistic contexts the decode hot loop is bound
-by reading the KV cache from HBM, and the XLA path reads all ``max_len``
-allocated positions every step no matter how few are filled.
+At serving contexts the decode hot loop is bound by reading the KV cache
+from HBM, and an XLA einsum reads all ``max_len`` allocated positions
+every step no matter how few are filled.  This kernel makes decode cost
+proportional to the FILLED context:
 
-This kernel makes decode cost proportional to the FILLED context:
-
-- **Grid** ``(B, key-blocks)`` with the per-lane fill length as a
-  scalar-prefetch operand, so the kernel's *index map* — not just its
-  compute — depends on it: key blocks past the lane's fill length are
-  remapped to the last live block.  Pallas/Mosaic skips the DMA when a
-  block window repeats, so unfilled cache tail blocks are never fetched
-  — the bandwidth win XLA cannot express with a dense einsum (it would
-  need dynamic shapes).
+- **Grid: a list of live cells (paged cache), a rectangle (contiguous
+  cache).**  A call's work is the set of (lane, key-block) cells that
+  hold attended positions.  :func:`paged_decode_attention` steps a
+  ONE-dimensional grid over exactly those cells: :func:`decode_cells`
+  lists them once a tick from the lanes' lengths, the block table and a
+  layer's window — lane, pool block, block index, lane bounds,
+  first/last-of-lane flags — the list rides in as a scalar-prefetch
+  operand that the index maps read, and its length is the grid's
+  run-time bound (a dynamic grid dimension).  A call therefore costs
+  what its lanes hold: at the serving cells' fill, a fifth of the pool,
+  82.5 us against the rectangle's 127.7 (16 lanes, 10,790 context
+  tokens in 51 blocks of 1 MB, 8 kv heads; 65 us is 51 whole blocks at
+  819 GB/s), 40.5 against 90.7 with 9 of the 16 lanes masked out of the
+  step, and at full fill (16 x 4,096: the list IS the rectangle) 389.6
+  against 388.2 — 1.5 us a 1 MB cell, 690 GB/s, 84 % of the chip's
+  peak; with 4 kv heads a cell is 0.5 MB and costs 1.1 us, bound by
+  the cell's compute and the grid step rather than its bytes (v5e,
+  isolated differenced timing, PERF.md section 6, PR 31).  The
+  rectangle ``(B, key-blocks)`` — cells past a lane's fill remapped to
+  its last live block, so Mosaic skips their DMA, and their compute
+  skipped, but each still a grid step of 0.3 us — is what the paged
+  kernel ran before and what the contiguous cache's
+  :func:`decode_attention` still runs (no benchmark cell reaches it).
+  The same list driven by a loop inside the kernel (grid ``(B,)``,
+  ``fori_loop`` over the lane's cells, two-slot manual DMA from the
+  pool in ``pl.ANY``) measured slower at every fill: 97.0, 42.9 and
+  471.5 us.
 - **Head-major cache layout** ``[B, H_kv, S, D]`` (the decode caches
   are stored this way, infer/decode.py init_cache): each grid cell
-  reads one CONTIGUOUS ``[hkv * block_k, D]`` tile.  The token-major
-  layout was measured 0.64x vs XLA at long fill — Mosaic relayouts
-  every strided per-head slice; head-major makes the block the natural
-  DMA unit.
-- **Block-contraction matmuls, not per-head matvecs.**  The r4 kernel
-  unrolled hkv per-head dots of shape [n_rep, D] x [D, block_k]; with
-  n_rep 1-4 those are matvecs that leave the MXU pipeline idle, and 16
-  of them per cell serialized into ~16us of compute against a 2.5us
-  block DMA — the kernel sat at ~225 GB/s, 0.32-0.47x XLA at high fill
-  (measured r5, isolated differenced timing).  This version contracts
-  over the BLOCK dimension instead: the whole cell's scores are ONE
-  ``[hkv*bk, d] @ [d, hq]`` matmul against every head's query (the
-  cross-head products are masked off — MXU flops are free next to the
-  HBM stream), and the output is ONE ``[hq, hkv*bk] @ [hkv*bk, d]``
-  matmul of the head-masked probabilities against the V tile, with the
-  softmax bookkeeping kept in the transposed [hq, rows] layout (hq ~16
-  as the lane dim wastes 7/8 of every vreg).  Per-cell compute drops
-  ~8x and the kernel runs at the DMA roofline; measured isolated (v5e,
-  B=8..64, S 2048/2304, differenced device timing) it streams 720-760
-  GB/s vs the einsum's 540-720 at full fill, and wins 2.7-14x at
-  ring-regime sparse fills where the dead-block DMA skip compounds.
-  Model-level (dim-2048/L8, bf16 weights): 1.6x tokens/s at b8 short
-  cache, 4.5x at b64, 2.6x at prompt 2048, 4.8x in the 6%-filled ring
-  regime — decode HBM utilization 0.54-0.83 vs 0.17-0.49 for the
-  einsum path.
+  reads one CONTIGUOUS ``[hkv * block_k, D]`` tile; token-major would
+  make Mosaic relayout every strided per-head slice.
+- **Block-contraction matmuls, not per-head matvecs.**  Per-head dots
+  of shape [n_rep, D] x [D, block_k] with n_rep 1-4 are matvecs that
+  leave the MXU pipeline idle.  The cell contracts over the BLOCK
+  dimension instead: its scores are ONE ``[hkv*bk, d] @ [d, hq]``
+  matmul against every head's query (the cross-head products are masked
+  off — MXU flops are free next to the HBM stream), and the output is
+  ONE ``[hq, hkv*bk] @ [hkv*bk, d]`` matmul of the head-masked
+  probabilities against the V tile, with the softmax bookkeeping kept
+  in the transposed [hq, rows] layout (hq ~16-32 as the lane dim would
+  waste most of every vreg), so that a 1 MB cell's compute hides under
+  its DMA.
 - **Online softmax** accumulation in f32 VMEM scratch, cache tiles read
   in storage dtype (bf16 native MXU rate); masking folds the causal/
   fill bound AND the head-match predicate into one -inf write.
@@ -93,7 +98,7 @@ not an error.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -148,14 +153,29 @@ def _cell_softmax(qt, k2, v2, ik, length, scale, block_k, n_rep,
         preferred_element_type=jnp.float32)
 
 
+def _init_softmax(acc_ref, m_ref, l_ref):
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _finish_softmax(o_ref, acc_ref, m_ref, l_ref):
+    # a lane with nothing to attend (length 0: an idle or masked ring
+    # lane): no cell computed, l == 0 — emit zeros rather than 0/0
+    l = l_ref[:, 0]
+    o = acc_ref[:] / jnp.where(l == 0.0, 1.0, l)[:, None]
+    o_ref[0] = jnp.where(m_ref[:, 0][:, None] <= NEG_INF / 2, 0.0,
+                         o).astype(o_ref.dtype)
+
+
 def _kernel(len_ref, *refs, scale: float, block_k: int, n_rep: int,
-            stacked: bool, windowed: bool = False):
+            stacked: bool):
+    """The contiguous cache's body, on the rectangular grid ``(B,
+    key-blocks)``: blocks at/after the fill boundary were index-remapped
+    to the last live block (no new DMA) and their compute is skipped —
+    but the grid still steps through them (the paged kernel's list,
+    :func:`decode_cells`, is what removes those steps)."""
     b = pl.program_id(0)
-    start = None
-    if windowed:      # the LAST scalar-prefetch ref: lane b attends
-        n_pf = 2 if stacked else 1          # positions [start_b, length_b)
-        start = refs[n_pf - 1][b]
-        refs = refs[:n_pf - 1] + refs[n_pf:]
     if stacked:       # extra scalar-prefetch ref (layer index, unused
         _lay, qt_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
         k_ref, v_ref = k_ref.at[0], v_ref.at[0]   # in body; maps use it)
@@ -163,43 +183,25 @@ def _kernel(len_ref, *refs, scale: float, block_k: int, n_rep: int,
         qt_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
     ik, nk = pl.program_id(1), pl.num_programs(1)
     length = len_ref[b]
-    hkv = k_ref.shape[1]
-    hq = qt_ref.shape[2]
-    rows = hkv * block_k
+    rows = k_ref.shape[1] * block_k
 
     @pl.when(ik == 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        _init_softmax(acc_ref, m_ref, l_ref)
 
-    # Blocks at/after the fill boundary were index-remapped to the last
-    # live block (no new DMA); their compute is skipped outright.  So
-    # are, on a sliding-window layer, the blocks wholly before ``start``
-    # (remapped to the first live block).
-    live_cell = ik * block_k < length
-    if windowed:
-        live_cell = live_cell & ((ik + 1) * block_k > start)
-
-    @pl.when(live_cell)
+    @pl.when(ik * block_k < length)
     def _compute():
         # the cell's whole K/V tile as one 2D matrix; rows are
         # (head-major) h*block_k + s — a pure leading-dim collapse of
         # the contiguous [hkv, block_k, d] window, no relayout
         k2 = k_ref[0].reshape(rows, -1)              # [hkv*bk, d]
         v2 = v_ref[0].reshape(rows, -1)
-        qt = qt_ref[0]                               # [d, hq]
-        _cell_softmax(qt, k2, v2, ik, length, scale, block_k, n_rep,
-                      acc_ref, m_ref, l_ref, start=start)
+        _cell_softmax(qt_ref[0], k2, v2, ik, length, scale, block_k, n_rep,
+                      acc_ref, m_ref, l_ref)
 
     @pl.when(ik == nk - 1)
     def _finish():
-        # length == 0 (an idle ring lane): every block skipped, l == 0 —
-        # emit zeros rather than 0/0
-        l = l_ref[:, 0]
-        o = acc_ref[:] / jnp.where(l == 0.0, 1.0, l)[:, None]
-        o_ref[0] = jnp.where(m_ref[:, 0][:, None] <= NEG_INF / 2, 0.0,
-                             o).astype(o_ref.dtype)
+        _finish_softmax(o_ref, acc_ref, m_ref, l_ref)
 
 
 @jax.named_scope("attn.kernel")
@@ -293,93 +295,182 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return out
 
 
-def _paged_kernel(len_ref, tbl_ref, *refs, scale: float, block_k: int,
-                  n_rep: int, stacked: bool, windowed: bool = False):
-    """Paged-cache kernel body: identical compute to :func:`_kernel` —
-    the block table participates only through the *index maps* (each
-    grid cell's K/V window is looked up in ``tbl_ref`` instead of being
-    ``ik`` itself), so the online-softmax/bandwidth story is unchanged.
-    ``tbl_ref`` rides as one more scalar-prefetch operand that the body
-    never reads."""
-    del tbl_ref
-    _kernel(len_ref, *refs, scale=scale, block_k=block_k, n_rep=n_rep,
-            stacked=stacked, windowed=windowed)
+# The work list's rows: one column a live (lane, block) cell.
+CELL_LANE, CELL_BLOCK, CELL_INDEX, CELL_FLAGS, CELL_LEN, CELL_START = range(6)
+CELL_FIRST, CELL_LAST = 1, 2      # CELL_FLAGS bits: a lane's first/last cell
 
 
-def _paged_kernel_quant(len_ref, tbl_ref, *refs, scale: float,
-                        block_k: int, n_rep: int, stacked: bool):
-    """Paged kernel over the INT8 pool with the dequant fused into the
-    cell (SERVE_KV_QUANT=int8, infer/paged.py): the K/V tiles stream
-    from HBM as int8 codes (half the bytes of the bf16 kernel — the
-    capacity story in the module header), the lane's per-(block,
-    kv-head) f32 scales sit in SMEM (gathered through the block table
-    by the wrapper — a ``[1, hkv]`` VMEM window per pool block is a
-    shape Mosaic's (8, 128) tiling refuses), and the lane's bf16
-    staging tail (the one partial write block, quantized only on
-    completion) substitutes for the cell at the write frontier — so
-    full blocks are read quantized and the in-progress block is read
-    exact, matching the einsum fallback's view (infer/paged.py
-    ``_gather_lane_view_quant``) element for element.  Either way the
-    cell's tile lands in a compute-dtype VMEM scratch; compute after
-    that is byte-for-byte :func:`_cell_softmax`."""
-    del tbl_ref
-    if stacked:
-        (_lay, qt_ref, k_ref, v_ref, ks_ref, vs_ref, kt_ref, vt_ref,
-         o_ref, acc_ref, m_ref, l_ref, kd_ref, vd_ref) = refs
-        k_ref, v_ref = k_ref.at[0], v_ref.at[0]
-        kt_ref, vt_ref = kt_ref.at[0], vt_ref.at[0]
-    else:
+class DecodeCells(NamedTuple):
+    """The paged kernel's grid as a list (:func:`decode_cells`): ``rows``
+    [5, B*M] int32 (6 with a window: ``CELL_START``), of which the first
+    ``n`` columns are the cells the grid steps over."""
+    rows: jax.Array
+    n: jax.Array
+
+
+def decode_cells(block_table: jax.Array, lengths: jax.Array, block_k: int,
+                 starts: Optional[jax.Array] = None) -> DecodeCells:
+    """The (lane, block) cells a decode call has to visit, lane after
+    lane and block after block — what the kernel's one-dimensional grid
+    steps over in place of the whole ``B x M`` rectangle.
+
+    block_table [B, M] pool ids, lengths [B]: lane b attends positions
+    ``[starts[b], lengths[b])`` (``starts`` absent: from 0), which lie in
+    its blocks ``[starts[b] // block_k, ceil(lengths[b] / block_k))``.
+    Each such block is one cell: its lane, its pool id (the table is
+    read here, so the kernel needs none), its index in the lane (the
+    mask's positions), the lane's bounds, and whether it opens or closes
+    the lane (accumulators reset / output written).  A lane with nothing
+    to attend (length 0: idle, or masked out of the step) still owns an
+    output row, so it keeps ONE cell, which computes nothing and writes
+    zeros; its pool id repeats the cell before it (the first real cell's,
+    ahead of any), so no block is fetched for it.
+
+    A few integer operations over ``B*M`` elements: lengths and table
+    hold for every layer of a tick, so a caller with a layer loop builds
+    the list once outside it (infer/paged.py ``PagedView.cells``)."""
+    b, m = block_table.shape
+    table = block_table.astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
+    end = (lengths + block_k - 1) // block_k
+    lo = (jnp.zeros_like(end) if starts is None
+          else jnp.minimum(starts.astype(jnp.int32) // block_k, end))
+    has = end > lo                                     # [B]: any cell
+    n = jnp.maximum(end - lo, 1)
+    # Written as masks and sums over [B, B], [C, B] and [C, B, M] so that
+    # XLA fuses it into a few operations: a cumulative sum, a search and
+    # gathers of this size would each cost a launch of their own.
+    lanes = jnp.arange(b, dtype=jnp.int32)
+    blocks = jnp.arange(m, dtype=jnp.int32)
+    c = jnp.arange(b * m, dtype=jnp.int32)
+    before = lanes[None, :] < lanes[:, None]           # [b, b']: b' < b
+    off = jnp.sum(jnp.where(before, n[None, :], 0), axis=1)
+    own = ((c[:, None] >= off[None, :])                # [C, B]: the cell's
+           & (c[:, None] < (off + n)[None, :]))        # lane, one-hot
+
+    def of_lane(x):
+        return jnp.sum(jnp.where(own, x[None, :], 0), axis=1)
+
+    def entry(at):      # [B]: table[b, at[b]] (0 where at[b] is outside)
+        return jnp.sum(jnp.where(blocks[None, :] == at[:, None], table, 0),
+                       axis=1)
+
+    j = c - of_lane(off)                               # cell within lane
+    index = of_lane(lo) + j
+    block = jnp.sum(jnp.where(
+        own[:, :, None] & (blocks[None, None, :] == index[:, None, None]),
+        table[None], 0), axis=(1, 2))
+    # a lane with no cell of its own repeats the last block of the
+    # nearest lane before it that has one, else the first lane's first
+    prev = jnp.max(jnp.where(before & has[None, :], lanes[None, :], -1),
+                   axis=1)
+    first = jnp.min(jnp.where(has, lanes, b))
+    fill = jnp.where(
+        prev >= 0,
+        jnp.sum(jnp.where(lanes[None, :] == prev[:, None],
+                          entry(end - 1)[None, :], 0), axis=1),
+        jnp.sum(jnp.where(lanes == first, entry(lo), 0)))
+    block = jnp.where(of_lane(has.astype(jnp.int32)) > 0, block,
+                      of_lane(fill))
+    flags = (jnp.where(j == 0, CELL_FIRST, 0)
+             | jnp.where(j == of_lane(n) - 1, CELL_LAST, 0))
+    rows = [of_lane(lanes), block, index, flags, of_lane(lengths)]
+    if starts is not None:
+        rows.append(of_lane(starts.astype(jnp.int32)))
+    return DecodeCells(jnp.stack(rows), jnp.sum(n))
+
+
+def _cells_kernel(cells_ref, *refs, scale: float, block_k: int, n_rep: int,
+                  stacked: bool, quant: bool):
+    """The paged kernel's body over grid step ``c`` of the work list:
+    cell ``c``'s K/V tile is already the window (the index maps read the
+    list), so the body takes the cell's place in its lane from the list
+    too and is :func:`_kernel`'s compute — ``_cell_softmax`` over the
+    same blocks of a lane in the same order, the accumulators reset on a
+    lane's first cell and the output written on its last.
+
+    ``quant``: the INT8 pool with the dequant fused into the cell
+    (SERVE_KV_QUANT=int8, infer/paged.py): the K/V tiles stream from HBM
+    as int8 codes (half the bytes of the bf16 kernel — the capacity
+    story in the module header), the lane's per-(block, kv-head) f32
+    scales sit in SMEM (gathered through the block table by the wrapper
+    — a ``[1, hkv]`` VMEM window per pool block is a shape Mosaic's
+    (8, 128) tiling refuses), and the lane's bf16 staging tail (the one
+    partial write block, quantized only on completion) substitutes for
+    the cell at the write frontier — so full blocks are read quantized
+    and the in-progress block is read exact, matching the einsum
+    fallback's view (infer/paged.py ``_gather_lane_view_quant``) element
+    for element.  Either way the cell's tile lands in a compute-dtype
+    VMEM scratch; compute after that is byte-for-byte
+    :func:`_cell_softmax`."""
+    if stacked:       # extra scalar-prefetch ref (layer index, unused
+        refs = refs[1:]                           # in body; maps use it)
+    if quant:
         (qt_ref, k_ref, v_ref, ks_ref, vs_ref, kt_ref, vt_ref,
          o_ref, acc_ref, m_ref, l_ref, kd_ref, vd_ref) = refs
-    b = pl.program_id(0)
-    ik, nk = pl.program_id(1), pl.num_programs(1)
-    length = len_ref[b]
+    else:
+        qt_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    if stacked:
+        k_ref, v_ref = k_ref.at[0], v_ref.at[0]
+        if quant:
+            kt_ref, vt_ref = kt_ref.at[0], vt_ref.at[0]
+    c = pl.program_id(0)
+    ik, flags = cells_ref[CELL_INDEX, c], cells_ref[CELL_FLAGS, c]
+    length = cells_ref[CELL_LEN, c]
+    start = None
+    if cells_ref.shape[0] > CELL_START:     # a sliding-window layer: the
+        start = cells_ref[CELL_START, c]    # lane attends [start, length)
     hkv = k_ref.shape[1]
     rows = hkv * block_k
 
-    @pl.when(ik == 0)
+    @pl.when((flags & CELL_FIRST) != 0)
     def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        _init_softmax(acc_ref, m_ref, l_ref)
 
+    # every listed cell holds live positions but the one cell of a lane
+    # with nothing to attend
     @pl.when(ik * block_k < length)
     def _compute():
-        # the lane's write-frontier block: its rows live in the bf16
-        # staging tail (quantize-on-completion), not the int8 pool
-        wb = jnp.maximum(length - 1, 0) // block_k
+        if not quant:
+            # the cell's whole K/V tile as one 2D matrix; rows are
+            # (head-major) h*block_k + s — a pure leading-dim collapse
+            # of the contiguous [hkv, block_k, d] window, no relayout
+            k2 = k_ref[0].reshape(rows, -1)              # [hkv*bk, d]
+            v2 = v_ref[0].reshape(rows, -1)
+        else:
+            # the lane's write-frontier block: its rows live in the bf16
+            # staging tail (quantize-on-completion), not the int8 pool
+            wb = jnp.maximum(length - 1, 0) // block_k
 
-        @pl.when(ik == wb)
-        def _tail():
-            kd_ref[...] = kt_ref[0].astype(kd_ref.dtype)
-            vd_ref[...] = vt_ref[0].astype(vd_ref.dtype)
+            @pl.when(ik == wb)
+            def _tail():
+                kd_ref[...] = kt_ref[0].astype(kd_ref.dtype)
+                vd_ref[...] = vt_ref[0].astype(vd_ref.dtype)
 
-        @pl.when(ik != wb)
-        def _dequant():
-            # one scalar scale per head: a static unroll of
-            # [block_k, d] tile x SMEM scalar multiplies
-            for h in range(hkv):
-                kd_ref[h] = (k_ref[0, h].astype(jnp.float32)
-                             * ks_ref[0, ik, h]).astype(kd_ref.dtype)
-                vd_ref[h] = (v_ref[0, h].astype(jnp.float32)
-                             * vs_ref[0, ik, h]).astype(vd_ref.dtype)
+            @pl.when(ik != wb)
+            def _dequant():
+                # one scalar scale per head: a static unroll of
+                # [block_k, d] tile x SMEM scalar multiplies
+                for h in range(hkv):
+                    kd_ref[h] = (k_ref[0, h].astype(jnp.float32)
+                                 * ks_ref[0, ik, h]).astype(kd_ref.dtype)
+                    vd_ref[h] = (v_ref[0, h].astype(jnp.float32)
+                                 * vs_ref[0, ik, h]).astype(vd_ref.dtype)
 
-        _cell_softmax(qt_ref[0], kd_ref[...].reshape(rows, -1),
-                      vd_ref[...].reshape(rows, -1), ik, length, scale,
-                      block_k, n_rep, acc_ref, m_ref, l_ref)
+            k2 = kd_ref[...].reshape(rows, -1)
+            v2 = vd_ref[...].reshape(rows, -1)
+        _cell_softmax(qt_ref[0], k2, v2, ik, length, scale, block_k, n_rep,
+                      acc_ref, m_ref, l_ref, start=start)
 
-    @pl.when(ik == nk - 1)
+    @pl.when((flags & CELL_LAST) != 0)
     def _finish():
-        l = l_ref[:, 0]
-        o = acc_ref[:] / jnp.where(l == 0.0, 1.0, l)[:, None]
-        o_ref[0] = jnp.where(m_ref[:, 0][:, None] <= NEG_INF / 2, 0.0,
-                             o).astype(o_ref.dtype)
+        _finish_softmax(o_ref, acc_ref, m_ref, l_ref)
 
 
 @jax.named_scope("attn.kernel")
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, block_table: jax.Array,
-                           lengths: jax.Array, *,
+                           lengths: Optional[jax.Array] = None, *,
                            scale: Optional[float] = None,
                            layer: Optional[jax.Array] = None,
                            interpret: bool = False,
@@ -387,7 +478,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_scale: Optional[jax.Array] = None,
                            k_tail: Optional[jax.Array] = None,
                            v_tail: Optional[jax.Array] = None,
-                           starts: Optional[jax.Array] = None
+                           starts: Optional[jax.Array] = None,
+                           cells: Optional[DecodeCells] = None
                            ) -> jax.Array:
     """:func:`decode_attention` over a PAGED cache: lane b's context
     lives in pool blocks ``block_table[b, 0..ceil(len_b/bs)-1]`` instead
@@ -400,41 +492,40 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     ignored); lengths: [B] — lane b attends logical positions
     [0, lengths[b]).  Returns [B, Hq, D].
 
-    The pool's block size IS the kernel's key block: the grid stays
-    ``(B, M)`` and the only change from the contiguous kernel is the
-    cache index map — ``ik -> table[b, ik]`` with dead tail blocks
-    clamped to the lane's last live *table entry* (repeated window =>
-    Mosaic skips the DMA, exactly like the contiguous fill clamp).  The
+    The pool's block size IS the kernel's key block, and the grid is
+    ONE dimension over the call's live cells (:func:`decode_cells`; its
+    bound a run-time scalar), not the ``B x M`` rectangle: the K/V index
+    map is ``c -> rows[CELL_BLOCK, c]``, the query's and the output's
+    ``c -> rows[CELL_LANE, c]``, so a call costs what its lanes hold —
+    at full fill the list is the whole rectangle.  The pipeline keeps
+    prefetching the next cell's tile, across lane boundaries too.  The
     gather that the XLA fallback must materialize (infer/paged.py
     ``_gather_lane_view``) never exists here: blocks stream straight
     from their pool rows.
+
+    ``cells``: the list already built from ``block_table``, the lengths
+    and the starts (a layer loop builds it once a tick), in place of
+    ``lengths`` and ``starts``; absent, it is built here from them.
 
     ``k_scale``/``v_scale``/``k_tail``/``v_tail`` (all four together)
     select the QUANTIZED-pool variant (SERVE_KV_QUANT=int8): pools are
     int8 codes, scales are f32 ``[N, Hkv]`` (or ``[L, N, Hkv]``
     stacked), and the tails are the per-lane bf16 staging blocks
     ``[lanes+1, Hkv, bs, D]`` (or stacked with L) whose row ``b``
-    substitutes for lane b's one partial write block — constant-in-ik
-    index map, so Mosaic fetches each lane's tail once and skips the
-    repeat.  The scales are gathered through the block table here
-    (``[B, M, Hkv]``, a few KB) and each lane's slab rides into SMEM,
-    where the cell reads one scalar per head.  Dequant happens in the
-    cell (:func:`_paged_kernel_quant`); HBM streams half the bytes.
+    substitutes for lane b's one partial write block — its index map
+    follows the cell's lane, so Mosaic fetches each lane's tail once and
+    skips the repeat.  The scales are gathered through the block table
+    here (``[B, M, Hkv]``, a few KB) and each lane's slab rides into
+    SMEM, where the cell reads one scalar per head.  Dequant happens in
+    the cell (:func:`_cells_kernel`); HBM streams half the bytes.
 
     ``starts`` [B] (sliding-window layers; bf16 pool only): lane b
-    attends logical positions [starts[b], lengths[b]).  It rides as one
-    more scalar-prefetch operand: the mask drops the positions before
-    it, and the index map clamps blocks wholly before it to the lane's
-    first live block, as it clamps the unfilled tail to the last (a
-    repeated block is not fetched).  Absent, the traced program is the
-    windowless one, operand for operand."""
+    attends logical positions [starts[b], lengths[b]).  The list leaves
+    out the blocks wholly before it and carries it for the mask, which
+    drops the positions before it.  Absent, the traced program is the
+    windowless one."""
     b, hq, d = q.shape
     quant = k_scale is not None
-    windowed = starts is not None
-    if windowed and quant:
-        raise ValueError("a window over the int8 pool is not written "
-                         "(the staging tail's substitution assumes the "
-                         "whole prefix)")
     if quant and (v_scale is None or k_tail is None or v_tail is None):
         raise ValueError("quantized paged attention needs k_scale, "
                          "v_scale, k_tail and v_tail together")
@@ -446,85 +537,63 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         raise ValueError(
             f"paged_decode_attention requires head_dim % 128 == 0 on TPU "
             f"(got {d}); use decode_attn='xla' for this config")
+    if cells is None:
+        cells = decode_cells(block_table, lengths, block_k, starts)
+    if quant and cells.rows.shape[0] > CELL_START:
+        raise ValueError("a window over the int8 pool is not written "
+                         "(the staging tail's substitution assumes the "
+                         "whole prefix)")
     n_rep = hq // hkv
     nk = block_table.shape[1]
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
     qt = q.transpose(0, 2, 1)
-    lengths = lengths.astype(jnp.int32)
-    block_table = block_table.astype(jnp.int32)
 
-    def blk(ik, lens, tbl, bb, first=None):
-        # pool id of this cell's window; dead tail cells repeat the
-        # lane's last live entry (no new DMA, compute pl.when-skipped)
-        live = jnp.minimum(ik, jnp.maximum(lens[bb] - 1, 0) // block_k)
-        if first is not None:       # and cells before the window its first
-            live = jnp.maximum(live, first[bb] // block_k)
-        return tbl[bb, live]
+    def lane_map(c, cells, *_):
+        return (cells[CELL_LANE, c], 0, 0)
 
-    if windowed:
-        if not stacked:
-            raise ValueError("the windowed kernel reads the stacked pool "
-                             "(pass layer=)")
+    if stacked:
         lay = jnp.reshape(layer, (1,)).astype(jnp.int32)
         cache_spec = pl.BlockSpec(
             (1, 1, hkv, block_k, d),
-            lambda b, ik, lens, tbl, lay, first: (
-                lay[0], blk(ik, lens, tbl, b, first), 0, 0, 0))
-        tail_spec = None
-        q_spec = pl.BlockSpec((1, d, hq), lambda b, ik, *_: (b, 0, 0))
-        out_spec = pl.BlockSpec((1, hq, d), lambda b, ik, *_: (b, 0, 0))
-        num_prefetch, extra = 4, (lay, starts.astype(jnp.int32))
-    elif stacked:
-        lay = jnp.reshape(layer, (1,)).astype(jnp.int32)
-        cache_spec = pl.BlockSpec(
-            (1, 1, hkv, block_k, d),
-            lambda b, ik, lens, tbl, lay: (lay[0], blk(ik, lens, tbl, b),
-                                           0, 0, 0))
+            lambda c, cells, lay: (lay[0], cells[CELL_BLOCK, c], 0, 0, 0))
         tail_spec = pl.BlockSpec(
             (1, 1, hkv, block_k, d),
-            lambda b, ik, lens, tbl, lay: (lay[0], b, 0, 0, 0))
-        q_spec = pl.BlockSpec((1, d, hq),
-                              lambda b, ik, lens, tbl, lay: (b, 0, 0))
-        out_spec = pl.BlockSpec((1, hq, d),
-                                lambda b, ik, lens, tbl, lay: (b, 0, 0))
-        num_prefetch, extra = 3, (lay,)
+            lambda c, cells, lay: (lay[0], cells[CELL_LANE, c], 0, 0, 0))
+        extra = (lay,)
     else:
         cache_spec = pl.BlockSpec(
             (1, hkv, block_k, d),
-            lambda b, ik, lens, tbl: (blk(ik, lens, tbl, b), 0, 0, 0))
+            lambda c, cells: (cells[CELL_BLOCK, c], 0, 0, 0))
         tail_spec = pl.BlockSpec(
-            (1, hkv, block_k, d), lambda b, ik, lens, tbl: (b, 0, 0, 0))
-        q_spec = pl.BlockSpec((1, d, hq), lambda b, ik, lens, tbl: (b, 0, 0))
-        out_spec = pl.BlockSpec((1, hq, d),
-                                lambda b, ik, lens, tbl: (b, 0, 0))
-        num_prefetch, extra = 2, ()
+            (1, hkv, block_k, d),
+            lambda c, cells: (cells[CELL_LANE, c], 0, 0, 0))
+        extra = ()
 
-    in_specs = [q_spec, cache_spec, cache_spec]
+    in_specs = [pl.BlockSpec((1, d, hq), lane_map), cache_spec, cache_spec]
     scratch_shapes = [
         pltpu.VMEM((hq, d), jnp.float32),        # acc
         pltpu.VMEM((hq, 128), jnp.float32),      # m (col 0 live)
         pltpu.VMEM((hq, 128), jnp.float32),      # l (col 0 live)
     ]
     quant_operands = ()
-    kernel_body = _paged_kernel
     compiler_params = None
     if quant:
         def lane_scales(plane):
             if stacked:
                 plane = jax.lax.dynamic_index_in_dim(
                     plane, lay[0], 0, keepdims=False)
-            return plane.astype(jnp.float32)[block_table]   # [B, M, Hkv]
+            return plane.astype(jnp.float32)[
+                block_table.astype(jnp.int32)]              # [B, M, Hkv]
 
-        # lane b's whole [M, hkv] slab: constant in ik, fetched once
-        scale_spec = pl.BlockSpec((1, nk, hkv), lambda b, ik, *_: (b, 0, 0),
+        # the cell's lane's whole [M, hkv] slab: fetched once a lane
+        scale_spec = pl.BlockSpec((1, nk, hkv), lane_map,
                                   memory_space=pltpu.SMEM)
         in_specs += [scale_spec, scale_spec, tail_spec, tail_spec]
         quant_operands = (lane_scales(k_scale), lane_scales(v_scale),
                           k_tail, v_tail)
         # the cell's dequantized (or tail-substituted) K/V tiles
         scratch_shapes += [pltpu.VMEM((hkv, block_k, d), q.dtype)] * 2
-        kernel_body = _paged_kernel_quant
         # double-buffered code and tail windows plus the two scratch
         # tiles: 16 MiB at 32 kv heads x 256 rows x 128, which is the
         # whole default scoped-VMEM budget — ask for what the shapes need
@@ -533,28 +602,26 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         compiler_params = pltpu.CompilerParams(
             vmem_limit_bytes=need + (16 << 20))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=num_prefetch,
-        grid=(b, nk),
+        num_scalar_prefetch=1 + len(extra),
+        grid=(cells.n,),
         in_specs=in_specs,
-        out_specs=out_spec,
+        out_specs=pl.BlockSpec((1, hq, d), lane_map),
         scratch_shapes=scratch_shapes,
     )
-    kernel_kw = {"windowed": True} if windowed else {}
-    out = pl.pallas_call(
-        functools.partial(kernel_body, scale=scale, block_k=block_k,
-                          n_rep=n_rep, stacked=stacked, **kernel_kw),
+    return pl.pallas_call(
+        functools.partial(_cells_kernel, scale=scale, block_k=block_k,
+                          n_rep=n_rep, stacked=stacked, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
         compiler_params=compiler_params,
         interpret=interpret,
-    )(lengths, block_table, *extra, qt, k_pool, v_pool, *quant_operands)
-    return out
+    )(cells.rows, *extra, qt, k_pool, v_pool, *quant_operands)
 
 
 def sharded_paged_decode_attention(mesh, q: jax.Array, k_pool: jax.Array,
                                    v_pool: jax.Array,
                                    block_table: jax.Array,
-                                   lengths: jax.Array, wo, *,
+                                   lengths: Optional[jax.Array], wo, *,
                                    layer: Optional[jax.Array] = None,
                                    axis_name: str = "tp",
                                    interpret: bool = False,
@@ -562,13 +629,15 @@ def sharded_paged_decode_attention(mesh, q: jax.Array, k_pool: jax.Array,
                                    k_scale: Optional[jax.Array] = None,
                                    v_scale: Optional[jax.Array] = None,
                                    k_tail: Optional[jax.Array] = None,
-                                   v_tail: Optional[jax.Array] = None
+                                   v_tail: Optional[jax.Array] = None,
+                                   cells: Optional[DecodeCells] = None
                                    ) -> jax.Array:
     """:func:`sharded_decode_attention` for the paged pool: the pool
     shards over its kv-head axis exactly like the ring cache (block ids
     are position-like, replicated), so each shard runs the paged kernel
     on its own whole GQA groups and the wo psum completes the Megatron
-    row-parallel projection — block table and lengths replicate.
+    row-parallel projection — block table and work list replicate
+    (``cells``, or ``lengths`` to build it from here).
 
     The quantized-pool operands (``k_scale``/``v_scale`` per-block
     scales, ``k_tail``/``v_tail`` per-lane staging blocks) shard over
@@ -597,7 +666,7 @@ def sharded_paged_decode_attention(mesh, q: jax.Array, k_pool: jax.Array,
     stacked = layer is not None
     quant = k_scale is not None
 
-    def body(q, kc, vc, tbl, lens, wo, *rest):
+    def body(q, kc, vc, tbl, cells, wo, *rest):
         if quant:
             ks, vs, kt, vt = rest[:4]
             rest = rest[4:]
@@ -605,7 +674,7 @@ def sharded_paged_decode_attention(mesh, q: jax.Array, k_pool: jax.Array,
                    "k_tail": kt, "v_tail": vt}
         else:
             qkw = {}
-        out = paged_decode_attention(q, kc, vc, tbl, lens,
+        out = paged_decode_attention(q, kc, vc, tbl, cells=cells,
                                      layer=rest[0] if stacked else None,
                                      interpret=interpret,
                                      **qkw)                 # [B, Hq/tp, D]
@@ -617,9 +686,11 @@ def sharded_paged_decode_attention(mesh, q: jax.Array, k_pool: jax.Array,
                 o = o @ wo.astype(dtype)
             return jax.lax.psum(o, axis_name)                   # [B, E]
 
-    in_specs = (head_spec, pool_spec, pool_spec, P(), P(), wo_spec)
-    args = (q, k_pool, v_pool, block_table.astype(jnp.int32),
-            lengths.astype(jnp.int32), wo)
+    if cells is None:
+        cells = decode_cells(block_table, lengths, k_pool.shape[-2])
+    in_specs = (head_spec, pool_spec, pool_spec, P(),
+                DecodeCells(P(), P()), wo_spec)
+    args = (q, k_pool, v_pool, block_table.astype(jnp.int32), cells, wo)
     if quant:
         in_specs += (scale_spec, scale_spec, pool_spec, pool_spec)
         args += (k_scale, v_scale, k_tail, v_tail)

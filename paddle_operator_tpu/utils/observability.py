@@ -194,7 +194,8 @@ def _counter_gauges(out: dict, status_serving: dict, job: str,
                     replica: str = None) -> None:
     """The ring's raw cumulative counters (top-level block only, like
     the QoS gauges): decode dispatches, the device decode iterations
-    they ran and those times the lanes live in each plan; insert
+    they ran, those times the lanes live in each plan, and the paged
+    decode kernel's live cells against its old rectangle; insert
     programs dispatched (by program width), the real tokens they
     prefilled and the positions they computed; and the loop thread's
     self seconds and counts by phase.  Counters, so a dashboard takes
@@ -206,6 +207,8 @@ def _counter_gauges(out: dict, status_serving: dict, job: str,
             ("dispatchesTotal", "dispatches"),
             ("decodeStepsTotal", "decode_steps"),
             ("decodeLaneStepsTotal", "decode_lane_steps"),
+            ("decodeCellsLive", "decode_cells_live"),
+            ("decodeCellsGrid", "decode_cells_grid"),
             ("prefillTokensTotal", "prefill_tokens"),
             ("prefillBucketTokensTotal", "prefill_bucket_tokens")):
         out[f"tpujob_serve_{name}_total{lbl}"] = \

@@ -367,6 +367,64 @@ def test_windowed_paged_kernel_against_the_einsum(window):
         assert rel(got, plain) < 1e-6
 
 
+@pytest.mark.parametrize("window", [1, 64, 100, 300, 10 ** 6])
+def test_windowed_list_against_the_rectangular_grid(window):
+    """The list-driven kernel answers bit for bit what the rectangular
+    grid it replaced answers (tests/rect_paged_attention.py): windows
+    that start at 0, on a block's edge and mid-block; an empty lane."""
+    from paddle_operator_tpu.ops.decode_attention import (
+        decode_cells,
+        paged_decode_attention,
+    )
+    from tests.rect_paged_attention import rect_paged_decode_attention
+
+    b, m, bs, layers = 4, 6, 64, 2
+    rng = np.random.RandomState(1)
+    pool_k = jnp.asarray(rng.randn(layers, b * m + 1, 2, bs, 128), jnp.float32)
+    pool_v = jnp.asarray(rng.randn(layers, b * m + 1, 2, bs, 128), jnp.float32)
+    table = jnp.asarray(1 + rng.permutation(b * m).reshape(b, m), jnp.int32)
+    q = jnp.asarray(rng.randn(b, 4, 128), jnp.float32)
+    lengths = jnp.asarray([37, 0, 200, 384], jnp.int32)
+    starts = jnp.maximum(lengths - window, 0)
+    cells = decode_cells(table, lengths, bs, starts)
+    lo, end = np.asarray(starts) // bs, -(-np.asarray(lengths) // bs)
+    assert int(cells.n) == int(np.maximum(end - lo, 1).sum())
+    for li in range(layers):
+        got = paged_decode_attention(q, pool_k, pool_v, table, lengths,
+                                     layer=jnp.int32(li), cells=cells,
+                                     interpret=True)
+        want = rect_paged_decode_attention(q, pool_k, pool_v, table, lengths,
+                                           layer=jnp.int32(li), starts=starts)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.asarray(got)[1].any()
+
+
+def test_ring_step_with_a_masked_lane_through_the_kernel():
+    """The chunk step with the kernel (interpret mode) and one lane out
+    of the step: the live lanes' tokens are the einsum step's, and every
+    lane's logits stay finite (``check_finite``)."""
+    cfg = M.AfmoeConfig(vocab_size=64, dim=64, n_layers=3, n_dense_layers=1,
+                        n_heads=2, n_kv_heads=1, head_dim=128, ffn_dim=64,
+                        moe_ffn_dim=32, n_experts=4, top_k=2,
+                        sliding_window=8, layer_types=(M.SLIDING, M.SLIDING,
+                                                       M.FULL),
+                        max_seq_len=64, dtype=jnp.float32,
+                        param_dtype=jnp.float32, decode_attn="xla")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    prompts = [ids(41, 5), ids(42, 19), ids(43, 11)]
+    active = jnp.asarray([True, False, True])
+    outs = []
+    for impl in ("xla", "pallas-interpret"):
+        c = dataclasses.replace(cfg, decode_attn=impl)
+        cache, table, tok, temp, keys = insert_prompts(c, params, prompts)
+        _, _, toks, ok, _ = AF.make_paged_chunk_step(
+            c, 3, check_finite=True)(params, cache, table, tok, temp, keys,
+                                     active)
+        assert np.asarray(ok).all()
+        outs.append(np.asarray(toks))
+    assert outs[0][:, [0, 2]].tolist() == outs[1][:, [0, 2]].tolist()
+
+
 def test_ring_forward_through_the_windowed_kernel():
     """The ring's forward with the kernel (interpret mode) agrees with its
     einsum path at a head width the kernel takes."""

@@ -14,11 +14,16 @@ import jax.numpy as jnp
 from paddle_operator_tpu.infer import decode as D
 from paddle_operator_tpu.models.llama import make_model
 from paddle_operator_tpu.ops.decode_attention import (
+    CELL_FIRST,
+    CELL_LAST,
     decode_attention,
     decode_attention_reference,
+    decode_cells,
+    paged_decode_attention,
     sharded_decode_attention,
 )
 from paddle_operator_tpu.parallel.mesh import make_serving_mesh
+from tests.rect_paged_attention import rect_paged_decode_attention
 
 
 def _rand(shape, seed=0):
@@ -73,6 +78,148 @@ class TestKernelEquivalence:
         got = decode_attention(q, k, v, L, interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+
+def _cells_by_loop(table, lengths, block, starts=None):
+    """:func:`decode_cells` as a plain loop: lane after lane, a column a
+    block that holds positions of ``[start, length)``; a lane with none
+    keeps one column, whose pool id repeats its neighbour's."""
+    cols = []
+    for b, n in enumerate(lengths):
+        end = -(-n // block)
+        lo = 0 if starts is None else min(starts[b] // block, end)
+        blocks = list(range(lo, end))
+        for j in blocks or [lo]:
+            flags = ((CELL_FIRST if j == (blocks or [lo])[0] else 0)
+                     | (CELL_LAST if j == (blocks or [lo])[-1] else 0))
+            col = [b, table[b][j] if blocks else None, j, flags, n]
+            cols.append(col + ([] if starts is None else [starts[b]]))
+    real = [c[1] for c in cols if c[1] is not None]
+    last = real[0] if real else 0       # no lane holds anything: block 0
+    for c in cols:
+        c[1] = last = last if c[1] is None else c[1]
+    return np.asarray(cols, np.int64).T
+
+
+BLOCK, M = 256, 16           # the serving cells' block and blocks a lane
+LENGTHS = {
+    "edges": [0, 1, 255, 256, 257, 4096],
+    "all-masked": [0, 0, 0, 0],
+    "all-full": [4096, 4096, 4096],
+    "leading-and-trailing-empty": [0, 0, 700, 0, 300, 0],
+    "one-lane": [513],
+}
+
+
+class TestWorkList:
+    """The decode kernel's grid as a list (``decode_cells``) against a
+    plain Python loop."""
+
+    @pytest.mark.parametrize("name", sorted(LENGTHS))
+    @pytest.mark.parametrize("window", [None, 0, 256, 300, 2048, 10 ** 6])
+    def test_against_the_loop(self, name, window):
+        """Windows: none; one whose start falls at 0 for every lane
+        (``10 ** 6``), on a block's edge (256 under a length of 512 or
+        a multiple), mid-block (300), and past every length's start (0:
+        an empty range, which keeps a lane's one cell)."""
+        lengths = np.asarray(LENGTHS[name], np.int32)
+        b = len(lengths)
+        table = 1 + np.random.default_rng(b).permutation(b * M).reshape(b, M)
+        starts = (None if window is None
+                  else np.maximum(lengths - window, 0))
+        got = decode_cells(jnp.asarray(table, jnp.int32),
+                           jnp.asarray(lengths), BLOCK,
+                           None if starts is None else jnp.asarray(starts))
+        want = _cells_by_loop(table.tolist(), lengths.tolist(), BLOCK,
+                              None if starts is None else starts.tolist())
+        n = int(got.n)
+        assert n == want.shape[1]
+        assert got.rows.shape == (5 + (window is not None), b * M)
+        assert got.rows.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got.rows)[:, :n], want)
+
+    def test_block_edge_start_drops_the_block_before_it(self):
+        table = jnp.arange(1, 1 + M, dtype=jnp.int32)[None]
+        got = decode_cells(table, jnp.asarray([600]), BLOCK,
+                           jnp.asarray([256]))
+        assert int(got.n) == 2
+        assert np.asarray(got.rows)[:3, :2].tolist() == [[0, 0], [2, 3],
+                                                         [1, 2]]
+
+
+def _pool_case(lengths, seed=0, b_hkv_hq_d=(2, 4, 32), bs=16, m=4,
+               layers=2):
+    hkv, hq, d = b_hkv_hq_d
+    b = len(lengths)
+    rng = np.random.default_rng(seed)
+    n = b * m + 1
+    table = jnp.asarray(1 + rng.permutation(b * m).reshape(b, m), jnp.int32)
+    return (_rand((b, hq, d), seed + 1), _rand((layers, n, hkv, bs, d),
+                                               seed + 2),
+            _rand((layers, n, hkv, bs, d), seed + 3), table,
+            jnp.asarray(lengths, jnp.int32))
+
+
+def _lane_view(pool, table, li):
+    """Layer ``li`` of the pool as contiguous lanes [B, Hkv, M*bs, D]."""
+    _, _, hkv, bs, d = pool.shape
+    b, m = table.shape
+    v = np.asarray(pool)[li][np.asarray(table).reshape(-1)]
+    return jnp.asarray(v.reshape(b, m, hkv, bs, d).transpose(0, 2, 1, 3, 4)
+                       .reshape(b, hkv, m * bs, d))
+
+
+class TestListKernel:
+    """The paged kernel over the work list (interpret mode): against the
+    einsum, and bit for bit against the rectangular grid it replaced
+    (tests/rect_paged_attention.py)."""
+
+    @pytest.mark.parametrize("lengths", [[0, 1, 15, 16, 17, 64],
+                                         [64, 64, 64], [0, 0, 0],
+                                         [0, 33, 0, 0, 5]])
+    def test_against_reference_and_rectangle(self, lengths):
+        q, kp, vp, table, lens = _pool_case(lengths)
+        li = jnp.int32(1)
+        got = paged_decode_attention(q, kp, vp, table, lens, layer=li,
+                                     interpret=True)
+        ref = decode_attention_reference(
+            q, _lane_view(kp, table, 1), _lane_view(vp, table, 1), lens)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+        rect = rect_paged_decode_attention(q, kp, vp, table, lens, layer=li)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(rect))
+        assert not np.asarray(got)[np.asarray(lengths) == 0].any()
+
+    @pytest.mark.parametrize("window", [1, 16, 20, 40, 10 ** 6])
+    def test_window_against_rectangle(self, window):
+        """Starts at 0, on a block's edge and mid-block."""
+        q, kp, vp, table, lens = _pool_case([0, 1, 16, 36, 57, 64], seed=4)
+        starts = jnp.maximum(lens - window, 0)
+        got = paged_decode_attention(q, kp, vp, table, lens,
+                                     layer=jnp.int32(0), starts=starts,
+                                     interpret=True)
+        rect = rect_paged_decode_attention(q, kp, vp, table, lens,
+                                           layer=jnp.int32(0), starts=starts)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(rect))
+
+    def test_prebuilt_list_and_masked_lanes(self):
+        """A list built once serves every layer's call; a lane handed in
+        at length 0 (masked out of the step) reads nothing, answers
+        zeros, and leaves the live lanes' answers what they were."""
+        q, kp, vp, table, lens = _pool_case([9, 40, 17, 64], seed=7)
+        mask = jnp.asarray([True, False, True, False])
+        cells = decode_cells(table, jnp.where(mask, lens, 0), kp.shape[3])
+        assert int(cells.n) == 1 + 1 + 2 + 1
+        for li in range(2):
+            full = paged_decode_attention(q, kp, vp, table, lens,
+                                          layer=jnp.int32(li),
+                                          interpret=True)
+            got = paged_decode_attention(q, kp, vp, table, lens,
+                                         layer=jnp.int32(li), cells=cells,
+                                         interpret=True)
+            np.testing.assert_array_equal(np.asarray(got)[[0, 2]],
+                                          np.asarray(full)[[0, 2]])
+            assert not np.asarray(got)[[1, 3]].any()
 
 
 class TestShardedKernel:

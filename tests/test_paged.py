@@ -212,6 +212,28 @@ class TestAllocator:
         assert mgr.blocks_free() == 8
 
 
+    @pytest.mark.parametrize("pos,active,live", [
+        # ceil((p + 1) / 256) for an active lane, 1 for any other
+        ([0, 254, 255, 256, 4095, 700], {0, 1, 2, 3, 4, 5},
+         1 + 1 + 1 + 2 + 16 + 3),
+        ([0, 254, 255, 256, 4095, 700], {3, 5}, 1 + 1 + 1 + 2 + 1 + 3),
+        ([0, 0, 0, 0, 0, 0], set(), 6),
+        ([4095] * 6, set(range(6)), 96),
+    ])
+    def test_decode_cells_of_known_positions(self, pos, active, live):
+        """``decodeCellsLive`` / ``decodeCellsGrid`` on ``/statusz``: one
+        decode iteration's counts from the lane positions the scheduler
+        holds — and they are the kernel's own list's length."""
+        from paddle_operator_tpu.ops.decode_attention import decode_cells
+
+        mgr = PagedCacheManager(slots=6, max_len=4096, block_size=256)
+        assert mgr.decode_cell_counts(pos, active) == (live, 6 * 16)
+        lengths = jnp.asarray([p + 1 if i in active else 0
+                               for i, p in enumerate(pos)], jnp.int32)
+        assert int(decode_cells(jnp.zeros((6, 16), jnp.int32), lengths,
+                                256).n) == live
+
+
 class TestPagedKernel:
     def test_matches_reference_under_scrambled_block_map(self):
         from paddle_operator_tpu.ops.decode_attention import (
@@ -255,6 +277,55 @@ class TestPagedKernel:
                                              v * (li + 1), lengths)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                        rtol=1e-5, atol=1e-5)
+
+
+    @pytest.mark.parametrize("quant", ["none", "int8"])
+    def test_step_masks_inactive_lanes_out_of_the_kernel(self, setup, quant):
+        """The decode step through the kernel (interpret mode) with a
+        lane masked out: the view hands the kernel length 0 for it, so
+        its attention is zeros, its logits stay finite under
+        ``check_finite``, and the live lane's tokens are the einsum
+        step's."""
+        from paddle_operator_tpu.infer import paged as PG
+
+        _, cfg_x, params = setup
+        _, cfg_k = make_model("tiny", dtype=jnp.float32,
+                              decode_attn="pallas-interpret")
+        m = MAX_LEN // BS
+        active = jnp.asarray([True, False])
+
+        def run(cfg):
+            cache = PG.init_paged_cache(cfg, 2, 2 * m + 1, BS, quant=quant)
+            table = jnp.asarray(1 + np.arange(2 * m).reshape(2, m),
+                                jnp.int32)
+            insert = PG.make_paged_prefill_insert(cfg, 16, BS,
+                                                  quant=quant == "int8")
+            tok = jnp.zeros((2,), jnp.int32)
+            temp = jnp.zeros((2,), jnp.float32)
+            keys = jnp.zeros((2, 2), jnp.uint32)
+            for slot, n in enumerate((13, 9)):
+                padded = np.zeros((1, 16), np.int32)
+                padded[0, :n] = _prompt(cfg, n, seed=20 + slot)
+                cache, tok, temp, keys, _ = insert(
+                    params, cache, table[slot], tok, temp, keys,
+                    jnp.asarray(padded), n, slot, 0.0, 0)
+            view = PG.paged_view(cfg, cache, table, lane_mask=active)
+            bufs, _ = view.begin(1)
+            att = None
+            if view.kernel:
+                q = jnp.ones((2, 1, cfg.n_heads, cfg.head_dim), cfg.dtype)
+                att = np.asarray(view.kernel_attend(bufs, jnp.int32(0), q))
+            step = PG.make_paged_chunk_step(cfg, 3, check_finite=True,
+                                            quant=quant == "int8")
+            _, _, toks, ok = step(params, cache, table, tok, temp, keys,
+                                  active)
+            return att, np.asarray(toks), np.asarray(ok)
+
+        att, toks_k, ok = run(cfg_k)
+        assert att[0].any() and not att[1].any()
+        assert ok.tolist() == [True, True]
+        _, toks_x, _ = run(cfg_x)
+        assert toks_k[:, 0].tolist() == toks_x[:, 0].tolist()
 
 
 class TestPagedRingParity:

@@ -222,6 +222,8 @@ class TestGaugeNaming:
             'tpujob_serve_dispatches_total{job="default/j"}',
             'tpujob_serve_decode_steps_total{job="default/j"}',
             'tpujob_serve_decode_lane_steps_total{job="default/j"}',
+            'tpujob_serve_decode_cells_live_total{job="default/j"}',
+            'tpujob_serve_decode_cells_grid_total{job="default/j"}',
             'tpujob_serve_prefill_tokens_total{job="default/j"}',
             'tpujob_serve_prefill_bucket_tokens_total'
             '{job="default/j"}',
@@ -233,7 +235,8 @@ class TestGaugeNaming:
         thread's self seconds and counts labeled ``phase``."""
         g = serving_gauges(
             {"dispatchesTotal": 7, "decodeStepsTotal": 56,
-             "decodeLaneStepsTotal": 600, "prefillTokensTotal": 900,
+             "decodeLaneStepsTotal": 600, "decodeCellsLive": 2800,
+             "decodeCellsGrid": 14336, "prefillTokensTotal": 900,
              "prefillBucketTokensTotal": 4608,
              "prefillCallsByBucket": {"512": 1, "4096": 1},
              "phaseSeconds": {"sched.idle.no_work": 1.5,
@@ -243,6 +246,8 @@ class TestGaugeNaming:
         lbl = 'job="ns/x",replica="r0"'
         assert g[f"tpujob_serve_dispatches_total{{{lbl}}}"] == 7.0
         assert g[f"tpujob_serve_decode_lane_steps_total{{{lbl}}}"] == 600.0
+        assert g[f"tpujob_serve_decode_cells_live_total{{{lbl}}}"] == 2800.0
+        assert g[f"tpujob_serve_decode_cells_grid_total{{{lbl}}}"] == 14336.0
         assert g["tpujob_serve_prefill_calls_total"
                  f'{{{lbl},bucket="4096"}}'] == 1.0
         assert g["tpujob_serve_phase_seconds_total"
@@ -445,7 +450,8 @@ class TestBatcherServingStatus:
                            # raw counters and the loop's phase table
                            # (ISSUE 26)
                            "dispatchesTotal", "decodeStepsTotal",
-                           "decodeLaneStepsTotal", "prefillCallsTotal",
+                           "decodeLaneStepsTotal", "decodeCellsLive",
+                           "decodeCellsGrid", "prefillCallsTotal",
                            "prefillTokensTotal",
                            "prefillBucketTokensTotal",
                            "prefillCallsByBucket",
@@ -546,6 +552,12 @@ class TestBatcherServingStatus:
             assert st["prefixHitRate"] > 0      # second request hit
             assert st["kvBlocksFree"] > 0       # lanes retired
             assert st["kvBlocksHwm"] >= 2
+            # the decode kernel's cells: the rectangle is lanes x blocks a
+            # decode iteration; a 16-20 token context fills 3 of its 8
+            # (its 3 blocks, and the idle lane's one cell: 4) at most
+            assert st["decodeCellsGrid"] == st["decodeStepsTotal"] * 2 * 4
+            assert (2 * st["decodeStepsTotal"] <= st["decodeCellsLive"]
+                    <= 4 * st["decodeStepsTotal"])
             g = serving_gauges(st, "ns/j")
             assert g['tpujob_serve_prefix_hit_rate{job="ns/j"}'] > 0
             assert g['tpujob_serve_kv_blocks_free{job="ns/j"}'] > 0

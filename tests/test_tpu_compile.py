@@ -284,3 +284,71 @@ def test_tp_whole_prompt_prefill(tp_mesh, monkeypatch):
                            ).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert "all-reduce" in text and "all-gather" not in text
+
+
+def _ring_lanes(sharding, lanes, blocks):
+    """table, tok, temp, keys, active of a paged ring, as shapes."""
+    return (sds((lanes, blocks), jnp.int32, sharding),
+            sds((lanes,), jnp.int32, sharding),
+            sds((lanes,), jnp.float32, sharding),
+            sds((lanes, 2), jnp.uint32, sharding),
+            sds((lanes,), jnp.bool_, sharding))
+
+
+def test_serving_step_of_the_dense_cell(one_chip, monkeypatch):
+    """ISSUE 31: ``jit_step`` at ``mistral-7b-serve-16l``'s shapes (16
+    layers of 7B width over 8 kv heads, feed-forward 14336; 16 lanes,
+    block 256, ``max_len`` 4096, chunk 8).  The decode kernel over its
+    work list is the layer scan's ONE custom call, and the list costs no
+    temporaries to speak of: 673.3 MB, the parent's 673.2 (two weight
+    stacks' change of layout: PERF.md section 5)."""
+    from paddle_operator_tpu.infer import paged as PG
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(_gqa_7b(16), ffn_dim=14336,
+                              dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    on_chip = lambda t: jax.tree.map(                        # noqa: E731
+        lambda x: sds(x.shape, x.dtype, one_chip), t)
+    params = _serving_shapes(
+        cfg, lambda t: jax.tree.map(lambda _: one_chip, t))
+    pool = PG.PagedCacheManager(16, 4096, 256)
+    cache = on_chip(jax.eval_shape(
+        lambda: PG.init_paged_cache(cfg, 16, pool.total, 256)))
+    compiled = PG.make_paged_chunk_step(cfg, 8).lower(
+        params, cache, *_ring_lanes(one_chip, 16, pool.max_blocks)).compile()
+    assert kernel_calls(compiled) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 674 * 10 ** 6
+
+
+def test_serving_step_of_the_expert_cell(one_chip, monkeypatch):
+    """ISSUE 31: the expert architecture's step at
+    ``trinity-mini-serve-5l``'s shapes: the dense layer's decode call,
+    and in the scanned layers' body one decode call (window and full
+    layers alike: each layer's work list rides the scan) beside the
+    three grouped products; 99.1 MB of temporaries (the parent's
+    98.8)."""
+    import json
+
+    from benchmark.harness import afmoe as H
+    from paddle_operator_tpu.infer import afmoe_serve as AF
+    from paddle_operator_tpu.infer import paged as PG
+    from paddle_operator_tpu.models import afmoe as M
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "trinity-mini-serve-5l.json")) as f:
+        cfgj = json.load(f)
+    s = cfgj["serve"]
+    cfg = H.config(cfgj, s["max_len"])
+    on_chip = lambda t: jax.tree.map(                        # noqa: E731
+        lambda x: sds(x.shape, x.dtype, one_chip), t)
+    pool = PG.PagedCacheManager(s["lanes"], s["max_len"], s["block"],
+                                prefix_cache=False)
+    cache = on_chip(jax.eval_shape(lambda: PG.init_paged_cache(
+        cfg, s["lanes"], pool.total, s["block"])))
+    compiled = AF.make_paged_chunk_step(cfg, s["chunk"]).lower(
+        on_chip(M.param_shapes(cfg)), cache,
+        *_ring_lanes(one_chip, s["lanes"], pool.max_blocks)).compile()
+    assert kernel_calls(compiled) == 2 + 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 100 * 10 ** 6
