@@ -1,16 +1,27 @@
-"""Serving the ``afmoe`` architecture (``models/afmoe.py``): the cached
-forward behind ``infer/decode.py generate`` and the paged continuous
-ring's decode step and prefill insert at tp 1 with a bf16 pool — the
-programs the serving cells time.  Every other serving mode refuses the
-architecture at start-up (:func:`refuse_modes`); none runs the LLaMA
-block over these weights.
+"""Serving an EXPERT STACK — a decoder whose leading layers are dense and
+whose other layers route every token to a few of many experts
+(``models/afmoe.py``: Arcee Trinity; ``models/glm_moe_lite.py``:
+GLM-4.7-Flash): the cached forward behind ``infer/decode.py generate``
+and the paged continuous ring's decode step and prefill insert at tp 1
+with a bf16 pool — the programs the serving cells time.  Every other
+serving mode refuses such an architecture at start-up
+(:func:`refuse_modes`); none runs the LLaMA block over its weights.
+
+ONE stack for every such architecture.  What an architecture brings is
+its model module (:func:`stack_of`): the parameter tree, embedding and
+head, the attention half of a block over a cache view (inputs, the
+view's write, the view's kernel or an einsum over its lanes, residual)
+and the feed-forward half; and its cache's buffers, which pick the view
+(``cfg.cache_buffers()``; infer/paged.py ``paged_view``: K and V a head,
+or one latent row for all heads).
 
 The stack is not one scan over identical layers: the leading dense
 layers run unrolled, then one ``lax.scan`` over the expert layers with
-each layer's kind as scanned operands (its window, a full layer's being
-past any position; whether it rotates q and k).  Pool layout and block
-tables are the ring's own: every layer keeps the whole context (a pool
-per layer kind that frees blocks behind the window is not written).
+whatever differs from layer to layer as scanned operands (``afmoe``: a
+layer's window, a full layer's being past any position, and whether it
+rotates q and k).  Pool layout and block tables are the ring's own:
+every layer keeps the whole context (a pool per layer kind that frees
+blocks behind the window is not written).
 
 Routing counters are computed on the device and ride the decode
 dispatch's results home: per dispatch the decode steps' assignments by
@@ -29,20 +40,28 @@ import jax.numpy as jnp
 
 from paddle_operator_tpu.infer import decode as D
 from paddle_operator_tpu.infer import paged as PG
-from paddle_operator_tpu.models import afmoe as M
-from paddle_operator_tpu.models.afmoe import AfmoeConfig
+from paddle_operator_tpu.models import afmoe, glm_moe_lite
+
+_STACKS = {afmoe.AfmoeConfig: afmoe,
+           glm_moe_lite.GlmMoeLiteConfig: glm_moe_lite}
 
 
-def is_afmoe(cfg) -> bool:
-    return isinstance(cfg, AfmoeConfig)
+def stack_of(cfg):
+    """The model module whose block the expert stack runs for `cfg`;
+    None for a configuration that is not an expert stack."""
+    return _STACKS.get(type(cfg))
+
+
+def is_expert_stack(cfg) -> bool:
+    return type(cfg) in _STACKS
 
 
 def refuse_modes(cfg, modes: Dict[str, Any]) -> None:
-    """One sentence and a ``ValueError`` naming the serving modes the
-    architecture is not written for; a no-op for any other config.
+    """One sentence and a ``ValueError`` naming the serving modes an
+    expert stack is not written for; a no-op for any other config.
     `modes` maps a mode's name (as the operator sets it) to whether it
     is on."""
-    if not is_afmoe(cfg):
+    if not is_expert_stack(cfg):
         return
     on = sorted(name for name, value in modes.items() if value)
     if on:
@@ -53,17 +72,27 @@ def refuse_modes(cfg, modes: Dict[str, Any]) -> None:
             + ", ".join(on))
 
 
-def load_params(cfg: AfmoeConfig, ckpt, seed: int):
-    """``load_serving_params`` for this architecture: smoke-mode weights
+def load_params(cfg, ckpt, seed: int):
+    """``load_serving_params`` for an expert stack: smoke-mode weights
     under one jit on the first device.  The trainer refuses the
     architecture, so there is no checkpoint to restore."""
     if ckpt is not None and ckpt.enabled and ckpt.latest_step() is not None:
-        raise ValueError("AfmoeConfig has no trainer, so no checkpoint "
-                         "layout to restore from")
+        raise ValueError(f"{type(cfg).__name__} has no trainer, so no "
+                         "checkpoint layout to restore from")
+    M = stack_of(cfg)
     one = jax.sharding.SingleDeviceSharding(jax.devices()[0])
     shardings = jax.tree.map(lambda _: one, M.param_shapes(cfg))
     return jax.jit(lambda rng: M.init_params(cfg, rng),
                    out_shardings=shardings)(jax.random.PRNGKey(seed)), False
+
+
+def prefill_attn_impl(cfg, width: int) -> str:
+    """``decode.prefill_attn_impl`` for an expert stack's whole-prompt
+    insert: the LLaMA insert's rule where the architecture's block can
+    attend a prompt through the flash kernel, the einsum elsewhere."""
+    if not stack_of(cfg).whole_prompt_flash(cfg):
+        return "einsum"
+    return D.prefill_attn_impl(cfg, width)
 
 
 # ---------------------------------------------------------------------------
@@ -71,64 +100,88 @@ def load_params(cfg: AfmoeConfig, ckpt, seed: int):
 # ---------------------------------------------------------------------------
 
 
-def forward(cfg: AfmoeConfig, params: Dict[str, Any], tokens: jax.Array,
+class _LaneView:
+    """A contiguous cache as a block's attention sees a cache (the paged
+    pool's view is infer/paged.py ``paged_view``): head-major buffers
+    ``[B, H, S, W]``, a layer's at a time; ``T`` new rows land at the
+    scalar position; attention is the einsum over the lanes themselves,
+    the whole allocation under the mask."""
+
+    kernel = False
+
+    def __init__(self, pos: jax.Array) -> None:
+        self.pos = pos
+
+    @jax.named_scope("cache_write")
+    def write(self, bufs, li, *rows):
+        return tuple(jax.lax.dynamic_update_slice(
+            buf, r.transpose(0, 2, 1, 3), (0, 0, self.pos, 0))
+            for buf, r in zip(bufs, rows))
+
+    def lanes(self, bufs, li):
+        return bufs
+
+
+def forward(cfg, params: Dict[str, Any], tokens: jax.Array,
             cache: Dict[str, jax.Array], *, head_at=None,
-            counted: Optional[jax.Array] = None):
-    """``decode._forward`` for this architecture: ``[B, T]`` new tokens at
+            counted: Optional[jax.Array] = None, whole_prompt: bool = False):
+    """``decode._forward`` for an expert stack: ``[B, T]`` new tokens at
     ``cache['pos']`` -> (logits, advanced cache, prefill load ``[E]``).
     ``head_at`` (an index among the T, may be traced): norm and head at
     that one position only, logits ``[B, 1, V]`` — the whole prompt's
     logits at this vocabulary are gigabytes.
-    Caches are head-major ``[L, B, H_kv, S, hd]``; attention is the
-    einsum over the whole allocation under the causal, fill and window
-    mask (prefill and cached decoding alike: the test oracle's path)."""
+    Caches are head-major ``[L, B, H, S, W]``, one a buffer of
+    ``cfg.cache_buffers()``; attention is the einsum over the whole
+    allocation under the causal, fill and window mask (prefill and cached
+    decoding alike: the test oracle's path) — but a ``whole_prompt`` (the
+    cache is empty and ``tokens`` is all it will hold: the ring's insert)
+    on a rung :func:`prefill_attn_impl` sends to the flash kernel."""
+    M = stack_of(cfg)
     pos = cache["pos"]
     b, t = tokens.shape
     x = M.embed(cfg, params, tokens)
-    cos, sin = M.rope_tables(cfg)
+    tables = M.rope_tables(cfg)
     q_pos = jnp.broadcast_to(pos + jnp.arange(t), (b, t))
     nd = cfg.n_dense_layers
-    windows, ropes = cfg.windows(), cfg.ropes()
+    names = tuple(cfg.cache_buffers())
+    kinds = M.layer_kinds(cfg)      # what differs from layer to layer
+    view = _LaneView(pos)
+    attend = {}
+    if whole_prompt and prefill_attn_impl(cfg, t) == "flash":
+        attend = {"flash": True, "blocks": D._prefill_blocks(t)}
 
     scanned, experts = M.split_experts(params["moe_layers"])
 
-    def block(lp, x, k_c, v_c, window, use_rope, moe_layer=None):
-        q, k, v, g = M.attn_inputs(cfg, lp, x, cos, sin, q_pos, use_rope)
-        with jax.named_scope("cache_write"):
-            k_c = jax.lax.dynamic_update_slice(
-                k_c, k.transpose(0, 2, 1, 3), (0, 0, pos, 0))
-            v_c = jax.lax.dynamic_update_slice(
-                v_c, v.transpose(0, 2, 1, 3), (0, 0, pos, 0))
-        att = M.attend(cfg, q, k_c, v_c, q_pos, window)
-        a = M.attn_residual(cfg, lp, x, att, g)
+    def block(lp, x, bufs, kind, moe_layer=None):
+        a, bufs = M.attention(cfg, lp, x, tables, q_pos, view, bufs, None,
+                              kind, **attend)
         y, load = M.ffn_residual(
             cfg, lp, a, None if moe_layer is None else experts, moe_layer,
             counted)
-        return y, k_c, v_c, load
+        return y, bufs, load
 
-    dense_k, dense_v = [], []
+    dense = []
     for i in range(nd):
-        x, k_c, v_c, _ = block(M.layer_at(params["dense_layers"], i), x,
-                               cache["k"][i], cache["v"][i], windows[i],
-                               ropes[i])
-        dense_k.append(k_c)
-        dense_v.append(v_c)
+        x, bufs, _ = block(M.layer_at(params["dense_layers"], i), x,
+                           tuple(cache[n][i] for n in names),
+                           tuple(k[i] for k in kinds))
+        dense.append(bufs)
 
     def body(x, layer_in):
-        lp, k_c, v_c, window, use_rope, l = layer_in
-        y, k_c, v_c, load = block(lp, x, k_c, v_c, window, use_rope, l)
-        return y, (k_c, v_c, load)
+        lp, bufs, kind, l = layer_in
+        y, bufs, load = block(lp, x, bufs, kind, l)
+        return y, (bufs, load)
 
-    x, (moe_k, moe_v, loads) = jax.lax.scan(
-        body, x, (scanned, cache["k"][nd:], cache["v"][nd:],
-                  jnp.asarray(windows[nd:], jnp.int32),
-                  jnp.asarray(ropes[nd:], bool),
+    x, (moe, loads) = jax.lax.scan(
+        body, x, (scanned, tuple(cache[n][nd:] for n in names),
+                  tuple(jnp.asarray(k[nd:]) for k in kinds),
                   jnp.arange(cfg.n_moe_layers)))
     if head_at is not None:
         x = jax.lax.dynamic_slice_in_dim(x, head_at, 1, axis=1)
-    new_cache = {"k": jnp.concatenate([jnp.stack(dense_k), moe_k]),
-                 "v": jnp.concatenate([jnp.stack(dense_v), moe_v]),
-                 "pos": pos + t}
+    new_cache = {n: jnp.concatenate([jnp.stack([d[j] for d in dense]),
+                                     moe[j]])
+                 for j, n in enumerate(names)}
+    new_cache["pos"] = pos + t
     return M.lm_head(cfg, params, x), new_cache, loads.sum(0)
 
 
@@ -137,69 +190,62 @@ def forward(cfg: AfmoeConfig, params: Dict[str, Any], tokens: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def paged_ring_forward(cfg: AfmoeConfig, params, tok: jax.Array, cache,
+def paged_ring_forward(cfg, params, tok: jax.Array, cache,
                        table: jax.Array, active: jax.Array):
-    """The paged ring's decode step for this architecture: ``tok [B]`` at
+    """The paged ring's decode step for an expert stack: ``tok [B]`` at
     per-lane ``cache['pos']`` -> (logits ``[B, V]``, advanced cache, the
     step's load ``[E]`` over `active` lanes and its experts touched,
     summed over the expert layers).  The pool is reached through its
-    view (``paged.PagedView``: the token write, the decode kernel with a
-    layer's window, the gathered lanes for :func:`models.afmoe.attend`);
-    the block is this architecture's own."""
+    view (infer/paged.py ``paged_view``: the token write, the decode
+    kernel over the tick's work list, the gathered lanes for an einsum);
+    the block is the architecture's own."""
+    M = stack_of(cfg)
     pos = cache["pos"]
     x = M.embed(cfg, params, tok[:, None])
-    cos, sin = M.rope_tables(cfg)
-    view = PG.PagedView(cfg, cache, table, lane_mask=active)
+    tables = M.rope_tables(cfg)
+    view = PG.paged_view(cfg, cache, table, lane_mask=active)
     view.enter(1)
     nd = cfg.n_dense_layers
-    windows, ropes = cfg.windows(), cfg.ropes()
+    kinds = M.layer_kinds(cfg)
     counted = active[:, None]
     scanned, experts = M.split_experts(params["moe_layers"])
     lists = None
-    if view.kernel:   # the tick's work lists: one a kind of layer
-        kinds = {w: view.cells(w) for w in set(windows)}
-        lists = [kinds[w] for w in windows]
+    if view.kernel:   # the tick's work lists: one a window among the layers
+        windows = M.kernel_windows(cfg)
+        by_window = {w: view.cells(w) for w in set(windows)}
+        lists = [by_window[w] for w in windows]
 
-    def block(lp, li, x, bufs, window, use_rope, cells, moe_layer=None):
-        q, k, v, g = M.attn_inputs(cfg, lp, x, cos, sin, pos[:, None],
-                                   use_rope)
-        bufs = view.write(bufs, li, k, v)
-        if view.kernel:
-            att = view.kernel_attend(bufs, li, q, cells=cells)
-        else:
-            att = M.attend(cfg, q, *view.lanes(bufs, li), pos[:, None],
-                           window)
-        a = M.attn_residual(cfg, lp, x, att, g)
+    def block(lp, li, x, bufs, kind, cells, moe_layer=None):
+        a, bufs = M.attention(cfg, lp, x, tables, pos[:, None], view, bufs,
+                              li, kind, cells)
         y, load = M.ffn_residual(
             cfg, lp, a, None if moe_layer is None else experts, moe_layer,
             counted)
         return y, bufs, load
 
-    bufs = (cache["k"], cache["v"])
+    bufs = view.buffers()
     for i in range(nd):
         x, bufs, _ = block(M.layer_at(params["dense_layers"], i),
-                           jnp.int32(i), x, bufs, windows[i], ropes[i],
-                           lists and lists[i])
+                           jnp.int32(i), x, bufs,
+                           tuple(k[i] for k in kinds), lists and lists[i])
 
     def body(carry, layer_in):
         x, bufs = carry
-        lp, li, window, use_rope, cells = layer_in
-        y, bufs, load = block(lp, li, x, bufs, window, use_rope, cells,
-                              li - nd)
+        lp, li, kind, cells = layer_in
+        y, bufs, load = block(lp, li, x, bufs, kind, cells, li - nd)
         return (y, bufs), load
 
-    (x, (kc, vc)), loads = jax.lax.scan(
+    (x, bufs), loads = jax.lax.scan(
         body, (x, bufs),
         (scanned, jnp.arange(nd, cfg.n_layers),
-         jnp.asarray(windows[nd:], jnp.int32),
-         jnp.asarray(ropes[nd:], bool),
+         tuple(jnp.asarray(k[nd:]) for k in kinds),
          lists and jax.tree.map(lambda *c: jnp.stack(c), *lists[nd:])))
-    new_cache = dict(cache, k=kc, v=vc, pos=pos + 1)
+    new_cache = dict(cache, **dict(zip(view.names, bufs)), pos=pos + 1)
     return (M.lm_head(cfg, params, x)[:, 0], new_cache, loads.sum(0),
             jnp.sum(loads > 0))
 
 
-def make_paged_chunk_step(cfg: AfmoeConfig, chunk_tokens: int,
+def make_paged_chunk_step(cfg, chunk_tokens: int,
                           top_k: Optional[int] = None,
                           top_p: Optional[float] = None,
                           check_finite: bool = False):
@@ -242,14 +288,14 @@ def make_paged_chunk_step(cfg: AfmoeConfig, chunk_tokens: int,
 # ---------------------------------------------------------------------------
 
 
-def make_paged_prefill_insert(cfg: AfmoeConfig, bucket: int, block_size: int,
+def make_paged_prefill_insert(cfg, bucket: int, block_size: int,
                               top_k: Optional[int] = None,
                               top_p: Optional[float] = None):
     """``paged.make_paged_prefill_insert``'s contract (cold admission: the
-    whole ``[1, bucket]`` prompt forward, its keys and values scattered
-    into the pool as whole blocks at the lane's table entries, the first
-    token sampled).  The real prompt tokens' assignments by expert add
-    to ``cache['moe_pf']``."""
+    whole ``[1, bucket]`` prompt forward, the rows it cached scattered
+    into the pool as whole blocks at the lane's table entries — the
+    pool's view says how — and the first token sampled).  The real prompt
+    tokens' assignments by expert add to ``cache['moe_pf']``."""
     if bucket % block_size:
         raise ValueError(f"prefill bucket {bucket} not a multiple of the "
                          f"block size {block_size}")
@@ -260,13 +306,11 @@ def make_paged_prefill_insert(cfg: AfmoeConfig, bucket: int, block_size: int,
         counted = jnp.arange(bucket)[None, :] < prompt_len
         logits, lane, load = forward(cfg, params, prompt, lane,
                                      head_at=prompt_len - 1,
-                                     counted=counted)
+                                     counted=counted, whole_prompt=True)
         new_cache = dict(
             cache,
-            k=PG.scatter_prompt_blocks(cache["k"], lane["k"], table_row,
-                                       block_size),
-            v=PG.scatter_prompt_blocks(cache["v"], lane["v"], table_row,
-                                       block_size),
+            **PG.view_class(cfg).scatter_prompt(cache, lane, table_row,
+                                                 block_size),
             pos=cache["pos"].at[slot].set(prompt_len),
             moe_pf=cache["moe_pf"] + load)
         key = jax.random.PRNGKey(seed)
@@ -279,7 +323,7 @@ def make_paged_prefill_insert(cfg: AfmoeConfig, bucket: int, block_size: int,
     return jax.jit(insert, donate_argnums=(1, 3, 4, 5))
 
 
-def split_moe(cfg: AfmoeConfig, moe) -> Tuple[Any, int, Any]:
+def split_moe(cfg, moe) -> Tuple[Any, int, Any]:
     """A dispatch's ``moe`` output -> (decode load [E], experts touched,
     prefill load [E])."""
     e = cfg.n_experts

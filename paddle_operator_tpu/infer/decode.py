@@ -239,12 +239,16 @@ def init_cache(cfg: LlamaConfig, batch: int,
         raise ValueError(f"cache max_len {max_len} exceeds the RoPE table "
                          f"(cfg.max_seq_len={cfg.max_seq_len})")
     alloc = cache_alloc_len(max_len)
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, alloc, cfg.head_dim)
-    return {
-        "k": alloc_kv_buffer(cfg, shape, mesh),
-        "v": alloc_kv_buffer(cfg, shape, mesh),
-        "pos": jnp.zeros((), jnp.int32),
-    }
+    # K and V a kv head; an expert stack names its own buffers (a latent
+    # cache holds one row for all heads: models/glm_moe_lite.py)
+    buffers = (cfg.cache_buffers() if hasattr(cfg, "cache_buffers")
+               else {"k": (cfg.n_kv_heads, cfg.head_dim),
+                     "v": (cfg.n_kv_heads, cfg.head_dim)})
+    cache = {name: alloc_kv_buffer(
+        cfg, (cfg.n_layers, batch, heads, alloc, width), mesh)
+        for name, (heads, width) in buffers.items()}
+    cache["pos"] = jnp.zeros((), jnp.int32)
+    return cache
 
 
 # ---------------------------------------------------------------------------
@@ -697,7 +701,8 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
                               "SERVE_ADAPTERS": lora is not None})
         logits, cache, _ = AF.forward(
             cfg, params, tokens, cache,
-            head_at=tokens.shape[1] - 1 if last_only else None)
+            head_at=tokens.shape[1] - 1 if last_only else None,
+            whole_prompt=whole_prompt)
         return logits, cache
     pos = cache["pos"]
     adp, aid = lora if lora is not None else (None, None)

@@ -1412,7 +1412,7 @@ class RingExecutor:
         # sentence (none may run the LLaMA block over its weights)
         from paddle_operator_tpu.infer import afmoe_serve as AF
 
-        self.afmoe = AF.is_afmoe(cfg)
+        self.expert_stack = AF.is_expert_stack(cfg)
         AF.refuse_modes(cfg, {
             "SERVE_PAGED=0": not paged, "SERVE_TP>1": D.mesh_tp(mesh) > 1,
             "SERVE_SPEC_K>0": spec_k, "SERVE_KV_QUANT=int8": self.quant,
@@ -1518,7 +1518,7 @@ class RingExecutor:
         else:
             self.draft_params = None
             self.spec_step = None
-            if self.afmoe:
+            if self.expert_stack:
                 self.step = AF.make_paged_chunk_step(
                     cfg, chunk_tokens, top_k, top_p,
                     check_finite=check_finite)
@@ -1541,11 +1541,11 @@ class RingExecutor:
                                                        top_p, mesh=mesh)
                                 for b in self.buckets}
         # which attention each rung's whole-prompt insert traces, from
-        # the function the trace itself asks (another architecture's
-        # block attends in its own model file, an einsum): static, shown
-        # on /statusz next to the calls by rung
+        # the function the trace itself asks (an expert stack's block
+        # attends in its own model file): static, shown on /statusz next
+        # to the calls by rung
         self.prefill_attn = {
-            b: ("einsum" if self.afmoe
+            b: (AF.prefill_attn_impl(cfg, b) if self.expert_stack
                 else D.prefill_attn_impl(cfg, b, mesh))
             for b in self.buckets}
 
@@ -1627,6 +1627,8 @@ class RingExecutor:
         else:
             self.cache = init_ring_cache(self.cfg, self.slots,
                                          self.max_len, mesh=self.mesh)
+        # bytes a token a layer the cache holds (``cacheRowBytes``)
+        self.cache_row_bytes = PG.cache_row_bytes(self.cache)
         if self.spec_k:
             self.dcache = init_ring_cache(self.draft_cfg, self.slots,
                                           self.max_len, mesh=self.mesh)
@@ -1760,7 +1762,7 @@ class RingExecutor:
                 out = self.step(self.params, self.cache, self.tok,
                                 self.temp, self.keys, active, *plan.lora)
             moe = None
-            if self.afmoe:      # the routing counters, last
+            if self.expert_stack:      # the routing counters, last
                 out, moe = out[:-1], out[-1]
             if self.check_finite:
                 self.cache, self.tok, toks, ok = out
@@ -2196,7 +2198,7 @@ class RingExecutor:
             out = self.step(self.params, cache, tbl, tok, temp, keys,
                             active, *st)
             cache, tok = out[0], out[1]
-            if self.afmoe:
+            if self.expert_stack:
                 # its inserts are executables already (compile_inserts);
                 # the programs warmed below are the LLaMA block's, for
                 # modes that refuse this architecture

@@ -68,6 +68,7 @@ from __future__ import annotations
 
 import functools
 import heapq
+import math
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -1098,19 +1099,20 @@ def init_paged_cache(cfg: LlamaConfig, slots: int, total_blocks: int,
     the TRASH tail: rows that must not land anywhere (prefill pads,
     inactive-lane ticks) redirect there, the per-lane analogue of pool
     block 0.  Everything shards over the kv-head axis."""
-    shape = (cfg.n_layers, total_blocks, cfg.n_kv_heads, block_size,
-             cfg.head_dim)
     if quant == "none":
-        cache = {
-            "k": D.alloc_kv_buffer(cfg, shape, mesh),
-            "v": D.alloc_kv_buffer(cfg, shape, mesh),
-            "pos": jnp.zeros((slots,), jnp.int32),
-        }
+        # the pool's buffers are the view's: K and V a head, or one
+        # latent row for all heads (:class:`LatentPagedView`)
+        shapes = view_class(cfg).pool_shapes(cfg, total_blocks, block_size)
+        cache = {name: D.alloc_kv_buffer(cfg, shape, mesh)
+                 for name, shape in shapes.items()}
+        cache["pos"] = jnp.zeros((slots,), jnp.int32)
         if not isinstance(cfg, LlamaConfig):
             # infer/afmoe_serve.py: prefill assignments by expert since
             # the last decode dispatch read them out
             cache["moe_pf"] = jnp.zeros((cfg.n_experts,), jnp.int32)
         return cache
+    shape = (cfg.n_layers, total_blocks, cfg.n_kv_heads, block_size,
+             cfg.head_dim)
     if quant != "int8":
         raise ValueError(f"kv_quant {quant!r} not in {KV_QUANT_MODES}")
     scale_shape = (cfg.n_layers, total_blocks, cfg.n_kv_heads)
@@ -1130,16 +1132,21 @@ def init_paged_cache(cfg: LlamaConfig, slots: int, total_blocks: int,
 @jax.named_scope("cache_write")
 def _write_token_paged(pool: jax.Array, kv: jax.Array, li: jax.Array,
                        table: jax.Array, pos: jax.Array,
-                       block_size: int) -> jax.Array:
+                       block_size: int, transposed: bool = False
+                       ) -> jax.Array:
     """[L, N, H, bs, D] pool <- [B, H, 1, D] new rows, lane b's row at
     pool block ``table[b, pos_b // bs]`` offset ``pos_b % bs``.  Static
     unroll over lanes for the same reason as decode._write_lane_stacked
-    (a vmapped ragged update lowers to a carry-copying scatter)."""
+    (a vmapped ragged update lowers to a carry-copying scatter).
+    ``transposed``: the pool is [L, N, H, D, bs] and the rows come as
+    columns [B, H, D, 1] (:class:`LatentPagedView`'s rotated keys)."""
     for lane in range(kv.shape[0]):
         blk = table[lane, pos[lane] // block_size]
+        slab = kv[lane][None, None]
+        at = pos[lane] % block_size
         pool = jax.lax.dynamic_update_slice(
-            pool, kv[lane][None, None],
-            (li, blk, 0, pos[lane] % block_size, 0))
+            pool, slab,
+            (li, blk, 0, 0, at) if transposed else (li, blk, 0, at, 0))
     return pool
 
 
@@ -1388,6 +1395,23 @@ class PagedView:
     (:class:`PagedQuantView`)."""
 
     stacked = True
+    names = ("k", "v")      # the pool's buffers, in the order they ride
+
+    @staticmethod
+    def pool_shapes(cfg, total_blocks: int, block_size: int) -> Dict[str, Any]:
+        """The pool's buffers, name -> shape (``init_paged_cache``)."""
+        shape = (cfg.n_layers, total_blocks, cfg.n_kv_heads, block_size,
+                 cfg.head_dim)
+        return {"k": shape, "v": shape}
+
+    @classmethod
+    def scatter_prompt(cls, cache: Dict[str, jax.Array],
+                       lane: Dict[str, jax.Array], table_row: jax.Array,
+                       block_size: int) -> Dict[str, jax.Array]:
+        """A whole-prompt insert's contiguous lane cache into the pool's
+        buffers, whole blocks at the lane's table entries."""
+        return {n: scatter_prompt_blocks(cache[n], lane[n], table_row,
+                                         block_size) for n in cls.names}
 
     def __init__(self, cfg, cache: Dict[str, jax.Array], table: jax.Array,
                  *, limit: Optional[jax.Array] = None,
@@ -1396,7 +1420,7 @@ class PagedView:
         self.cfg, self.mesh = cfg, mesh
         self.cache, self.table, self.pos = cache, table, cache["pos"]
         self.limit, self.lane_mask, self.aligned = limit, lane_mask, aligned
-        self.block_size = cache["k"].shape[3]
+        self.block_size = cache[self.names[0]].shape[3]
 
     def enter(self, t: int) -> None:
         """Fix the forward's kind from its static row count: the decode
@@ -1406,11 +1430,13 @@ class PagedView:
         self.kernel, self.projects, self.interpret = D.decode_kernel_mode(
             self.cfg, self.mesh, self.step)
 
+    def buffers(self):
+        return tuple(self.cache[n] for n in self.names)
+
     def begin(self, t: int):
         self.enter(t)
         self.step_cells = self.cells() if self.kernel else None
-        return ((self.cache["k"], self.cache["v"]),
-                jnp.arange(self.cfg.n_layers))
+        return self.buffers(), jnp.arange(self.cfg.n_layers)
 
     def cells(self, window=None):
         """The decode kernel's work list for this tick (ops/
@@ -1484,8 +1510,93 @@ class PagedView:
         return D._attend_cache(self.cfg, q, *self.lanes(bufs, li), rows)
 
     def end(self, bufs, t: int) -> Dict[str, jax.Array]:
-        kc, vc = bufs
-        return {"k": kc, "v": vc, "pos": self.pos + t}
+        return {**dict(zip(self.names, bufs)), "pos": self.pos + t}
+
+
+class LatentPagedView(PagedView):
+    """:class:`PagedView` over a LATENT pool (multi-head latent
+    attention, models/glm_moe_lite.py): ONE cached row a token a layer
+    for all heads, in two buffers — ``c`` [L, N, 1, bs, C] the normed
+    latents and ``pe`` [L, N, 1, R, bs] the rotated keys, TRANSPOSED
+    (ops/decode_attention.py ``latent_paged_decode_attention`` has the
+    reason: 64 columns are half a lane register, 256 rows are two) — the
+    singleton axis where a K/V pool has its heads, so that block tables,
+    the trash block, the work list and the host's ``PagedCacheManager``
+    are the K/V pool's own.  (C + R) * 2 bytes a token a layer.
+
+    Written for what the expert stack's ring runs at tp 1 with a bf16
+    pool: the decode step's row (``write`` at one token a lane), a
+    whole-prompt insert's blocks (:meth:`scatter_prompt`), the latent
+    kernel over the work list and the gathered lanes for its einsum
+    twin.  Rows written any other way (a suffix insert, a verify, a
+    prefill slice) are refused: nothing that needs them serves this
+    architecture."""
+
+    names = ("c", "pe")
+    REFUSAL = ("the latent pool is written by the decode step and the "
+               "whole-prompt insert at tp 1 only")
+
+    @staticmethod
+    def pool_shapes(cfg, total_blocks: int, block_size: int) -> Dict[str, Any]:
+        lead = (cfg.n_layers, total_blocks, 1)
+        return {"c": lead + (block_size, cfg.kv_lora_rank),
+                "pe": lead + (cfg.qk_rope_head_dim, block_size)}
+
+    @classmethod
+    def scatter_prompt(cls, cache, lane, table_row, block_size):
+        return {
+            "c": scatter_prompt_blocks(cache["c"], lane["c"], table_row,
+                                       block_size),
+            "pe": scatter_prompt_blocks(
+                cache["pe"], lane["pe"].transpose(0, 1, 2, 4, 3), table_row,
+                block_size, axis=4)}
+
+    def __init__(self, cfg, cache, table, **kw) -> None:
+        super().__init__(cfg, cache, table, **kw)
+        if self.limit is not None or self.aligned or D.mesh_tp(self.mesh) > 1:
+            raise ValueError(self.REFUSAL)
+
+    def write(self, bufs, li, c: jax.Array, pe: jax.Array):
+        """The step's rows ``c [B, 1, 1, C]``, ``pe [B, 1, 1, R]``."""
+        if not self.step:
+            raise ValueError(self.REFUSAL)
+        cc, pc = bufs
+        args = (li, self.table, self.pos, self.block_size)
+        return (_write_token_paged(cc, c.transpose(0, 2, 1, 3), *args),
+                _write_token_paged(pc, pe.transpose(0, 2, 3, 1), *args,
+                                   transposed=True))
+
+    def lanes(self, bufs, li) -> Tuple[jax.Array, jax.Array]:
+        """Layer ``li`` as contiguous lanes, ``(c [B, 1, M*bs, C], pe
+        [B, 1, M*bs, R])``: the rotated keys turned back into rows."""
+        cc, pc = bufs
+        b, m = self.table.shape
+        layer = jax.lax.dynamic_index_in_dim(pc, li, 0, keepdims=False)
+        pe = jnp.take(layer, self.table.reshape(-1), axis=0)  # [B*M,1,R,bs]
+        pe = pe.reshape(b, m, *pe.shape[1:]).transpose(0, 2, 1, 4, 3)
+        return (_gather_lane_view(cc, self.table, li),
+                pe.reshape(b, 1, m * self.block_size, -1))
+
+    def kernel_attend(self, bufs, li, q_lat: jax.Array, q_pe: jax.Array,
+                      cells=None):
+        """The latent kernel over the table-mapped blocks: the absorbed
+        queries ``q_lat [B, 1, H, C]``, ``q_pe [B, 1, H, R]`` -> the
+        latent each head attended, ``[B, 1, H, C]``."""
+        from paddle_operator_tpu.ops.decode_attention import (
+            latent_paged_decode_attention,
+        )
+
+        cc, pc = bufs
+        out = latent_paged_decode_attention(
+            q_lat[:, 0], q_pe[:, 0], cc, pc, self.table,
+            scale=self.cfg.head_dim ** -0.5, layer=li,
+            cells=self.step_cells if cells is None else cells,
+            interpret=self.interpret)
+        return out[:, None]
+
+    def attend(self, bufs, li, q, rows, wo):
+        raise ValueError("the latent pool serves models/glm_moe_lite.py's "
+                         "block, not the LLaMA block's cached forward")
 
 
 class PagedQuantView(PagedView):
@@ -1558,12 +1669,28 @@ class PagedQuantView(PagedView):
                 "pos": self.pos + t}
 
 
+def view_class(cfg):
+    """The bf16 pool's view for `cfg`: a configuration that caches one
+    latent row a token (``kv_lora_rank``), or K and V a head."""
+    return LatentPagedView if hasattr(cfg, "kv_lora_rank") else PagedView
+
+
 def paged_view(cfg, cache: Dict[str, jax.Array], table: jax.Array,
                **kw) -> PagedView:
     """The view of a paged cache, chosen from the cache itself: the int8
     pool carries its scales (``ks``)."""
-    return (PagedQuantView if "ks" in cache else PagedView)(
+    return (PagedQuantView if "ks" in cache else view_class(cfg))(
         cfg, cache, table, **kw)
+
+
+def cache_row_bytes(cache: Dict[str, jax.Array]) -> int:
+    """Bytes a token a layer a paged cache's pool holds (``cacheRowBytes``
+    on /statusz): K and V over the kv heads, int8 codes under
+    SERVE_KV_QUANT, or one latent row."""
+    pools = [cache[n] for n in ("k", "v", "c", "pe") if n in cache]
+    block = pools[0].shape[3]
+    return sum(math.prod(p.shape[2:]) * p.dtype.itemsize
+               for p in pools) // block
 
 
 def make_paged_chunk_step(cfg: LlamaConfig, chunk_tokens: int,
@@ -1707,17 +1834,19 @@ def make_paged_megastep(cfg: LlamaConfig, chunk_tokens: int,
 @jax.named_scope("cache_write")
 def scatter_prompt_blocks(pool: jax.Array, lane: jax.Array,
                            table_row: jax.Array,
-                           block_size: int) -> jax.Array:
+                           block_size: int, axis: int = 3) -> jax.Array:
     """Write a contiguous [L, 1, H, bucket, D] prefilled lane cache
     into the pool as block-aligned chunks at the lane's table entries —
     the block-granular prefill-write path, shared with the kernels'
     module (ops/decode_attention.py scatter_prefill_blocks has the
-    whole-block-vs-per-row story)."""
+    whole-block-vs-per-row story).  ``axis``: where the lane's positions
+    lie (4: a transposed buffer, [L, 1, H, D, bucket])."""
     from paddle_operator_tpu.ops.decode_attention import (
         scatter_prefill_blocks,
     )
 
-    return scatter_prefill_blocks(pool, lane, table_row, block_size)
+    return scatter_prefill_blocks(pool, lane, table_row, block_size,
+                                  axis=axis)
 
 
 def paged_prefill(params: Dict[str, Any], cfg: LlamaConfig,

@@ -556,7 +556,7 @@ class ContinuousBatcher:
                       # decode iteration (positions as the host holds
                       # them at the dispatch)
                       "decode_cells_live": 0, "decode_cells_grid": 0,
-                      # routing counters of an expert architecture
+                      # routing counters of an expert stack
                       # (infer/afmoe_serve.py): computed on the device,
                       # added up as each dispatch's results are consumed
                       "moe_layer_steps": 0, "moe_experts_touched": 0,
@@ -1109,6 +1109,10 @@ class ContinuousBatcher:
             # "flash" (the pallas kernel) or "einsum"
             "prefillAttnByBucket": {
                 str(k): v for k, v in self.executor.prefill_attn.items()},
+            # static: bytes a token a layer the cache holds — K and V
+            # over the kv heads, int8 codes, or one latent row — so that
+            # a reader of the pool's fill need not know the architecture
+            "cacheRowBytes": self.executor.cache_row_bytes,
             **self._moe_status(),
             "phaseSeconds": {k: round(v, 6) for k, v in
                              self.phases.self_seconds().items()},

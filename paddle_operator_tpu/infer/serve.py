@@ -239,7 +239,7 @@ def load_serving_params(cfg: LlamaConfig, ckpt, *, seed: int = 0,
     from paddle_operator_tpu.models.llama import Llama, partition_patterns
     from paddle_operator_tpu.train.checkpoint import restore_newest
 
-    if AF.is_afmoe(cfg):        # the preset's type selects the tree
+    if AF.is_expert_stack(cfg):     # the preset's type selects the tree
         AF.refuse_modes(cfg, {"SERVE_TP>1": D.mesh_tp(mesh) > 1})
         return AF.load_params(cfg, ckpt, seed)
     model = Llama(cfg)

@@ -99,6 +99,11 @@ class AfmoeConfig:
     def ropes(self) -> Tuple[bool, ...]:
         return tuple(k == SLIDING for k in self.layer_types)
 
+    def cache_buffers(self) -> Dict[str, Tuple[int, int]]:
+        """The cache's buffers, name -> (heads, width)."""
+        return {"k": (self.n_kv_heads, self.head_dim),
+                "v": (self.n_kv_heads, self.head_dim)}
+
     def resolved_decode_attn(self) -> str:
         """``LlamaConfig.resolved_decode_attn``'s rule: the paged kernel
         on the TPU when the head is lane-aligned, the einsum elsewhere."""
@@ -174,9 +179,14 @@ def param_shapes(cfg: AfmoeConfig) -> Dict[str, Any]:
 
 
 def init_params(cfg: AfmoeConfig, rng: jax.Array) -> Dict[str, Any]:
-    """Smoke-mode weights: N(0, 0.02) matrices, norm scales 1, the
-    routing bias 0 (the published buffer's starting value)."""
-    shapes = param_shapes(cfg)
+    """Smoke-mode weights (:func:`init_tree`)."""
+    return init_tree(param_shapes(cfg), rng)
+
+
+def init_tree(shapes: Dict[str, Any], rng: jax.Array) -> Dict[str, Any]:
+    """Smoke-mode weights for a tree of shapes: N(0, 0.02) matrices, norm
+    scales 1, the routing bias 0 (the published buffer's starting
+    value)."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     keys = jax.random.split(rng, len(leaves))
 
@@ -297,6 +307,38 @@ def attend(cfg: AfmoeConfig, q: jax.Array, k_all: jax.Array,
     return out.reshape(b, t, cfg.n_heads * d).astype(cfg.dtype)
 
 
+def layer_kinds(cfg: AfmoeConfig) -> tuple:
+    """What differs from layer to layer besides the weights, one tuple a
+    quantity: the window and whether q and k are rotated."""
+    return cfg.windows(), cfg.ropes()
+
+
+def kernel_windows(cfg: AfmoeConfig) -> tuple:
+    """Per layer the window of the decode kernel's work list."""
+    return cfg.windows()
+
+
+def whole_prompt_flash(cfg: AfmoeConfig) -> bool:
+    """The window mask and the gate are written over the einsum only."""
+    return False
+
+
+def attention(cfg: AfmoeConfig, lp, x: jax.Array, tables, q_pos, view, bufs,
+              li, kind, cells=None, flash: bool = False, blocks=None):
+    """The attention half of a block over a cache view -> ``(a, the
+    view's buffers after the write)``: the view's decode kernel where it
+    is on, else :func:`attend` over the view's lanes.  `kind` is the
+    layer's ``(window, use_rope)`` (:func:`layer_kinds`; may be traced)."""
+    window, use_rope = kind
+    q, k, v, g = attn_inputs(cfg, lp, x, *tables, q_pos, use_rope)
+    bufs = view.write(bufs, li, k, v)
+    if view.kernel:
+        att = view.kernel_attend(bufs, li, q, cells=cells)
+    else:
+        att = attend(cfg, q, *view.lanes(bufs, li), q_pos, window)
+    return attn_residual(cfg, lp, x, att, g), bufs
+
+
 def swiglu(x: jax.Array, w, dtype) -> jax.Array:
     gate = mm(x, w["w1"]["kernel"], dtype)
     up = mm(x, w["w3"]["kernel"], dtype)
@@ -350,11 +392,15 @@ def grouped_matmul(lhs: jax.Array, stack: jax.Array, layer, sizes: jax.Array,
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
         tm, tk, tn = GMM_TILING
+        # the widest tile of output columns, a multiple of 128, that
+        # divides n (1,536 columns: 768): the kernel masks a ragged last
+        # tile of k, not of n
+        tn = next(w for w in range(min(tn, n), 0, -128) if n % w == 0)
         all_sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((n_layers * g,), jnp.int32), sizes, (layer * g,))
         return gmm(lhs, stack.reshape(n_layers * g, k, n), all_sizes,
                    preferred_element_type=out_dtype,
-                   tiling=(min(tm, m), min(tk, k), min(tn, n)))
+                   tiling=(min(tm, m), min(tk, k), tn))
     return jax.lax.ragged_dot(lhs, stack[layer], sizes,
                               preferred_element_type=out_dtype)
 

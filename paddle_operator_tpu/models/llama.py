@@ -192,8 +192,10 @@ CONFIGS = {
 # Presets of other architectures live beside LLaMA's, where MODEL_PRESET is
 # looked up; the preset's type selects the code that serves it.
 from paddle_operator_tpu.models.afmoe import CONFIGS as _AFMOE_CONFIGS  # noqa: E402
+from paddle_operator_tpu.models.glm_moe_lite import CONFIGS as _GLM_LITE_CONFIGS  # noqa: E402
 
 CONFIGS.update(_AFMOE_CONFIGS)
+CONFIGS.update(_GLM_LITE_CONFIGS)
 
 
 # ---------------------------------------------------------------------------
@@ -528,6 +530,7 @@ def make_model(preset: str = "tiny", mesh=None, **overrides) -> Tuple[Llama, Lla
     if not isinstance(cfg, LlamaConfig):
         raise ValueError(
             f"preset {preset!r} ({type(cfg).__name__}) is served only "
-            "(infer/afmoe_serve.py): the trainer has no dropless expert "
-            "layer with a backward pass and no routing-bias update")
+            "(infer/afmoe_serve.py, the expert stack): the trainer has no "
+            "dropless expert layer with a backward pass and no "
+            "routing-bias update")
     return Llama(cfg, mesh), cfg

@@ -618,6 +618,172 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     )(cells.rows, *extra, qt, k_pool, v_pool, *quant_operands)
 
 
+def _latent_cells_kernel(cells_ref, *refs, scale: float, block_k: int,
+                         stacked: bool):
+    """:func:`_cells_kernel` for a LATENT pool: the cell's tiles are ONE
+    block of cached rows — the latents ``[block_k, C]`` and the rotated
+    keys, stored transposed, ``[R, block_k]`` — read once for every head:
+    the scores are the heads' (absorbed) queries against both, the values
+    the latents again.  Nothing is masked off by head: every product of
+    the matmuls is wanted."""
+    if stacked:
+        refs = refs[1:]
+    ql_ref, qp_ref, c_ref, pe_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    tile = (0, 0, 0) if stacked else (0, 0)  # the block's leading 1s
+    c = pl.program_id(0)
+    ik, flags = cells_ref[CELL_INDEX, c], cells_ref[CELL_FLAGS, c]
+    length = cells_ref[CELL_LEN, c]
+
+    @pl.when((flags & CELL_FIRST) != 0)
+    def _init():
+        _init_softmax(acc_ref, m_ref, l_ref)
+
+    @pl.when(ik * block_k < length)
+    def _compute():
+        lat = c_ref[tile]                                    # [bk, C]
+        s = (jax.lax.dot_general(ql_ref[0], lat, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qp_ref[0], pe_ref[tile],
+                                   (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+             ) * scale                                       # [hq, bk]
+        pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < length, s, NEG_INF)
+        m_prev = m_ref[:, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, None])
+        l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=1)
+        m_ref[:, 0] = m_new
+        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
+            p.astype(lat.dtype), lat, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [hq, C]
+
+    @pl.when((flags & CELL_LAST) != 0)
+    def _finish():
+        _finish_softmax(o_ref, acc_ref, m_ref, l_ref)
+
+
+@jax.named_scope("attn.kernel")
+def latent_paged_decode_attention(q_lat: jax.Array, q_pe: jax.Array,
+                                  c_pool: jax.Array, pe_pool: jax.Array,
+                                  block_table: jax.Array,
+                                  lengths: Optional[jax.Array] = None, *,
+                                  scale: float,
+                                  layer: Optional[jax.Array] = None,
+                                  interpret: bool = False,
+                                  cells: Optional[DecodeCells] = None
+                                  ) -> jax.Array:
+    """The decode step of LATENT (MLA) attention in its absorbed form,
+    over a paged pool of cached rows: every head attends the SAME rows,
+    which are key and value at once.
+
+    q_lat: [B, Hq, C] — a head's query against the latent itself (the
+    keys' up-projection folded into it, models/glm_moe_lite.py
+    ``absorb_query``) — and q_pe: [B, Hq, R], its rotated part; c_pool:
+    [N, 1, bs, C] the cached latents, pe_pool: [N, 1, R, bs] the one
+    rotated key a token, TRANSPOSED (both stacked with a leading L under
+    ``layer``; the singleton axis is where a K/V pool has its heads);
+    block_table, lengths, cells: as :func:`paged_decode_attention`.
+    Returns [B, Hq, C]: ``softmax((q_lat . c + q_pe . k_pe) * scale) @
+    c`` — the caller's down-projection turns it into values.
+
+    Why the rotated keys lie transposed: a ``[bs, 64]`` tile (or one
+    ``[bs, 576]`` row of both) is not a whole number of 128-lane
+    registers, so XLA pads it in HBM to 128 (640) columns or lays the
+    pool out column-major and copies it for every call; ``[64, bs]`` is
+    dense as it stands, the pool holds exactly (C + R) * 2 bytes a token,
+    and the scores' second product needs no transpose.
+
+    The grid is :func:`paged_decode_attention`'s work list of live
+    (lane, block) cells.  A cell's tiles are fetched once for all heads:
+    (C + R) * 2 bytes a cached token where expanded keys and values would
+    be ``Hq * (K + V) * 2`` — 1,152 against 20,480 at GLM-4.7-Flash's
+    sizes — and 2 * Hq * (2 C + R) operations, 38 a byte at 20 heads, so
+    the call is bound by bytes.  The heads are padded to a whole tile of
+    sublanes here (zero queries, dropped rows).
+
+    Written for a bf16 pool at tp 1 and a latent of a multiple of 128
+    columns; anything else is refused."""
+    b, hq, c = q_lat.shape
+    r = q_pe.shape[2]
+    stacked = layer is not None
+    block_k = c_pool.shape[-2]
+    want_c, want_pe = (1, block_k, c), (1, r, block_k)
+    if (tuple(c_pool.shape[-3:]) != want_c
+            or tuple(pe_pool.shape[-3:]) != want_pe
+            or c_pool.ndim != 4 + stacked or (c % 128 and not interpret)):
+        raise ValueError(
+            f"latent_paged_decode_attention is written for pools [.., 1, "
+            f"bs, C] and [.., 1, R, bs] matching q_lat [B, H, C] and q_pe "
+            f"[B, H, R] with C a multiple of 128 (got {tuple(c_pool.shape)}"
+            f", {tuple(pe_pool.shape)}, {tuple(q_lat.shape)}, "
+            f"{tuple(q_pe.shape)}); use decode_attn='xla' for this config")
+    if cells is None:
+        cells = decode_cells(block_table, lengths, block_k)
+    hp = -(-hq // 16) * 16
+    if hp != hq:
+        pad = ((0, 0), (0, hp - hq), (0, 0))
+        q_lat, q_pe = jnp.pad(q_lat, pad), jnp.pad(q_pe, pad)
+
+    def lane_map(i, cells, *_):
+        return (cells[CELL_LANE, i], 0, 0)
+
+    if stacked:
+        lay = jnp.reshape(layer, (1,)).astype(jnp.int32)
+        pool_map = lambda i, cells, lay: (lay[0], cells[CELL_BLOCK, i],
+                                          0, 0, 0)
+        lead, extra = (1, 1), (lay,)
+    else:
+        pool_map = lambda i, cells: (cells[CELL_BLOCK, i], 0, 0, 0)
+        lead, extra = (1,), ()
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1 + len(extra),
+        grid=(cells.n,),
+        in_specs=[pl.BlockSpec((1, hp, c), lane_map),
+                  pl.BlockSpec((1, hp, r), lane_map),
+                  pl.BlockSpec(lead + want_c, pool_map),
+                  pl.BlockSpec(lead + want_pe, pool_map)],
+        out_specs=pl.BlockSpec((1, hp, c), lane_map),
+        scratch_shapes=[
+            pltpu.VMEM((hp, c), jnp.float32),             # acc
+            pltpu.VMEM((hp, 128), jnp.float32),           # m (col 0 live)
+            pltpu.VMEM((hp, 128), jnp.float32),           # l (col 0 live)
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_cells_kernel, scale=scale, block_k=block_k,
+                          stacked=stacked),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hp, c), q_lat.dtype),
+        interpret=interpret,
+    )(cells.rows, *extra, q_lat, q_pe, c_pool, pe_pool)
+    return out[:, :hq]
+
+
+def latent_decode_attention_reference(q_lat: jax.Array, q_pe: jax.Array,
+                                      lat: jax.Array, pe: jax.Array,
+                                      lengths: jax.Array,
+                                      scale: float) -> jax.Array:
+    """:func:`latent_paged_decode_attention`'s einsum twin over
+    CONTIGUOUS lanes — the CPU's path and what the kernel is pinned
+    against: q_lat [B, Hq, C], q_pe [B, Hq, R], the lanes' cached rows
+    lat [B, S, C] and pe [B, S, R] (a pool gathered through its table, or
+    a contiguous cache), lane b attending rows ``[0, lengths[b])``.
+    Returns [B, Hq, C]; zeros for a lane with nothing to attend, like the
+    kernel."""
+    scores = (jnp.einsum("bhc,bsc->bhs", q_lat, lat,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhr,bsr->bhs", q_pe, pe,
+                           preferred_element_type=jnp.float32)) * scale
+    mask = (jnp.arange(lat.shape[1])[None, :] < lengths[:, None])[:, None]
+    probs = jax.nn.softmax(jnp.where(mask, scores, NEG_INF), axis=-1)
+    probs = jnp.where(mask, probs, 0.0)
+    out = jnp.einsum("bhs,bsc->bhc", probs.astype(lat.dtype), lat,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q_lat.dtype)
+
+
 def sharded_paged_decode_attention(mesh, q: jax.Array, k_pool: jax.Array,
                                    v_pool: jax.Array,
                                    block_table: jax.Array,
@@ -776,10 +942,13 @@ def sharded_decode_attention(mesh, q: jax.Array, k_cache: jax.Array,
 
 def scatter_prefill_blocks(pool: jax.Array, rows: jax.Array,
                            table_row: jax.Array, block_size: int,
-                           start_block: int = 0) -> jax.Array:
+                           start_block: int = 0, axis: int = 3
+                           ) -> jax.Array:
     """The prefill-WRITE path against the block pool: place a
     contiguous slab of freshly prefilled KV rows
-    (``[L, 1, H, T, D]``, T a multiple of ``block_size``) into the pool
+    (``[L, 1, H, T, D]``, T a multiple of ``block_size``; with ``axis``
+    4 a transposed slab ``[L, 1, H, D, T]`` into a pool of transposed
+    blocks) into the pool
     as WHOLE-block writes at the lane's table entries, starting at
     lane-local block ``start_block``.
 
@@ -796,10 +965,10 @@ def scatter_prefill_blocks(pool: jax.Array, rows: jax.Array,
     otherwise, where every row is overwritten before it becomes
     attendable (the exactness-with-padding contract, block-granular).
     """
-    t = rows.shape[3]
+    t = rows.shape[axis]
     for j in range(t // block_size):
         blk = jax.lax.slice_in_dim(rows, j * block_size,
-                                   (j + 1) * block_size, axis=3)
+                                   (j + 1) * block_size, axis=axis)
         pool = jax.lax.dynamic_update_slice(
             pool, blk, (0, table_row[start_block + j], 0, 0, 0))
     return pool

@@ -260,6 +260,11 @@ def _serving_gauges_one(status_serving: dict, job: str,
             float(status_serving.get("prefixHitRate", 0.0)),
         f"tpujob_serve_kv_blocks_free{lbl}":
             float(status_serving.get("kvBlocksFree", 0.0)),
+        # bytes a token a layer the cache holds (K and V over the kv
+        # heads, int8 codes, or one latent row): blocks x block size x
+        # layers x this is the pool's bytes, whatever the architecture
+        f"tpujob_serve_cache_row_bytes{lbl}":
+            float(status_serving.get("cacheRowBytes", 0.0)),
         # prefill path (ISSUE 6 scheduler/executor split): requests
         # admitted but still prefilling (chunked slices mid-flight or
         # disagg jobs on the prefill executor), labeled with the ring's

@@ -149,6 +149,8 @@ class TestGaugeNaming:
             'tpujob_serve_queue_depth{job="default/j"}',
             'tpujob_serve_prefix_hit_rate{job="default/j"}',
             'tpujob_serve_kv_blocks_free{job="default/j"}',
+            # ISSUE 32: bytes a token a layer the cache holds
+            'tpujob_serve_cache_row_bytes{job="default/j"}',
             'tpujob_serve_prefill_queue_depth'
             '{job="default/j",mode="chunked"}',
             'tpujob_serve_chunked_prefill_token_share'
@@ -413,6 +415,9 @@ class TestBatcherServingStatus:
                            "chunkedPrefillTokenShare",
                            # quantized-pool block (ISSUE 7)
                            "kvQuantMode", "kvPoolBytes",
+                           # bytes a token a layer the cache holds
+                           # (ISSUE 32: K and V, codes, or a latent row)
+                           "cacheRowBytes",
                            # weight-quant block (ISSUE 16)
                            "weightQuantMode", "draftQuantMode",
                            "paramBytes",
