@@ -1227,9 +1227,8 @@ def measure_qos(cfg, params, *, slots: int = 2, prompt_len: int = 16,
     padded[0, :] = p
     import jax.numpy as jnp
 
-    ex.cache, ex.tok, ex.temp, ex.keys, _ = ex.inserts[prompt_len](
-        ex.params, ex.cache, jnp.asarray(ex.pool.table[0]), ex.tok,
-        ex.temp, ex.keys, jnp.asarray(padded), len(p), 0, 0.0, 0)
+    ex.cold_insert(prompt_len, 0, ex.pool.table, [], jnp.asarray(padded),
+                   len(p), 0.0, 0)
     cycles = []
     for _ in range(max(3, probes // 2)):
         t0 = time.perf_counter()

@@ -604,6 +604,18 @@ def prefill_attn_impl(cfg: LlamaConfig, width: int, mesh=None) -> str:
     return "flash" if flash else "einsum"
 
 
+def _flash_prompt_attn(cfg: LlamaConfig, q: jax.Array, k: jax.Array,
+                       v: jax.Array, mesh=None) -> jax.Array:
+    """Causal self-attention of a whole prompt's own q, k, v
+    ``[B, T, H, D]`` through the flash kernel -> ``[B, T, Hq*D]``."""
+    from paddle_operator_tpu.ops.attention import attention
+
+    out = attention(q, k, v, causal=True, use_pallas=True, mesh=mesh,
+                    blocks=_prefill_blocks(q.shape[1]))
+    return out.reshape(*q.shape[:2], cfg.n_heads * cfg.head_dim).astype(
+        cfg.dtype)
+
+
 def _prefill_layer(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
                    cos: jax.Array, sin: jax.Array, k_cache: jax.Array,
                    v_cache: jax.Array, pos: jax.Array, lora=None, mesh=None
@@ -617,14 +629,10 @@ def _prefill_layer(cfg: LlamaConfig, lp: Dict[str, Any], x: jax.Array,
     cache (the lane's decode steps read them); pad positions are
     computed and thrown away as on the einsum path (a real row never
     sees a pad: pads sit after it)."""
-    from paddle_operator_tpu.ops.attention import attention
-
     q, k, v = _qkv(cfg, lp, x, cos, sin, pos, lora=lora)
     k_cache, v_cache = _write_rows(k_cache, v_cache, k, v, pos)
-    out = attention(q, k, v, causal=True, use_pallas=True, mesh=mesh,
-                    blocks=_prefill_blocks(x.shape[1]))
-    out = out.reshape(*x.shape[:2], cfg.n_heads * cfg.head_dim)
-    return _finish_layer(cfg, lp, x, out.astype(cfg.dtype)), k_cache, v_cache
+    out = _flash_prompt_attn(cfg, q, k, v, mesh)
+    return _finish_layer(cfg, lp, x, out), k_cache, v_cache
 
 
 def _moe_ffn(cfg: LlamaConfig, mp: Dict[str, Any],
@@ -661,8 +669,8 @@ def _moe_ffn(cfg: LlamaConfig, mp: Dict[str, Any],
 
 def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
              cache: Dict[str, jax.Array], *, last_only: bool = False,
-             mesh=None, lora=None, whole_prompt: bool = False
-             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+             mesh=None, lora=None, whole_prompt: bool = False,
+             head_at=None) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """[B, T] new tokens at cache['pos'] -> ([B, T, vocab] logits,
     advanced cache).  Layers run under lax.scan over the stacked params
     (the same ``layers`` layout nn.scan trains).
@@ -671,6 +679,10 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
     (logits [B, 1, vocab]) — prefill needs just the next-token logits,
     and head logits over a whole long prompt are the biggest tensor in
     the decode path ([B, S, V] f32 — gigabytes at real vocab sizes).
+    ``head_at`` (an index among the T, may be traced): the same at that
+    one position — a padded prompt's last REAL token (the whole-prompt
+    inserts; XLA does not push a slice of the logits through the head's
+    product, so slicing after it costs the head over all T rows).
 
     ``mesh``: a serving mesh with a tp axis (make_serving_mesh) makes
     the whole forward tensor-parallel: the einsum/matmul structure rides
@@ -701,7 +713,8 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
                               "SERVE_ADAPTERS": lora is not None})
         logits, cache, _ = AF.forward(
             cfg, params, tokens, cache,
-            head_at=tokens.shape[1] - 1 if last_only else None,
+            head_at=(tokens.shape[1] - 1 if last_only and head_at is None
+                     else head_at),
             whole_prompt=whole_prompt)
         return logits, cache
     pos = cache["pos"]
@@ -770,7 +783,9 @@ def _forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
 
         x, (k_new, v_new) = jax.lax.scan(
             body, x, (params["layers"], adp, (cache["k"], cache["v"])))
-    if last_only:
+    if head_at is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, head_at, 1, axis=1)
+    elif last_only:
         x = x[:, -1:]
     logits = _lm_head(cfg, params, x)
     new_cache = {"k": k_new, "v": v_new,
@@ -877,6 +892,75 @@ def cached_step(cfg: LlamaConfig, params: Dict[str, Any], tok: jax.Array,
     x, bufs = _cached_layers(cfg, params, tok[:, None], view, lora)
     logits = _lm_head(cfg, params, x)[:, 0]
     return logits, view.end(bufs, 1)
+
+
+def prefill_with_step(cfg: LlamaConfig, params: Dict[str, Any],
+                      prompt: jax.Array, prompt_len: jax.Array,
+                      tok: jax.Array, view, *, mesh=None
+                      ) -> Tuple[jax.Array, Dict[str, jax.Array],
+                                 Dict[str, jax.Array]]:
+    """A whole-prompt prefill of ``prompt [1, W]`` AND one decode step
+    of the view's ``tok [B]`` lanes in ONE scan over the layers: the
+    lanes' rows ride the prompt's read of ``wo``, the feed-forward's
+    three matrices and the head — nine tenths of a layer's weight bytes
+    — concatenated to the prompt's rows at those products (an insert
+    reads every layer once for W rows; a step of its own reads them all
+    again for B).  Before attention the two stay apart, each through its
+    own norm and q/k/v products: the prompt as :func:`_forward` has it
+    for ``whole_prompt`` — :func:`_qkv` from position 0, its own lane
+    cache, the flash kernel where :func:`prefill_attn_impl` says so,
+    else the einsum over the cache just written — and each lane as
+    :func:`_cached_layer` has it: rotated at its own ``view.pos``, its
+    row written through the view, attention by the decode kernel over
+    the tick's work list, or the einsum.  (One q/k/v product over all
+    the rows was measured: the lanes' kernel asks another layout of the
+    split heads than the flash kernel, and the compiler then copies and
+    re-rotates the prompt's q a layer, 18 ms an insert at 3072 where the
+    second read of the three matrices is under one: PERF.md section 6,
+    PR 33.)  The head runs once over ``1 + B`` rows: the prompt's hidden
+    state at ``prompt_len - 1``, sliced out before the final norm, and
+    the lanes'.
+
+    Returns ``(logits [1 + B, vocab], the prompt's lane cache {k, v}
+    [L, 1, H_kv, alloc, D], the view's cache one row a lane further)``;
+    which lanes the step counts for is the view's ``lane_mask`` and the
+    caller's business, as in the ring's step."""
+    w, b = prompt.shape[1], tok.shape[0]
+    lane = init_cache(cfg, 1, w)
+    pos0 = lane["pos"]
+    x = jnp.concatenate([_embed(cfg, params, prompt),
+                         _embed(cfg, params, tok[None, :])], axis=1)
+    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
+                                cfg.rope_theta)
+    flash = prefill_attn_impl(cfg, w, mesh) == "flash"
+    held, layer_ids = view.begin(1)
+
+    def body(carry, layer_in):
+        x, held = carry
+        lp, (k_c, v_c), li = layer_in
+        # the prompt's rows: rotation from 0, its own lane cache
+        q, k, v = _qkv(cfg, lp, x[:, :w], cos, sin, pos0)
+        k_c, v_c = _write_rows(k_c, v_c, k, v, pos0)
+        out_p = (_flash_prompt_attn(cfg, q, k, v, mesh) if flash
+                 else _attend_cache(cfg, q, k_c, v_c, pos0))
+        # the lanes' rows: one a lane, at the lane's own position
+        h = _rms(x[0, w:, None], lp["attn_norm"]["scale"], cfg.norm_eps,
+                 cfg.dtype)
+        q, k, v = _qkv_proj(cfg, lp, h, 1)
+        q, k = _rope_lanes(q, k, cos, sin, view.pos)
+        held = view.write(held, li, k, v)
+        out_d = view.attend(held, li, q, view.pos,
+                            lp["attn"]["wo"]["kernel"])
+        out = jnp.concatenate([out_p, out_d.reshape(1, b, -1)], axis=1)
+        return (_finish_layer(cfg, lp, x, out), held), (k_c, v_c)
+
+    (x, held), (lane_k, lane_v) = jax.lax.scan(
+        body, (x, held),
+        (params["layers"], (lane["k"], lane["v"]), layer_ids))
+    last = jax.lax.dynamic_slice_in_dim(x, prompt_len - 1, 1, axis=1)
+    logits = _lm_head(cfg, params,
+                      jnp.concatenate([last, x[:, w:]], axis=1))[0]
+    return logits, {"k": lane_k, "v": lane_v}, view.end(held, 1)
 
 
 def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,

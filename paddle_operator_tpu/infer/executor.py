@@ -108,16 +108,20 @@ class DispatchResult:
     ``counts`` the host-consumable row counts ([B] spec at N=1,
     [n, B] fused, None plain-1-step); ``raw`` the spec rounds' device
     commit counts (acceptance telemetry); ``ok`` the isfinite
-    verdicts (check_finite only)."""
+    verdicts (check_finite only); ``rows`` the most positions a lane
+    advances in it (what the plan's block projection counts for a
+    dispatch in flight: every fused iteration's chunk or speculated
+    block, and 1 for the step an insert carried)."""
 
-    __slots__ = ("toks", "counts", "ok", "raw", "n_steps", "moe")
+    __slots__ = ("toks", "counts", "ok", "raw", "n_steps", "rows", "moe")
 
-    def __init__(self, toks, counts, ok, raw, n_steps, moe=None):
+    def __init__(self, toks, counts, ok, raw, n_steps, rows, moe=None):
         self.toks = toks
         self.counts = counts
         self.ok = ok
         self.raw = raw
         self.n_steps = n_steps
+        self.rows = rows
         # routing counters of an expert architecture's dispatch
         # (infer/afmoe_serve.py make_paged_chunk_step), else None
         self.moe = moe
@@ -1531,7 +1535,7 @@ class RingExecutor:
                     check_finite=check_finite, quant=self.quant)
                 self.inserts = {b: self._pg.make_paged_prefill_insert(
                     cfg, b, self.block_size, top_k, top_p, mesh=mesh,
-                    quant=self.quant)
+                    quant=self.quant, check_finite=check_finite)
                     for b in self.buckets}
             else:
                 self.step = make_chunk_step(cfg, chunk_tokens, top_k,
@@ -1540,6 +1544,17 @@ class RingExecutor:
                 self.inserts = {b: make_prefill_insert(cfg, b, top_k,
                                                        top_p, mesh=mesh)
                                 for b in self.buckets}
+        # the LLaMA paged ring's cold inserts take the whole block table
+        # and a lane mask (paged.make_paged_prefill_insert); every other
+        # insert of a paged ring takes the inserted slot's row
+        self.insert_takes_ring = (self.paged and not self.spec_k
+                                  and not self.expert_stack)
+        # ... and the rungs whose program carries a decode step of the
+        # live lanes: the scheduler names the lanes that ride it
+        self.insert_steps = frozenset(
+            b for b in self.buckets if self.insert_takes_ring
+            and PG.insert_carries_step(b, mesh, self.quant,
+                                       adapters is not None))
         # which attention each rung's whole-prompt insert traces, from
         # the function the trace itself asks (an expert stack's block
         # attends in its own model file): static, shown on /statusz next
@@ -1745,6 +1760,8 @@ class RingExecutor:
         the N=1 stream is byte-identical to the pre-refactor ring)."""
         active = jnp.asarray(plan.active, bool)
         tbl = jnp.asarray(plan.table) if plan.table is not None else None
+        rows = plan.n_steps * (self.spec_k + 1 if self.spec_k
+                               else self.chunk)
         if plan.n_steps == 1:
             if self.spec_k:
                 spec_args = (self.params, self.draft_params, self.cache,
@@ -1754,7 +1771,7 @@ class RingExecutor:
                 (self.cache, self.dcache, self.tok, toks,
                  counts) = self.spec_step(
                     *spec_args, self.tok, self.temp, self.keys, active)
-                return DispatchResult(toks, counts, None, counts, 1)
+                return DispatchResult(toks, counts, None, counts, 1, rows)
             if self.paged:
                 out = self.step(self.params, self.cache, tbl, self.tok,
                                 self.temp, self.keys, active, *plan.lora)
@@ -1768,7 +1785,7 @@ class RingExecutor:
                 self.cache, self.tok, toks, ok = out
             else:
                 (self.cache, self.tok, toks), ok = out, None
-            return DispatchResult(toks, None, ok, None, 1, moe)
+            return DispatchResult(toks, None, ok, None, 1, rows, moe)
         prog = self.megastep_prog(plan.n_steps)
         eos = jnp.asarray(plan.eos, jnp.int32)
         left = jnp.asarray(plan.left, jnp.int32)
@@ -1781,7 +1798,7 @@ class RingExecutor:
             (self.cache, self.dcache, self.tok, toks, raw,
              counts) = prog(*spec_args, self.tok, self.temp, self.keys,
                             active, eos, left, steps)
-            return DispatchResult(toks, counts, None, raw, plan.n_steps)
+            return DispatchResult(toks, counts, None, raw, plan.n_steps, rows)
         if self.paged:
             out = prog(self.params, self.cache, tbl, self.tok, self.temp,
                        self.keys, active, eos, left, steps, *plan.lora)
@@ -1792,7 +1809,7 @@ class RingExecutor:
             self.cache, self.tok, toks, counts, oks = out
         else:
             (self.cache, self.tok, toks, counts), oks = out, None
-        return DispatchResult(toks, counts, oks, None, plan.n_steps)
+        return DispatchResult(toks, counts, oks, None, plan.n_steps, rows)
 
     # -- lazily-compiled admission programs --------------------------------
 
@@ -2117,16 +2134,50 @@ class RingExecutor:
 
     # -- every rung ready before the ring is ------------------------------
 
-    def _insert_operands(self, cache, dcache, row, tok, temp, keys,
-                         prompt, tail) -> tuple:
+    def _insert_operands(self, cache, dcache, tok, temp, keys, prompt,
+                         tail, make=jnp.zeros) -> tuple:
         """A whole-prompt insert's operands in the order every call
-        site passes them (scheduler ``_admit``/``_admit_paged``), for
-        this ring's mode: prompt length 1, lane 0, greedy, seed 0."""
+        site passes them (scheduler ``_admit``, :meth:`cold_insert`),
+        for this ring's mode: prompt length 1, lane 0, greedy, seed 0,
+        an empty table and no lane riding (``make(shape, dtype)``
+        builds them: zeros, or ``jax.ShapeDtypeStruct`` to lower
+        from)."""
         head = ((self.params, self.draft_params, cache, dcache)
                 if self.spec_k else (self.params, cache))
-        if self.paged:
-            head += (row,)
-        return head + (tok, temp, keys, prompt, 1, 0, 0.0, 0) + tuple(tail)
+        lanes = (tok, temp, keys)
+        if self.insert_takes_ring:
+            head += (make((self.slots, self.pool.max_blocks), jnp.int32),)
+            lanes += (make((self.slots,), bool),)
+        elif self.paged:
+            head += (make((self.pool.max_blocks,), jnp.int32),)
+        return head + lanes + (prompt, 1, 0, 0.0, 0) + tuple(tail)
+
+    def cold_insert(self, bucket: int, slot: int, table, riders,
+                    prompt, n: int, temp_val: float, seed: int,
+                    aid: int = 0):
+        """Dispatch the paged ring's cold (whole-prompt) insert for
+        ``slot`` and take the ring state it returns.  ``table`` is the
+        pool's host table, ``riders`` the lanes whose next token the
+        insert's carried step may advance (none for a program that takes
+        no lane mask).  Returns ``(first, res)``: the first token's
+        device scalar and — where the program returned the lanes' tokens
+        — a one-step :class:`DispatchResult` (``toks [1, B]``) for the
+        scheduler's pipeline, else None."""
+        if self.insert_takes_ring:
+            active = np.zeros((self.slots,), bool)
+            active[list(riders)] = True
+            tbl, mask = jnp.asarray(table), (jnp.asarray(active),)
+        else:
+            tbl, mask = jnp.asarray(table[slot]), ()
+        out = self.inserts[bucket](
+            self.params, self.cache, tbl, self.tok, self.temp, self.keys,
+            *mask, prompt, n, slot, temp_val, seed,
+            *self.lora_insert_tail(aid))
+        self.cache, self.tok, self.temp, self.keys, first = out[:5]
+        if len(out) == 5:
+            return first, None
+        ok = out[6] if self.check_finite else None
+        return first, DispatchResult(out[5], None, ok, None, 1, 1)
 
     def compile_inserts(self) -> None:
         """Make every rung's insert an executable NOW: lowered from the
@@ -2144,14 +2195,13 @@ class RingExecutor:
         a new checkpoint's tree differs."""
         if self.prefill_mode != "inline" or self._insert_jits:
             return
-        row = (jax.ShapeDtypeStruct((self.pool.max_blocks,), jnp.int32)
-               if self.paged else None)
         tail = self.lora_insert_tail(0)
         jits = dict(self.inserts)
         for b, fn in jits.items():
             operands = self._insert_operands(
-                self.cache, self.dcache, row, self.tok, self.temp,
-                self.keys, jax.ShapeDtypeStruct((1, b), jnp.int32), tail)
+                self.cache, self.dcache, self.tok, self.temp, self.keys,
+                jax.ShapeDtypeStruct((1, b), jnp.int32), tail,
+                make=jax.ShapeDtypeStruct)
             self.inserts[b] = fn.lower(
                 *jax.tree.map(_abstract, operands)).compile()
         self._insert_jits = jits
@@ -2229,13 +2279,11 @@ class RingExecutor:
                 out = prog(self.params, cache, tok, temp, keys, active,
                            eos, left, stp, *st)
                 cache, tok = out[0], out[1]
-        row = (jnp.zeros((self.pool.max_blocks,), jnp.int32)
-               if self.paged else None)
         for b in self.buckets:
             if b in self._insert_jits:
                 continue        # an executable already (compile_inserts)
             out = self.inserts[b](*self._insert_operands(
-                cache, dcache, row, tok, temp, keys,
+                cache, dcache, tok, temp, keys,
                 jnp.zeros((1, b), jnp.int32), it))
             if self.spec_k:
                 cache, dcache, tok, temp, keys = out[:5]

@@ -1853,7 +1853,8 @@ def paged_prefill(params: Dict[str, Any], cfg: LlamaConfig,
                   tokens: jax.Array, pool_cache: Dict[str, jax.Array],
                   table_row: jax.Array, *, block_size: Optional[int] = None,
                   mesh=None, quant: bool = False,
-                  prompt_len: Optional[jax.Array] = None, lora=None):
+                  prompt_len: Optional[jax.Array] = None, lora=None,
+                  head_at=None):
     """Prefill a whole [1, bucket] prompt and write its KV into the
     PAGED block pool as block-aligned chunks at the
     lane's ``table_row`` entries — the cold-admission half of paged
@@ -1867,8 +1868,9 @@ def paged_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     ``table_row[j]``, pad blocks land wherever the table maps them
     (the trash block when unmapped — exactness-with-padding,
     block-granular).  Returns ([1, bucket, vocab] logits — the caller
-    samples at ``prompt_len - 1`` — and the pool cache with this
-    lane's position untouched (the caller's insert sets it).
+    samples at ``prompt_len - 1``; with ``head_at`` (traced) the head
+    runs at that one position alone, [1, 1, vocab] — and the pool cache
+    with this lane's position untouched (the caller's insert sets it).
 
     ``quant=True`` (needs ``prompt_len``, traced): whole blocks
     quantize ONCE on the way into the int8 pool
@@ -1880,7 +1882,8 @@ def paged_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     bs = block_size or pool_cache["k"].shape[3]
     lane = D.init_cache(cfg, 1, tokens.shape[1])
     logits, lane = D._forward(cfg, params, tokens, lane, mesh=mesh,
-                              lora=lora, whole_prompt=True)
+                              lora=lora, whole_prompt=True,
+                              head_at=head_at)
     if not quant:
         k = scatter_prompt_blocks(pool_cache["k"], lane["k"], table_row,
                                    bs)
@@ -1924,37 +1927,114 @@ def paged_prefill(params: Dict[str, Any], cfg: LlamaConfig,
     return logits, cache, tail_k, tail_v
 
 
+# The widest rung whose insert carries a decode step.  Every rung is
+# traced and lowered before the server is ready, in Python, and the
+# step's half — the decode kernel's call, its work list, sixteen lanes'
+# cache writes — doubles that (1.0 -> 1.95 s a rung on the v5e's host:
+# PERF.md section 6, PR 33).  The rungs above 1024 are a seventh of the
+# inserts, gain the least a call (a 12 ms step beside a 90-200 ms
+# insert) and would take ``setup_s`` past its bound; they keep the
+# insert alone until the lanes' half is lowered once for all rungs.
+# This is the one fork in the factory: both branches take the head at
+# the prompt's last real token and sample it the same way.
+_STEP_MAX_BUCKET = 1024
+
+
+def insert_carries_step(bucket: int, mesh=None, quant: bool = False,
+                        adapters: bool = False) -> bool:
+    """Whether :func:`make_paged_prefill_insert`'s program for this rung
+    advances the ring's live lanes by a token (and returns ``toks``):
+    the plain bf16 pool at tp 1, up to ``_STEP_MAX_BUCKET``.  The int8
+    pool (its step quantizes on completion through staging tails), a
+    LoRA tail (the lanes' adapter ids are not the prompt's) and a tp
+    mesh (the step's kernel projects inside its manual region) keep the
+    insert alone."""
+    return (bucket <= _STEP_MAX_BUCKET and not quant and not adapters
+            and D.mesh_tp(mesh) == 1)
+
+
 def make_paged_prefill_insert(cfg: LlamaConfig, bucket: int,
                               block_size: int,
                               top_k: Optional[int] = None,
                               top_p: Optional[float] = None, mesh=None,
-                              quant: bool = False):
+                              quant: bool = False,
+                              check_finite: bool = False):
     """Cold (no prefix hit) paged admission — the contiguous
-    make_prefill_insert with the splice replaced by a block scatter.
-    The prefill forward and first-token sample are the SAME compiled
-    ops as the contiguous insert, which is what makes the first token
-    bit-identical between the two rings.
+    make_prefill_insert with the splice replaced by a block scatter —
+    which on the plain bf16 pool (:func:`insert_carries_step`) CARRIES
+    ONE DECODE STEP of the ring: an insert reads every weight once for
+    the prompt's rows while the live lanes stand still, so their next
+    token rides that read (``decode.prefill_with_step``: the lanes' rows
+    concatenated to the prompt's at ``wo``, the feed-forward and the
+    head; apart through q/k/v, rotation, cache write and attention)
+    instead of paying a step's read of its own — on the rungs up to
+    ``_STEP_MAX_BUCKET``.  The step is ``make_paged_chunk_step``'s
+    tick: sampled at the lanes' own positions, ``tok`` and ``pos``
+    advanced for ``active`` lanes only.  A lane outside ``active`` — the
+    inserted slot always — writes its row to the trash block and keeps
+    its token and position (unlike the step, which zeroes an inactive
+    lane's position: a live lane whose pool block could not be mapped
+    sits this one out and decodes on).
 
     ``quant=True``: whole blocks quantize once into the int8 pool; the
     prompt's partial last block lands exact in the lane's staging tail
     (decode.paged_prefill quant contract).
 
-    ``insert(params, cache, table_row, tok, temp, keys,
+    ``insert(params, cache, table [B, M], tok, temp, keys, active [B],
     prompt [1,bucket], prompt_len, slot, temp_val, seed)
-    -> (cache', tok', temp', keys', first_token)``
+    -> (cache', tok', temp', keys', first_token, toks [1, B][, ok [B]])``
+    — ``ok`` under ``check_finite``, the step's isfinite verdict a lane;
+    without the carried step (a wider rung, int8, adapters, tp) the
+    first five outputs alone, the slot's row taken from ``table``.
     """
     if bucket % block_size:
         raise ValueError(f"prefill bucket {bucket} not a multiple of the "
                          f"block size {block_size}")
 
-    def insert(params, cache, table_row, tok, temp, keys, prompt,
+    def insert(params, cache, table, tok, temp, keys, active, prompt,
                prompt_len, slot, temp_val, seed, *lora_args):
+        table_row = jax.lax.dynamic_index_in_dim(table, slot,
+                                                 keepdims=False)
+        key = jax.random.PRNGKey(seed)
+        temp1 = jnp.reshape(temp_val, (1,)).astype(jnp.float32)
+        if insert_carries_step(bucket, mesh, quant, bool(lora_args)):
+            active = active & (jnp.arange(tok.shape[0]) != slot)
+            pos = cache["pos"]
+            view = paged_view(
+                cfg, cache, jnp.where(active[:, None], table, TRASH_BLOCK),
+                lane_mask=active, mesh=mesh)
+            logits, lane, stepped = D.prefill_with_step(
+                cfg, params, prompt, prompt_len, tok, view, mesh=mesh)
+            new_cache = dict(
+                stepped,
+                **view_class(cfg).scatter_prompt(stepped, lane, table_row,
+                                                 block_size),
+                pos=jnp.where(active, stepped["pos"], pos).at[slot].set(
+                    prompt_len))
+            # one draw for the prompt's row and the lanes', each by its
+            # own temperature, key and position (a sampler of their own
+            # costs the lanes a third of a second's lowering a rung)
+            drawn = D._sample_tokens(
+                logits, jnp.concatenate([temp1, temp]),
+                jnp.concatenate([key[None], keys]),
+                jnp.concatenate([jnp.reshape(prompt_len - 1, (1,)), pos]),
+                top_k, top_p)
+            first, nxt = drawn[0], jnp.where(active, drawn[1:], tok)
+            out = (new_cache, nxt.at[slot].set(first),
+                   temp.at[slot].set(temp_val), keys.at[slot].set(key),
+                   first, nxt[None])
+            if check_finite:
+                out += (jnp.all(jnp.isfinite(logits[1:]), axis=-1)
+                        | ~active,)
+            return out
+        # the insert alone: the head at the prompt's last real token, as
+        # on the carrying branch (no [W, vocab] logits on any rung)
         lora = tuple(lora_args) if lora_args else None
         if quant:
             logits, new_cache, tail_k, tail_v = paged_prefill(
                 params, cfg, prompt, cache, table_row,
                 block_size=block_size, mesh=mesh, quant=True,
-                prompt_len=prompt_len, lora=lora)
+                prompt_len=prompt_len, lora=lora, head_at=prompt_len - 1)
             new_cache["kt"] = jax.lax.dynamic_update_slice(
                 new_cache["kt"], tail_k, (0, slot, 0, 0, 0))
             new_cache["vt"] = jax.lax.dynamic_update_slice(
@@ -1963,14 +2043,12 @@ def make_paged_prefill_insert(cfg: LlamaConfig, bucket: int,
             logits, new_cache = paged_prefill(params, cfg, prompt,
                                               cache, table_row,
                                               block_size=block_size,
-                                              mesh=mesh, lora=lora)
-        logits = logits[0, prompt_len - 1]
+                                              mesh=mesh, lora=lora,
+                                              head_at=prompt_len - 1)
         new_cache["pos"] = new_cache["pos"].at[slot].set(prompt_len)
-        key = jax.random.PRNGKey(seed)
         first = D._sample_tokens(
-            logits[None], jnp.reshape(temp_val, (1,)).astype(jnp.float32),
-            key[None], jnp.reshape(prompt_len - 1, (1,)),
-            top_k, top_p)[0]
+            logits[0], temp1, key[None],
+            jnp.reshape(prompt_len - 1, (1,)), top_k, top_p)[0]
         return (new_cache,
                 tok.at[slot].set(first),
                 temp.at[slot].set(temp_val),

@@ -480,6 +480,9 @@ class ContinuousBatcher:
         # the rolling anti-thrash budget bounding how often residents
         # may be spilled at all
         self._parked: List[_ParkedLane] = []
+        # dispatches in flight, oldest first: [(chunk_reqs, res,
+        # t_dispatch)] — the ring thread's pipeline (_loop_body)
+        self._inflight: List[tuple] = []
         self._preempt_budget = QOS.PreemptionBudget(
             self.qos.preempt_budget, self.qos.preempt_window_s)
         # fleet-level KV (ISSUE 12).  ``migrate_out(meta, spill)`` —
@@ -551,6 +554,11 @@ class ContinuousBatcher:
                       "prefill_bucket_tokens": 0,
                       "prefill_calls_by_bucket": {},
                       "decode_steps": 0, "decode_lane_steps": 0,
+                      # cold inserts whose program carried a decode
+                      # step of the ring (paged.make_paged_prefill_
+                      # insert), and the lanes those steps advanced;
+                      # the step itself counts above like any other
+                      "insert_steps": 0, "insert_step_lanes": 0,
                       # the paged decode kernel's work list against the
                       # lanes x blocks rectangle, a layer's call of each
                       # decode iteration (positions as the host holds
@@ -1096,6 +1104,8 @@ class ContinuousBatcher:
             "decodeLaneStepsTotal": self.stats["decode_lane_steps"],
             "decodeCellsLive": self.stats["decode_cells_live"],
             "decodeCellsGrid": self.stats["decode_cells_grid"],
+            "insertStepsTotal": self.stats["insert_steps"],
+            "insertStepLanesTotal": self.stats["insert_step_lanes"],
             "prefillCallsTotal": self.stats["prefill_calls"],
             "prefillTokensTotal": pf_tok,
             "prefillBucketTokensTotal":
@@ -1723,32 +1733,94 @@ class ContinuousBatcher:
         # allocator then maps fresh blocks instead of the cached ones
         # (never written over) when spec mode is off
         hit_len = self._pool_admit(slot, req)   # NoFreeBlocks -> req fails
-        tbl_row = jnp.asarray(self.pool.table[slot])
         if self.spec_k:
             with TR.phase("exec.insert", width=req.bucket, tokens=n):
                 (ex.cache, ex.dcache, ex.tok, ex.temp, ex.keys,
                  first) = ex.inserts[req.bucket](
                     ex.params, ex.draft_params, ex.cache, ex.dcache,
-                    tbl_row, ex.tok, ex.temp, ex.keys, req.dev_prompt,
-                    n, slot, float(req.temperature), req.seed)
+                    jnp.asarray(self.pool.table[slot]), ex.tok, ex.temp,
+                    ex.keys, req.dev_prompt, n, slot,
+                    float(req.temperature), req.seed)
             self._count_prefill(req.bucket, n)
         elif hit_len:
-            first = self._suffix_admit(slot, req, tbl_row, hit_len)
+            first = self._suffix_admit(
+                slot, req, jnp.asarray(self.pool.table[slot]), hit_len)
         else:
-            with TR.phase("exec.insert", width=req.bucket, tokens=n):
-                ex.cache, ex.tok, ex.temp, ex.keys, first = \
-                    ex.inserts[req.bucket](
-                        ex.params, ex.cache, tbl_row, ex.tok,
-                        ex.temp, ex.keys, req.dev_prompt, n, slot,
-                        float(req.temperature), req.seed,
-                        *ex.lora_insert_tail(req.adapter_idx))
+            riders = (self._insert_riders(slot)
+                      if req.bucket in ex.insert_steps else [])
+            with TR.phase("exec.insert", width=req.bucket, tokens=n,
+                          lanes_stepped=len(riders)) as ph:
+                first, res = ex.cold_insert(
+                    req.bucket, slot, self.pool.table, riders,
+                    req.dev_prompt, n, float(req.temperature), req.seed,
+                    req.adapter_idx)
             self._count_prefill(req.bucket, n)
+            if res is not None:
+                self._queue_insert_step(riders, res, ph.t0)
         # register this lane's full prompt blocks for future admissions
         # (content is valid for any later dispatch — same device stream;
         # adapter lanes publish under their namespace, so reuse happens
         # within a tenant's fine-tune and never across)
         self.pool.publish(slot, req.prompt, ns=req.ns)
         return first
+
+    def _insert_riders(self, slot: int) -> List[int]:
+        """The lanes a cold insert into ``slot`` advances by a token
+        (the step its program carries): every lane with a request and
+        no prefill pending but the inserted one — a lane an earlier
+        insert activated and no chunk has included yet rides too.  The
+        step writes lane i's row at its device position, the host's
+        mirror plus what is in flight for it, so the pool must map that
+        position's block first (the plan's projection with one more
+        row); a lane that cannot grow sits this step out — it is not
+        failed here, its next chunk's plan asks again."""
+        waiting = self._pending_prefill_slots()
+        riders = []
+        for i, r in enumerate(self.lane):
+            if r is None or i == slot or i in waiting:
+                continue
+            try:
+                self.pool.ensure(i, self._lane_pos[i]
+                                 + self._rows_in_flight(i) + 1)
+            except self.executor._pg.NoFreeBlocks:
+                continue
+            riders.append(i)
+        return riders
+
+    def _rows_in_flight(self, i: int) -> int:
+        """Positions lane ``i`` advances in the dispatches not yet
+        consumed: the host's position mirror lags them."""
+        return sum(res.rows for chunk_reqs, res, _ in self._inflight
+                   for j, r in chunk_reqs
+                   if j == i and r is self.lane[i])
+
+    def _queue_insert_step(self, riders: List[int], res, t0: float) -> None:
+        """An insert returned the tokens of the step it carried: queue
+        them behind the dispatches in flight, as the one-step result
+        they are, so :meth:`_consume` delivers them in device order —
+        after the chunk before the insert, before the chunk after —
+        with budgets, EOS, deadlines and eviction as for any step."""
+        if not riders:
+            # nobody rode (an idle ring, or no lane could grow): the
+            # program's step advanced no lane, so it is no decode step
+            # to the counters and there is nothing to deliver
+            return
+        st = self.stats
+        st["insert_steps"] += 1
+        st["insert_step_lanes"] += len(riders)
+        st["decode_steps"] += 1
+        st["decode_lane_steps"] += len(riders)
+        live, grid = self.pool.decode_cell_counts(self._lane_pos,
+                                                  set(riders))
+        st["decode_cells_live"] += live
+        st["decode_cells_grid"] += grid
+        for dev in (res.toks, res.ok):
+            try:
+                dev.copy_to_host_async()
+            except AttributeError:      # None / interpret-mode ndarray
+                pass
+        self._inflight.append(([(i, self.lane[i]) for i in riders], res,
+                               t0))
 
     def _suffix_admit(self, slot: int, req: _Request, tbl_row, hit_len):
         """Prefix-hit admission: one suffix-only insert over the
@@ -2957,7 +3029,9 @@ class ContinuousBatcher:
         # chunks N+1..N+depth.  Without this the ring serializes the
         # host's share with compute.  Depth 2 by default; whether depth
         # 1 suffices on a directly attached chip is not re-measured.
-        pending: List[tuple] = []   # [(chunk_reqs, res, t_dispatch)]
+        # (an insert's carried step joins the same list: _admit_paged)
+        pending = self._inflight    # [(chunk_reqs, res, t_dispatch)]
+        pending.clear()
         # the loop thread's time, tiled: at every moment it is inside
         # exactly one top-level phase (utils/tracing.py Tiling), so the
         # phase table's self seconds are shares of this loop's wall
@@ -3201,18 +3275,13 @@ class ContinuousBatcher:
                 # the error) frees its blocks for the rest of the ring,
                 # which must keep serving.
                 for i in list(active_idx):
-                    inflight = sum(
-                        entry_res.n_steps
-                        for chunk_reqs, entry_res, _ in pending
-                        for j, r in chunk_reqs
-                        if j == i and r is self.lane[i])
                     left_i = max(1, self._lane_left[i])
                     my_steps = (min(n_mega, left_i) if self.spec_k
                                 else min(n_mega, -(-left_i // self.chunk)))
                     try:
                         self.pool.ensure(
-                            i, self._lane_pos[i]
-                            + (inflight + my_steps) * advance)
+                            i, self._lane_pos[i] + self._rows_in_flight(i)
+                            + my_steps * advance)
                     except self.executor._pg.NoFreeBlocks as e:
                         r = self.lane[i]
                         if r is not None and r.error is None:
@@ -3314,8 +3383,11 @@ class ContinuousBatcher:
                     pass
             pending.append(([(i, self.lane[i]) for i in active_idx],
                             res, ph.t0))
-            if len(pending) >= self.pipeline_depth:
+            # (while, not if: each insert since the last pass queued a
+            # one-step result of its own)
+            while len(pending) >= self.pipeline_depth:
                 try:
                     self._consume_oldest(pending)
                 except Exception as e:
                     self._fault = e
+                    break

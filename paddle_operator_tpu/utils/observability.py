@@ -195,8 +195,9 @@ def _counter_gauges(out: dict, status_serving: dict, job: str,
     """The ring's raw cumulative counters (top-level block only, like
     the QoS gauges): decode dispatches, the device decode iterations
     they ran, those times the lanes live in each plan, and the paged
-    decode kernel's live cells against its old rectangle; insert
-    programs dispatched (by program width), the real tokens they
+    decode kernel's live cells against its old rectangle; the cold
+    inserts that carried a decode step and the lanes those advanced;
+    insert programs dispatched (by program width), the real tokens they
     prefilled and the positions they computed; and the loop thread's
     self seconds and counts by phase.  Counters, so a dashboard takes
     ``rate()`` and forms the ratios itself (padding share, lane
@@ -209,6 +210,8 @@ def _counter_gauges(out: dict, status_serving: dict, job: str,
             ("decodeLaneStepsTotal", "decode_lane_steps"),
             ("decodeCellsLive", "decode_cells_live"),
             ("decodeCellsGrid", "decode_cells_grid"),
+            ("insertStepsTotal", "insert_steps"),
+            ("insertStepLanesTotal", "insert_step_lanes"),
             ("prefillTokensTotal", "prefill_tokens"),
             ("prefillBucketTokensTotal", "prefill_bucket_tokens")):
         out[f"tpujob_serve_{name}_total{lbl}"] = \

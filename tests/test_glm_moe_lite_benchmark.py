@@ -325,7 +325,10 @@ def test_benchmark_lists_the_new_cell_where_the_issue_says():
     assert "workloads" not in next(m for m in bench["end_to_end"]
                                    if m["name"] == "setup_s")
     new = [m for m in bench["per_layer"] if m["name"] in NEW]
-    assert [m["name"] for m in bench["per_layer"]][-5:] == list(NEW)
+    # ... appended together, in this order (later PRs append behind them)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + 5] == list(NEW) and at == 30
     for m in new:
         assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
         assert os.path.exists(os.path.join(ROOT, "benchmark", "metrics",

@@ -395,12 +395,10 @@ class TestSpillRestore:
     def _admit(self, ex, slot, p, seed=0):
         n = len(p)
         ex.pool.admit(slot, p)
-        row = jnp.asarray(ex.pool.table[slot])
         padded = np.zeros((1, 16), np.int32)
         padded[0, :n] = p
-        ex.cache, ex.tok, ex.temp, ex.keys, first = ex.inserts[16](
-            ex.params, ex.cache, row, ex.tok, ex.temp, ex.keys,
-            jnp.asarray(padded), n, slot, 0.0, seed)
+        first, _ = ex.cold_insert(16, slot, ex.pool.table, [],
+                                  jnp.asarray(padded), n, 0.0, seed)
         ex.pool.publish(slot, p)
         return int(first)
 

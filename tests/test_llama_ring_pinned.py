@@ -65,7 +65,7 @@ def _paged_ring(cfg):
         ("step", PG.make_paged_chunk_step(cfg, CHUNK),
          (params, cache, table, tok, temp, keys, active)),
         ("insert", PG.make_paged_prefill_insert(cfg, BUCKET, BLOCK),
-         (params, cache, table[0], tok, temp, keys,
+         (params, cache, table, tok, temp, keys, active,
           jnp.zeros((1, BUCKET), jnp.int32), 5, 1, 0.0, 3)),
     ]
 

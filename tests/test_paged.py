@@ -306,9 +306,10 @@ class TestPagedKernel:
             for slot, n in enumerate((13, 9)):
                 padded = np.zeros((1, 16), np.int32)
                 padded[0, :n] = _prompt(cfg, n, seed=20 + slot)
-                cache, tok, temp, keys, _ = insert(
-                    params, cache, table[slot], tok, temp, keys,
-                    jnp.asarray(padded), n, slot, 0.0, 0)
+                cache, tok, temp, keys = insert(
+                    params, cache, table, tok, temp, keys,
+                    jnp.zeros((2,), bool), jnp.asarray(padded), n, slot,
+                    0.0, 0)[:4]
             view = PG.paged_view(cfg, cache, table, lane_mask=active)
             bufs, _ = view.begin(1)
             att = None

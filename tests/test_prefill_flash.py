@@ -90,10 +90,12 @@ def _insert(kind, cfg, params, prompt, n, width):
                                 prefix_cache=False)
     cache = PG.init_paged_cache(cfg, SLOTS, pool.total, BS,
                                 quant="int8" if quant else "none")
-    row = jnp.arange(1, pool.max_blocks + 1, dtype=jnp.int32)
+    table = jnp.zeros((SLOTS, pool.max_blocks), jnp.int32).at[1].set(
+        jnp.arange(1, pool.max_blocks + 1))
     ins = PG.make_paged_prefill_insert(cfg, width, BS, quant=quant)
-    cache, _, _, _, first = ins(params, cache, row, tok, temp, keys, prompt,
-                                n, 1, 0.0, 0)
+    cache, _, _, _, first = ins(params, cache, table, tok, temp, keys,
+                                jnp.zeros((SLOTS,), bool), prompt, n, 1,
+                                0.0, 0)[:5]
     whole = n // BS if quant else -(-n // BS)
 
     def rows(name):

@@ -142,12 +142,10 @@ class TestEveryRungSameArithmetic:
         # and the ring's own program on this rung answers from them
         padded = np.zeros((1, rung), np.int32)
         padded[0, :n] = prompt
-        tbl = np.full((m,), TRASH_BLOCK, np.int32)
-        tbl[:len(used)] = used
-        ring.cache, ring.tok, ring.temp, ring.keys, first = \
-            ring.inserts[rung](ring.params, ring.cache, jnp.asarray(tbl),
-                               ring.tok, ring.temp, ring.keys,
-                               jnp.asarray(padded), n, 0, 0.0, 0)
+        tbl = np.full((2, m), TRASH_BLOCK, np.int32)
+        tbl[0, :len(used)] = used
+        first, _ = ring.cold_insert(rung, 0, tbl, [], jnp.asarray(padded),
+                                    n, 0.0, 0)
         assert int(first) == int(np.argmax(widest[0]))
         np.testing.assert_allclose(
             np.asarray(ring.cache["k"][:, used]), widest[1],

@@ -226,6 +226,8 @@ class TestGaugeNaming:
             'tpujob_serve_decode_lane_steps_total{job="default/j"}',
             'tpujob_serve_decode_cells_live_total{job="default/j"}',
             'tpujob_serve_decode_cells_grid_total{job="default/j"}',
+            'tpujob_serve_insert_steps_total{job="default/j"}',
+            'tpujob_serve_insert_step_lanes_total{job="default/j"}',
             'tpujob_serve_prefill_tokens_total{job="default/j"}',
             'tpujob_serve_prefill_bucket_tokens_total'
             '{job="default/j"}',
@@ -456,7 +458,8 @@ class TestBatcherServingStatus:
                            # (ISSUE 26)
                            "dispatchesTotal", "decodeStepsTotal",
                            "decodeLaneStepsTotal", "decodeCellsLive",
-                           "decodeCellsGrid", "prefillCallsTotal",
+                           "decodeCellsGrid", "insertStepsTotal",
+                           "insertStepLanesTotal", "prefillCallsTotal",
                            "prefillTokensTotal",
                            "prefillBucketTokensTotal",
                            "prefillCallsByBucket",
@@ -470,6 +473,7 @@ class TestBatcherServingStatus:
         assert st["prefillCallsByBucket"] == {"16": 1}
         assert st["prefillAttnByBucket"] == {"16": "einsum", "32": "einsum"}
         assert st["decodeStepsTotal"] == 2 * st["dispatchesTotal"]
+        assert st["insertStepsTotal"] == 0     # the contiguous insert's
         assert st["decodeLaneStepsTotal"] == st["decodeStepsTotal"] >= 3
         assert st["phaseCounts"]["sched.admit"] == 1
         assert st["phaseSeconds"]["exec.dispatch"] > 0

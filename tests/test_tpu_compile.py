@@ -320,6 +320,51 @@ def test_serving_step_of_the_dense_cell(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 674 * 10 ** 6
 
 
+@pytest.mark.parametrize("width,calls", [(1024, 2), (256, 1), (3072, 1)])
+def test_serving_insert_of_the_dense_cell(one_chip, monkeypatch, width, calls):
+    """ISSUE 33: ``jit_insert`` at ``mistral-7b-serve-16l``'s shapes carries
+    one decode step of the 16 lanes on the rungs up to 1024.  Its layer
+    scan holds the decode kernel's call beside the flash kernel's (the
+    einsum rungs: the decode kernel's alone); the head runs over 1 + 16
+    rows; the function is still named ``insert`` (the roofline readers
+    tell decode-kernel calls by ``"step" in module``).  A wider rung
+    keeps the insert alone — the flash kernel's call, five outputs — with
+    the head at the prompt's last real token: no ``[W, 32000]`` logits
+    buffer exists on any rung (ROADMAP A1(d))."""
+    import re
+
+    from paddle_operator_tpu.infer import paged as PG
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(_gqa_7b(16), ffn_dim=14336,
+                              dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    on_chip = lambda t: jax.tree.map(                        # noqa: E731
+        lambda x: sds(x.shape, x.dtype, one_chip), t)
+    params = _serving_shapes(
+        cfg, lambda t: jax.tree.map(lambda _: one_chip, t))
+    pool = PG.PagedCacheManager(16, 4096, 256)
+    cache = on_chip(jax.eval_shape(
+        lambda: PG.init_paged_cache(cfg, 16, pool.total, 256)))
+    table, tok, temp, keys, active = _ring_lanes(one_chip, 16,
+                                                 pool.max_blocks)
+    compiled = PG.make_paged_prefill_insert(cfg, width, 256).lower(
+        params, cache, table, tok, temp, keys, active,
+        sds((1, width), jnp.int32, one_chip), 1, 0, 0.0, 0).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_insert")
+    assert kernel_calls(compiled) == calls
+    # (the parent's compiled text held it as ``bf16[W,32000]``: XLA had
+    # pushed the conversion past the slice, not the slice past the product)
+    assert not re.search(rf"\[(1,)?{width},32000\]", text)
+    if not PG.insert_carries_step(width):
+        assert re.search(r"\[(1,)?1,32000\]", text)   # the head: one row
+        assert len(compiled.output_shardings) == 5
+        return
+    assert "bf16[17,32000]" in text             # the one head product
+    assert len(compiled.output_shardings) == 6  # ... and ``toks [1, 16]``
+    assert compiled.memory_analysis().temp_size_in_bytes < 200 * 10 ** 6
+
+
 def test_serving_step_of_the_expert_cell(one_chip, monkeypatch):
     """ISSUE 31: the expert architecture's step at
     ``trinity-mini-serve-5l``'s shapes: the dense layer's decode call,
